@@ -20,7 +20,7 @@ minutes of training or dropping queued inference requests:
   quiesce → finish in-flight → persist undrained queue → replay on
   restart, zero loss / zero duplicates.
 - :mod:`~autodist_tpu.ft.procdrain` — signal-then-grace subprocess
-  termination (standalone; the queue driver loads it by path).
+  termination (standalone: no package imports).
 
 Entry point for users: ``AutoDist(fault_tolerance=FTConfig(...))`` — the
 returned :class:`FTRuntime` rides on ``autodist.ft``. See

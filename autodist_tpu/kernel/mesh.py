@@ -5,8 +5,7 @@ Replaces the reference's device resolver + ClusterSpec
 ``cluster.py:70-82``): AutoDist device strings resolved into a
 ``jax.sharding.Mesh`` instead of TF ``DeviceSpecV2`` job/task strings. On real
 TPU slices the mesh uses ``mesh_utils.create_device_mesh`` so logical axes map
-onto physical ICI rings; on the host-platform (tests) it falls back to a plain
-reshape.
+onto physical ICI rings; on the host platform (tests) it is a plain reshape.
 """
 from __future__ import annotations
 
@@ -106,11 +105,11 @@ def build_mesh(
     if devices and devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
 
-        try:
-            mesh_devices = mesh_utils.create_device_mesh(dims, devices=devices)
-            return Mesh(mesh_devices, axis_names)
-        except Exception as e:  # noqa: BLE001 - fall back to naive order
-            logging.warning("create_device_mesh failed (%s); using naive order", e)
+        # A failure here raises: device order on a TPU decides which
+        # collectives ride ICI neighbours, so a silent reshape in id order
+        # would be a different (slower) mesh than the one asked for.
+        return Mesh(mesh_utils.create_device_mesh(dims, devices=devices),
+                    axis_names)
     return Mesh(np.asarray(devices).reshape(dims), axis_names)
 
 

@@ -131,8 +131,18 @@ def _attention(q, k, v, cfg: TransformerConfig):
     if impl == "dot":
         return _dot_attention(q, k, v, cfg.causal)
     if impl == "flash":
-        from autodist_tpu.ops.flash_attention import flash_attention
+        from autodist_tpu.ops.flash_attention import (
+            flash_attention, kernel_supports)
 
+        if not kernel_supports(q.shape[1], k.shape[1]):
+            # "auto" never lands here (it only resolves to flash on aligned
+            # sequences), so this is an explicit config the kernel cannot
+            # honor — the op's reference fallback would silently train a
+            # different program than the one configured.
+            raise ValueError(
+                f"attention_impl='flash' needs equal sequence lengths that "
+                f"are a multiple of 128; got q={q.shape[1]} k={k.shape[1]}. "
+                f"Use attention_impl='auto' or 'dot' for this shape.")
         return flash_attention(q, k, v, causal=cfg.causal)
     if impl == "ring":
         from autodist_tpu.parallel.ring_attention import ring_attention

@@ -54,7 +54,8 @@ from autodist_tpu.obs.exporter import (
     parse_openmetrics,
     render_openmetrics,
 )
-from autodist_tpu.obs.profiler import StepProfiler, StepTimer, detect_peak_flops
+from autodist_tpu.obs.profiler import (
+    StepProfiler, StepTimer, detect_peak_flops, peak_flops_for_kind)
 from autodist_tpu.obs.recorder import FlightRecorder, read_records
 from autodist_tpu.obs.sentry import Finding, Sentry, SentryConfig
 from autodist_tpu.obs.slo import SLOSpec, SLOTracker, replay_flight_records
@@ -97,6 +98,7 @@ __all__ = [
     "events_for_request",
     "get_tracer",
     "parse_openmetrics",
+    "peak_flops_for_kind",
     "read_records",
     "render_openmetrics",
     "replay_flight_records",

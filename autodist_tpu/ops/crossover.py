@@ -29,6 +29,8 @@ import json
 import os
 from typing import Optional
 
+from autodist_tpu.utils import logging
+
 #: Fallback when no measured table is readable: the v5e-measured breakeven
 #: (flash ties dot at s=1024 and wins beyond; docs/measured/
 #: flash_crossover.json).
@@ -39,6 +41,15 @@ DEFAULT_FLASH_CROSSOVER_SEQ = 1024
 _FLASH_BLOCK = 128
 
 _cache: dict = {}
+
+
+def _warn_unreadable(path: str, err: Exception, default: int) -> None:
+    """The constant stands in for a table that could not be read — a
+    different selection rule than the measured one, so say it. The lookups
+    are cached per shape, so this fires once per shape, not per trace."""
+    logging.warning(
+        "crossover table %s unreadable (%s: %s); using the packaged "
+        "default %d", path, type(err).__name__, err, default)
 
 
 def _measured_path() -> str:
@@ -56,8 +67,9 @@ def flash_crossover_seq(path: Optional[str] = None) -> int:
     if key in _cache:
         return _cache[key]
     out = DEFAULT_FLASH_CROSSOVER_SEQ
+    path = path or _measured_path()
     try:
-        with open(path or _measured_path(), "r", encoding="utf-8") as f:
+        with open(path, "r", encoding="utf-8") as f:
             rows = json.load(f).get("rows", [])
         by_seq: dict = {}
         for r in rows:
@@ -70,8 +82,8 @@ def flash_crossover_seq(path: Optional[str] = None) -> int:
                    for t in seqs[i:]):
                 out = s
                 break
-    except (OSError, ValueError, KeyError, TypeError):
-        pass  # unmeasured installs use the packaged default
+    except (OSError, ValueError, KeyError, TypeError) as e:
+        _warn_unreadable(path, e, out)
     _cache[key] = out
     return out
 
@@ -113,9 +125,9 @@ def paged_crossover_timeline(batch: Optional[int] = None,
     if key in _cache:
         return _cache[key]
     out = DEFAULT_PAGED_CROSSOVER_TIMELINE
+    path = path or _paged_measured_path()
     try:
-        with open(path or _paged_measured_path(), "r",
-                  encoding="utf-8") as f:
+        with open(path, "r", encoding="utf-8") as f:
             rows = json.load(f).get("rows", [])
         # Nearest recorded (batch, heads) bucket: the sweep records a few
         # decode-shaped points, not the full cross product.
@@ -141,8 +153,8 @@ def paged_crossover_timeline(batch: Optional[int] = None,
                    for u in tls[i:]):
                 out = t
                 break
-    except (OSError, ValueError, KeyError, TypeError):
-        pass  # unmeasured installs use the packaged default
+    except (OSError, ValueError, KeyError, TypeError) as e:
+        _warn_unreadable(path, e, out)
     _cache[key] = out
     return out
 

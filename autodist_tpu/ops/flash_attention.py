@@ -11,8 +11,10 @@ Layout contract: q, k, v are [batch, seq, heads, head_dim] (the transformer's
 natural shape); internally folded to [batch*heads, seq, head_dim].
 
 On CPU the kernels run in pallas interpret mode (tests exercise the same
-kernel logic); non-block-aligned sequence lengths fall back to the jnp
-reference implementation.
+kernel logic). Direct callers with a sequence the kernel cannot take
+(:func:`kernel_supports`) get the jnp reference instead, logged once on a
+TPU backend; the transformer's explicit ``attention_impl="flash"`` raises
+at trace time rather than fall back.
 """
 from __future__ import annotations
 
@@ -22,7 +24,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from autodist_tpu.utils import logging
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -252,30 +255,47 @@ def _pallas_forward(q3, k3, v3, causal, block_q, block_k, interpret):
     return out, lse
 
 
-def _use_reference(q, k, block_q, block_k) -> bool:
-    # Conservative: require block-aligned sequences (TPU tile constraint is
-    # last-two block dims divisible by (8, 128) or equal to the array dims;
-    # checking against the *uncapped* block size keeps odd lengths off the
-    # kernel path entirely).
-    seq_q, seq_k = q.shape[1], k.shape[1]
+def kernel_supports(seq_q: int, seq_k: int,
+                    block_q: int = DEFAULT_BLOCK_Q,
+                    block_k: int = DEFAULT_BLOCK_K) -> bool:
+    """True when the pallas kernels take this shape: equal, block-aligned
+    sequence lengths (the TPU tile constraint is last-two block dims
+    divisible by (8, 128) or equal to the array dims; checking against the
+    *uncapped* 128 keeps odd lengths off the kernel path entirely)."""
     return (
-        seq_q % min(block_q, seq_q) != 0
-        or seq_k % min(block_k, seq_k) != 0
-        or seq_q % 128 != 0
-        or seq_k % 128 != 0
-        or seq_q != seq_k
+        seq_q == seq_k
+        and seq_q % 128 == 0
+        and seq_q % min(block_q, seq_q) == 0
+        and seq_k % min(block_k, seq_k) == 0
     )
+
+
+_fallback_logged = False
+
+
+def _log_fallback_once(seq_q: int, seq_k: int) -> None:
+    """A direct caller asked for the kernel and got the reference: on the
+    device that is a different program than the one named, so say so."""
+    global _fallback_logged
+    if _fallback_logged or jax.default_backend() != "tpu":
+        return
+    _fallback_logged = True
+    logging.warning(
+        "flash_attention: seq_q=%d seq_k=%d is not kernel-aligned; running "
+        "the jnp reference (O(s^2) logits) instead of the pallas kernel",
+        seq_q, seq_k)
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
     if interpret is None:
         interpret = _should_interpret()
     b, s, h, d = q.shape
-    block_q = min(block_q, s)
-    block_k = min(block_k, k.shape[1])
-    if _use_reference(q, k, block_q, block_k):
+    if not kernel_supports(s, k.shape[1], block_q, block_k):
+        _log_fallback_once(s, k.shape[1])
         out = mha_reference(q, k, v, causal)
         return out, (q, k, v, out, None)
+    block_q = min(block_q, s)
+    block_k = min(block_k, k.shape[1])
     q3, k3, v3 = _fold_heads(q), _fold_heads(k), _fold_heads(v)
     out3, lse = _pallas_forward(q3, k3, v3, causal, block_q, block_k, interpret)
     return _unfold_heads(out3, b, h), (q, k, v, _unfold_heads(out3, b, h), lse)

@@ -181,8 +181,7 @@ def _paged_kernel(tables_ref, qpos_ref, q_ref, *rest, page_len: int,
     ) * scale                                      # [H, Q, T] fp32
     t_abs = p * page_len + jax.lax.broadcasted_iota(
         jnp.int32, (n_q, page_len), 1)
-    qpos = qpos_ref[0]                             # [Q] int32
-    admit = t_abs <= qpos[:, None]                 # [Q, T]
+    admit = t_abs <= qpos_ref[0]                   # [Q, 1] -> [Q, T]
     s = jnp.where(admit[None, :, :], s, NEG_INF)
 
     m = m_ref[...]                                 # [H, Q, 1]
@@ -217,12 +216,15 @@ def _kernel_attention(q4, k_pages, v_pages, page_tables, q_positions,
     quantized = k_scale is not None
     scale = 1.0 / (d ** 0.5)
     tables = page_tables.astype(jnp.int32)
-    qpos = q_positions.astype(jnp.int32)
+    # [B, Q, 1]: a (1, Q, 1) block equals the array's last two dims, which
+    # Mosaic requires of a block that is not (8, 128)-aligned — a (1, Q)
+    # block of [B, Q] is refused when Q is 1 (decode).
+    qpos = q_positions.astype(jnp.int32)[..., None]
 
     page_spec = pl.BlockSpec(
         (1, page_len, h, d), lambda bi, pi, t: (t[bi, pi], 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, n_q), lambda bi, pi, t: (bi, 0)),          # qpos
+        pl.BlockSpec((1, n_q, 1), lambda bi, pi, t: (bi, 0, 0)),    # qpos
         pl.BlockSpec((1, n_q, h, d), lambda bi, pi, t: (bi, 0, 0, 0)),
         page_spec,                                                  # k page
         page_spec,                                                  # v page

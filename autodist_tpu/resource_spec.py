@@ -78,14 +78,23 @@ HBM_BY_ACCELERATOR = {
 DEFAULT_HBM = min(HBM_BY_ACCELERATOR.values())
 
 
-def hbm_spec_for_kind(kind: str) -> Tuple[float, float]:
-    """(HBM GB, HBM GB/s) for a device-kind string (e.g. jax's ``device_kind``
-    \"TPU v5 lite\"), longest-substring-first; DEFAULT_HBM when unknown."""
+def _hbm_key(kind: str) -> Optional[str]:
+    """The HBM_BY_ACCELERATOR key a device-kind string resolves to
+    (longest-substring-first), or None when the table does not list it."""
     kind = (kind or "").lower()
     for key in sorted(HBM_BY_ACCELERATOR, key=len, reverse=True):
         if key in kind:
-            return HBM_BY_ACCELERATOR[key]
-    return DEFAULT_HBM
+            return key
+    return None
+
+
+def hbm_spec_for_kind(kind: str) -> Tuple[float, float]:
+    """(HBM GB, HBM GB/s) for a device-kind string (e.g. jax's ``device_kind``
+    \"TPU v5 lite\"); DEFAULT_HBM for a kind a hand-written spec names that
+    the table does not list. A kind read from a live TPU is checked where it
+    is read (:meth:`ResourceSpec.from_local_devices`) and raises instead."""
+    key = _hbm_key(kind)
+    return HBM_BY_ACCELERATOR[key] if key is not None else DEFAULT_HBM
 
 
 class DeviceType(Enum):
@@ -454,7 +463,19 @@ class ResourceSpec:
         d = {}
         dev0 = jax.devices()[0]
         if dev0.platform == "tpu":
-            d["tpu"] = {"accelerator": str(dev0.device_kind)}
+            kind = str(dev0.device_kind)
+            if _hbm_key(kind) is None:
+                # The conservative default is for specs written by hand; a
+                # chip that is actually here has a real capacity, and
+                # planning (or sizing a KV page pool) against a guess for
+                # it would be silently wrong in either direction.
+                raise ValueError(
+                    f"the local TPU reports device_kind {kind!r}, which "
+                    f"HBM_BY_ACCELERATOR does not list "
+                    f"({sorted(HBM_BY_ACCELERATOR)}); add its HBM capacity "
+                    f"and bandwidth, or pass a resource spec with "
+                    f"tpu.hbm_gb / tpu.hbm_gb_per_s")
+            d["tpu"] = {"accelerator": kind}
         if n_proc == 1:
             d["nodes"] = [{"address": "localhost", "chips": local, "chief": True}]
         else:

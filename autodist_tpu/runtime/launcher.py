@@ -453,16 +453,22 @@ def _launch_local_fleet(
 ) -> int:
     """Emulate an n-host cluster on one machine (testing path).
 
-    ``base_env`` replaces the inherited environment (tests use it to pin
-    ``JAX_PLATFORMS=cpu`` regardless of the host's default backend) —
-    except the framework role vars, which are scrubbed from either source
-    and set explicitly below (see :func:`_scrub_role_vars`).
+    Every process of the emulated fleet is pinned to the CPU platform by
+    its environment: a TPU chip belongs to one process at a time, so n
+    local processes that each initialized the default backend would fail
+    or hang on the same chip. (A real multi-host TPU launch is one process
+    per host through the SSH path, never this one.)
+
+    ``base_env`` replaces the inherited environment — except the framework
+    role vars, which are scrubbed from either source and set explicitly
+    below (see :func:`_scrub_role_vars`).
     """
     port = coordinator_port or const.DEFAULT_COORDINATOR_PORT
     coord = f"127.0.0.1:{port}"
     inherited = _scrub_role_vars(
         dict(os.environ) if base_env is None else dict(base_env)
     )
+    inherited["JAX_PLATFORMS"] = "cpu"
     procs: List[subprocess.Popen] = []
     for pid_idx in range(1, n):
         env = {
@@ -532,7 +538,8 @@ def main(args: Optional[Sequence[str]] = None) -> int:
     parser.add_argument("--resource-spec", default="", help="path to resource_spec.yml")
     parser.add_argument(
         "--num-local-processes", type=int, default=0,
-        help="emulate N hosts on this machine (testing)",
+        help="emulate N hosts on this machine, every process pinned to "
+             "the CPU platform (testing)",
     )
     parser.add_argument("--coordinator-port", type=int, default=0)
     parser.add_argument(

@@ -197,6 +197,9 @@ def main(argv=None) -> int:
 
     import autodist_tpu.strategy as S
     from autodist_tpu.api import AutoDist
+    from autodist_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from autodist_tpu.models import get_model
     from autodist_tpu.models.transformer import decode_model
     from autodist_tpu.serve.batcher import ContinuousBatcher

@@ -1,9 +1,9 @@
-"""JAX API-drift bridges.
+"""The one spelling of ``shard_map`` the package uses.
 
-One place for every "this API moved between the jax versions this package
-spans" adapter, so call sites stay written against the CURRENT jax surface
-and older toolchains are bridged here instead of each site growing its own
-try/except (docs/parity.md § shard_map drift triage).
+Call sites pass the manual axes as ``axis_names`` (partial-manual mode: the
+rest stay GSPMD-auto) and never import ``shard_map`` themselves
+(``tools/check_patterns.py`` rule 1), so a future move of the API is one
+edit here.
 """
 from __future__ import annotations
 
@@ -19,31 +19,12 @@ def shard_map(
     axis_names: Optional[Set[str]] = None,
     check_vma: bool = False,
 ):
-    """``jax.shard_map`` across the experimental→top-level API drift.
-
-    Newer jax exposes ``jax.shard_map(f, mesh, in_specs, out_specs,
-    axis_names=..., check_vma=...)`` where ``axis_names`` lists the MANUAL
-    axes (partial-manual mode: the rest stay GSPMD-auto). jax 0.4.x ships
-    the same machinery as ``jax.experimental.shard_map.shard_map`` with the
-    complementary spelling — ``auto=`` lists the AUTO axes and the varying-
-    manual-axes check is called ``check_rep``. Call sites here are written
-    against the new surface; this shim maps it onto whichever one the
-    installed jax provides.
-    """
+    """``jax.shard_map`` with this package's defaults: ``check_vma`` off,
+    and ``axis_names=None`` meaning every mesh axis is manual."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset()
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check_vma)
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=bool(check_vma), auto=auto,
-    )
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)

@@ -31,27 +31,13 @@ MEASURED = os.path.abspath(os.path.join(
     os.path.dirname(__file__), "..", "..", "docs", "measured"))
 
 def _peak_flops_for(device_kind: str) -> float:
-    """Per-chip peak bf16 FLOPs/s from bench.py's shared table, keyed on
-    the device kind membw.json recorded — a hardcoded v5e constant would
-    silently fake the verdict on any other chip generation."""
-    import importlib.util
+    """Per-chip peak bf16 FLOPs/s from the package's one peak table, keyed
+    on the device kind membw.json recorded — a hardcoded v5e constant would
+    silently fake the verdict on any other chip generation, and so would a
+    default for a kind the table does not list (it raises)."""
+    from autodist_tpu.obs.profiler import peak_flops_for_kind
 
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "..", "..", "bench.py")
-    spec = importlib.util.spec_from_file_location("_bench_for_peaks", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-
-    class _Dev:
-        pass
-
-    d = _Dev()
-    d.device_kind = device_kind
-    peak, detected = mod._peak_flops(d)
-    if not detected:
-        print(f"roofline: unknown device kind {device_kind!r}; assuming "
-              f"{peak / 1e12:.0f} TFLOP/s peak", file=sys.stderr)
-    return peak
+    return peak_flops_for_kind(device_kind)
 
 PROFILES = {
     # model key -> (zoo name, kwargs, profile artifact)

@@ -10,7 +10,7 @@ must train end-to-end (the reference's published benchmark matrix slots):
     NCF        × PSLoadBalancing (embedding-table bin packing)
 
 This driver runs each through ``train.py --pin`` (steady-state device rate,
-one fresh subprocess per pair so a failure or wedge cannot poison the next)
+one fresh subprocess per pair so a failure or hang cannot poison the next)
 and records one artifact: ``docs/measured/strategy_coverage.json``. The
 point is coverage evidence — every pair trains AND its measured rate is on
 record — not a horse race; single-chip strategy spread is small by design

@@ -12,9 +12,12 @@ backward-overlap gradient sync (``GraphConfig.bucket_bytes``,
 backward only hide the wire if the latency-hiding scheduler and async
 collective fusion actually schedule them under compute — these flags ARE
 the mechanism, so the winning set is part of the feature. ``--emit-json``
-records the winner into ``docs/measured/xla_flags.json``, which
-``bench.py`` applies by default on accelerator runs (delete the file or
-set ``AUTODIST_NO_MEASURED_XLA_FLAGS=1`` to opt out).
+records the per-config ms/step and the winner into a JSON file (the
+autopilot's flag-set action reads ``docs/measured/xla_flags.json`` when one
+exists, ``pilot/actions.py``). Nothing applies the file to a run: a flag
+this libtpu does not know aborts backend init, so each run names its flags
+itself. The parent here never imports jax; the configurations run one
+after the other, each owning the chip alone.
 
 These are throughput experiments: anything that wins must be re-validated
 for numerics before promotion (and flags are runtime-version-specific by
@@ -85,7 +88,7 @@ def run_one(name, xla, libtpu, batch, window):
 
 
 def emit_json(path, results, chosen, stable) -> None:
-    """Record the winning flag set where bench.py picks it up by default.
+    """Record the winning flag set.
 
     ``chosen`` is a CONFIGS name; the file keeps the raw per-config
     ms/step so a later round can audit the decision."""

@@ -109,3 +109,13 @@ class TestAutoForward:
         cfg, params, tokens = self._setup(64, "nope")
         with pytest.raises(ValueError, match="unknown attention_impl"):
             forward(params, tokens, cfg)
+
+    def test_explicit_flash_on_unaligned_seq_raises(self):
+        # The op falls back to the jnp reference for direct callers; a
+        # MODEL configured for the kernel must not quietly train another
+        # program. ("auto" never resolves to flash on such a shape.)
+        cfg, params, tokens = self._setup(96, "flash")
+        with pytest.raises(ValueError, match="multiple of 128"):
+            forward(params, tokens, cfg)
+        cfg_a, _, _ = self._setup(96, "auto")
+        assert np.isfinite(np.asarray(forward(params, tokens, cfg_a))).all()

@@ -298,13 +298,7 @@ def test_compressed_path_with_sparse_embedding_matches_oracle():
 
 
 @pytest.mark.parametrize("name", [
-    "HorovodCompressor", "HorovodCompressorEF",
-    pytest.param("PowerSGDCompressor", marks=pytest.mark.skipif(
-        not hasattr(jax, "shard_map"),
-        reason="jax<0.6 partial-manual shard_map: PowerSGD's in-region "
-               "matmuls trip an XLA SPMD partitioner CHECK (process abort, "
-               "not a Python error) on the auto= bridge — see docs/parity.md "
-               "shard_map drift triage")),
+    "HorovodCompressor", "HorovodCompressorEF", "PowerSGDCompressor",
 ])
 def test_compression_on_data_model_mesh(name):
     """Compression must survive a mixed data×model mesh (VERDICT r1 next
@@ -590,10 +584,9 @@ def test_topk_full_ratio_matches_dense_psum():
     out, local2 = _run_topk_shardwise(comp, grads, n_shards)
     expected = jnp.mean(grads, axis=0)
     for s in range(n_shards):
-        # rtol covers psum-vs-mean reassociation: old jaxlib's full-manual
-        # all-reduce sums in a different order than jnp.mean, which moves a
-        # couple of near-cancelling elements by a few ulp (observed 4.5e-6
-        # relative on jax 0.4.37; exact on newer toolchains).
+        # rtol covers psum-vs-mean reassociation: an all-reduce may sum in
+        # a different order than jnp.mean, which moves a couple of
+        # near-cancelling elements by a few ulp.
         np.testing.assert_allclose(np.asarray(out[s]), np.asarray(expected),
                                    rtol=1e-5)
     # Full selection leaves no residual.

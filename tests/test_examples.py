@@ -74,13 +74,6 @@ def test_launcher_cli_runs_trivial_command(tmp_path):
     assert marker.read_text() == "yes"
 
 
-@pytest.mark.xfail(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="jax 0.4.x partial-manual shard_map cannot lower ring "
-           "attention's ppermute on the data×seq mesh (UNIMPLEMENTED "
-           "PartitionId) — docs/parity.md shard_map drift triage",
-    strict=False,
-)
 def test_long_context_example(monkeypatch, capsys):
     import runpy
 

@@ -20,9 +20,6 @@ import __graft_entry__ as graft  # noqa: E402
 
 def test_dryrun_runs_on_preprovisioned_mesh():
     # conftest provisioned the 8-device CPU mesh; no subprocess needed.
-    # (On jax 0.4.x the ring/pipeline families self-skip — see
-    # __graft_entry__._partial_manual_supported — so the gate stays green
-    # on every toolchain it may run under.)
     graft.dryrun_multichip(8)
 
 

@@ -53,14 +53,6 @@ def _compiled_hlo(chunk_size):
 
 
 @pytest.mark.parametrize("chunk_size", [4, 128])
-@pytest.mark.xfail(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="jax 0.4.x's bundled XLA does not run the AllReduceCombiner on "
-           "the CPU backend (12 per-var all-reduces stay unfused); the "
-           "fusion claim holds on the toolchains the package targets — "
-           "docs/parity.md shard_map drift triage row 13",
-    strict=False,
-)
 def test_xla_combines_gradient_allreduces(chunk_size):
     hlo = _compiled_hlo(chunk_size)
     ar_ops = [

@@ -59,14 +59,6 @@ def test_gate_disables_offload_off_tpu():
     assert np.isfinite(float(m["loss"]))
 
 
-@pytest.mark.xfail(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="jax 0.4.x CPU devices address only unpinned_host, so building a "
-           "pinned_host NamedSharding raises even with the gate forced open "
-           "(newer jax CPU backends expose pinned_host) — docs/parity.md "
-           "shard_map drift triage row 14",
-    strict=False,
-)
 def test_plan_marks_ps_vars_when_forced(monkeypatch):
     """Plumbing check: with the gate forced open, PS vars (and their
     optimizer slots) carry pinned_host shardings; AllReduce vars don't."""

@@ -21,19 +21,6 @@ def make_mesh(shape, names):
     return Mesh(np.array(jax.devices()).reshape(shape), names)
 
 
-# jax 0.4.x bridges partial-manual shard_map via the experimental auto=
-# parameter, whose SPMD lowering cannot partition the ppermute wire the
-# pipeline ring needs on mixed data×pipe meshes (UNIMPLEMENTED PartitionId).
-# Pipe-only (full-manual) meshes are unaffected. See docs/parity.md
-# shard_map drift triage.
-_partial_manual_xfail = pytest.mark.xfail(
-    not hasattr(jax, "shard_map"),
-    reason="jax 0.4.x partial-manual shard_map cannot lower ppermute on "
-           "mixed meshes (UNIMPLEMENTED PartitionId)",
-    strict=False,
-)
-
-
 def tiny_moe(**kw):
     return get_model(
         "moe_transformer", vocab_size=128, num_layers=1, d_model=32,
@@ -156,7 +143,6 @@ class TestPipeline:
             x = self.stage_fn(jax.tree.map(lambda a: a[s], params), x)
         return x
 
-    @_partial_manual_xfail
     @pytest.mark.parametrize("n_micro", [4, 8])
     def test_pipeline_matches_sequential_forward(self, n_micro):
         mesh = make_mesh((2, 4), ("data", "pipe"))
@@ -333,7 +319,6 @@ class Test1F1B:
 
 
 class TestPipelineRemat:
-    @_partial_manual_xfail
     def test_remat_stages_identical_math(self):
         # jax.checkpoint changes memory, never values: forward and grads
         # must match the non-remat pipeline bit-for-bit.
@@ -399,7 +384,6 @@ class TestPipelineTrainStep:
             self._stage, self._loss_head, n_microbatches=4,
             optimizer=optax.sgd(0.1), donate_state=False)
 
-    @_partial_manual_xfail
     def test_matches_sequential_oracle(self):
         import optax
 
@@ -428,7 +412,6 @@ class TestPipelineTrainStep:
         np.testing.assert_allclose(
             float(m["loss"]), float(loss_fn(params, x, tgt)), rtol=1e-5)
 
-    @_partial_manual_xfail
     def test_windowed_run_and_evaluate(self):
         params, x, tgt = self._problem()
         step = self._make_step({"data": 2, "pipe": 4})

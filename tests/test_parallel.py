@@ -18,31 +18,12 @@ def make_mesh(shape, names):
     return Mesh(np.array(jax.devices()).reshape(shape), names)
 
 
-# jax 0.4.x bridges partial-manual shard_map via the experimental auto=
-# parameter, whose SPMD lowering cannot partition the PartitionId/ppermute
-# wire the ring schedule needs on mixed meshes — it either raises
-# UNIMPLEMENTED or trips an XLA CHECK (process abort). Full-manual meshes
-# (seq-only) are unaffected. See docs/parity.md shard_map drift triage.
-_OLD_PARTIAL_MANUAL = not hasattr(jax, "shard_map")
-_partial_manual_xfail = pytest.mark.xfail(
-    _OLD_PARTIAL_MANUAL,
-    reason="jax 0.4.x partial-manual shard_map cannot lower ppermute on "
-           "mixed meshes (UNIMPLEMENTED PartitionId)",
-    strict=False,
-)
-
-
 def qkv(b=2, s=64, h=4, d=8, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     shape = (b, s, h, d)
     return tuple(jax.random.normal(k, shape, jnp.float32) for k in ks)
 
 
-@pytest.mark.skipif(
-    _OLD_PARTIAL_MANUAL,
-    reason="jax 0.4.x partial-manual shard_map ABORTS the process (XLA "
-           "CHECK, not a Python error) on the data×seq mesh — must skip, "
-           "an xfail would still crash the run")
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("impl", [ring_attention, ulysses_attention])
 def test_seq_parallel_matches_reference_forward(causal, impl):
@@ -75,7 +56,6 @@ def test_seq_parallel_matches_reference_grads(causal, impl):
         )
 
 
-@_partial_manual_xfail
 def test_ring_with_sharded_inputs():
     """Inputs already sharded batch×seq stay consistent (GSPMD composition)."""
     mesh = make_mesh((2, 4), ("data", "seq"))
@@ -110,7 +90,6 @@ def test_ring_nondivisible_seq_raises():
         ring_attention(q, k, v, mesh=mesh)
 
 
-@_partial_manual_xfail
 def test_transformer_ring_impl_end_to_end():
     """Flagship model trains a step with ring attention over a seq axis."""
     from autodist_tpu.api import AutoDist

@@ -223,3 +223,28 @@ def test_even_multi_node_and_single_node_unaffected():
     ResourceSpec(resource_dict={"nodes": [
         {"address": "localhost", "chips": 3, "chief": True},
     ]})  # single node: any count is trivially homogeneous
+
+
+def test_unknown_live_tpu_device_kind_raises(monkeypatch):
+    """A hand-written spec naming an unlisted accelerator gets the
+    conservative default; a chip that is actually here does not — its kind
+    came from the runtime, so planning against a guessed capacity for it
+    would be silently wrong."""
+    import jax
+
+    from autodist_tpu.resource_spec import DEFAULT_HBM, hbm_spec_for_kind
+
+    assert hbm_spec_for_kind("TPU v9 hypothetical") == DEFAULT_HBM
+
+    class _Dev:
+        platform = "tpu"
+        device_kind = "TPU v9 hypothetical"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Dev()])
+    with pytest.raises(ValueError, match="does not list"):
+        ResourceSpec.from_local_devices()
+
+    _Dev.device_kind = "TPU v5 lite"
+    rs = ResourceSpec.from_local_devices()
+    assert rs.tpu.accelerator == "TPU v5 lite"
+    assert rs.tpu.hbm_bytes == 16e9

@@ -4,12 +4,10 @@
 Runs in CI's lint job (``.github/workflows/ci.yml``) before any test tier;
 exits 1 listing ``file:line`` offenders. Rules:
 
-1. **shard_map drift shield** — ``jax.experimental.shard_map`` may be
-   imported ONLY inside ``autodist_tpu/utils/compat.py``: every other call
-   site must go through the compat shim, which maps the new
-   ``jax.shard_map`` surface onto 0.4.x's experimental one (docs/parity.md
-   drift triage). A bare import reintroduces exactly the toolchain-drift
-   class PR 4 spent 15 test failures fixing.
+1. **one spelling of shard_map** — ``jax.experimental.shard_map`` (the
+   pre-0.6 home of the API, with the complementary ``auto=`` / ``check_rep``
+   spelling) is imported nowhere: every call site goes through
+   ``autodist_tpu.utils.compat.shard_map``, which calls ``jax.shard_map``.
 
 2. **no wall-clock in timed bench windows** — ``time.time()`` is banned in
    ``bench.py`` and ``examples/benchmark/``: it steps with NTP/suspend, so
@@ -190,26 +188,16 @@ def _py_files(*roots):
 def main() -> int:
     errors = []
 
-    shard_map_allowed = {os.path.join("autodist_tpu", "utils", "compat.py")}
     for rel in _py_files("autodist_tpu", "tests", "examples", "bench.py"):
-        if rel in shard_map_allowed:
-            continue
         with open(os.path.join(REPO, rel), "r", encoding="utf-8") as f:
             for i, line in enumerate(f, 1):
                 code = line.split("#", 1)[0]
                 if SHARD_MAP_RE.search(code):
                     errors.append(
                         f"{rel}:{i}: bare jax.experimental.shard_map import"
-                        f" — use autodist_tpu.utils.compat.shard_map (the "
-                        f"version shim; docs/parity.md)")
+                        f" — use autodist_tpu.utils.compat.shard_map")
 
-    # The queue DRIVER (run_tpu_queue.py) legitimately uses wall-clock for
-    # subprocess deadlines/grace periods — the rule targets measurement
-    # windows, not timeouts.
-    time_exempt = {os.path.join("examples", "benchmark", "run_tpu_queue.py")}
     for rel in _py_files("bench.py", os.path.join("examples", "benchmark")):
-        if rel in time_exempt:
-            continue
         with open(os.path.join(REPO, rel), "r", encoding="utf-8") as f:
             for i, line in enumerate(f, 1):
                 code = line.split("#", 1)[0]
