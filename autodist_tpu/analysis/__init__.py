@@ -43,6 +43,7 @@ from autodist_tpu.analysis.inventory import (
     collective_sizes,
     compiled_artifacts,
     compiled_hlo,
+    compiled_text,
     compiled_window,
     hlo_contains,
 )
@@ -201,6 +202,7 @@ __all__ = [
     "collective_sizes",
     "compiled_artifacts",
     "compiled_hlo",
+    "compiled_text",
     "compiled_window",
     "degradation_check",
     "hbm_budget",

@@ -220,6 +220,14 @@ def compiled_window(step, state, batch, num_steps: int,
     return out
 
 
+def compiled_text(jitted, *args) -> str:
+    """Post-optimization HLO text of any jitted callable (a serving
+    program, a kernel) for ``args`` — arrays or ``ShapeDtypeStruct``s;
+    nothing executes. Its first line names the module as a device trace
+    does (``HloModule jit_<function name>``)."""
+    return jitted.lower(*args).compile().as_text()
+
+
 def _expand_iota_groups(num_groups: int, group_size: int,
                         dims: Tuple[int, ...],
                         perm: Optional[Tuple[int, ...]]) -> Tuple[Tuple[int, ...], ...]:

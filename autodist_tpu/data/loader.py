@@ -32,6 +32,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional
 import numpy as np
 
 from autodist_tpu.data import _build
+from autodist_tpu.obs import spans as obs_spans
 from autodist_tpu.utils import logging
 
 
@@ -203,12 +204,25 @@ class DataLoader:
         the device put, so this bypasses the per-batch ``_shard`` path.
         The multi-host ragged-tail contract is the same as ``__iter__``'s:
         a final batch that can't assemble into a global array fails here,
-        loudly, not deep inside window assembly.
+        loudly, not deep inside window assembly. The production of each
+        batch (the wait on the native ring, the copy out, the transform)
+        is one ``input.next`` span.
         """
         self._check_multihost_remainder()
-        return self._with_transform(
+        return self._spanned(self._with_transform(
             self._iter_native() if self.engine == "native" else self._iter_python()
-        )
+        ))
+
+    @staticmethod
+    def _spanned(it) -> Iterator[Dict[str, np.ndarray]]:
+        it = iter(it)
+        while True:
+            with obs_spans.span("input.next"):
+                try:
+                    batch = next(it)
+                except StopIteration:
+                    return
+            yield batch
 
     @classmethod
     def from_files(cls, data_dir: str, batch_size: int,

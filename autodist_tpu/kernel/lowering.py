@@ -51,6 +51,7 @@ from autodist_tpu.chaos import hooks as chaos_hooks
 from autodist_tpu.kernel import bucketing
 from autodist_tpu.kernel.mesh import data_axis
 from autodist_tpu.obs import recorder as flight
+from autodist_tpu.obs import spans as obs_spans
 from autodist_tpu.model_item import ModelItem, VarItem, _path_to_name
 from autodist_tpu.strategy.ir import (
     AllReduceSynchronizer,
@@ -877,8 +878,13 @@ class ShardingPlan:
 
         Window leaves are batched by construction, so no broadcast-leaf
         ambiguity exists: dim 1 (after the step axis) always concatenates
-        across processes.
+        across processes. The host's part of the transfer is one
+        ``input.stage`` span.
         """
+        with obs_spans.span("input.stage"):
+            return self._window_from_local(stacked_local)
+
+    def _window_from_local(self, stacked_local) -> Any:
         if jax.process_count() == 1:
             return jax.device_put(
                 stacked_local, self.window_shardings(stacked_local))
@@ -1702,8 +1708,11 @@ class DistributedTrainStep:
             self._state_shardings = self.plan.state_shardings(jax.eval_shape(lambda: state))
         in_shardings = (self._state_shardings, self.plan.batch_shardings(batch))
         out_shardings = (self._state_shardings, None)
+        def train_step(st, b):       # the trace's module: jit_train_step
+            return self._step(st, b)
+
         self._compiled = jax.jit(
-            self._step,
+            train_step,
             in_shardings=in_shardings,
             out_shardings=out_shardings,
             donate_argnums=(0,) if self._donate else (),
@@ -1752,12 +1761,15 @@ class DistributedTrainStep:
             fn = self._window_program(state, batch, num_steps, stacked,
                                       _force_unroll)
             batch = self._chaos_batch(batch, num_steps, stacked)
-            if fresh:
-                # The first call of a fresh program compiles synchronously
-                # before dispatching; its latency is the compile-time signal
-                # the obs StepProfiler reports.
-                t0 = time.perf_counter()
+            # The host's part of one window: the (asynchronous) dispatch,
+            # and for a fresh program the synchronous compile before it,
+            # whose latency is the compile-time signal the obs
+            # StepProfiler reports.
+            t0 = time.perf_counter()
+            with obs_spans.span("train.window_dispatch", program=program,
+                                fresh=fresh):
                 out = fn(state, batch)
+            if fresh:
                 entry = {
                     "program": program,
                     "first_call_s": time.perf_counter() - t0,
@@ -1767,8 +1779,6 @@ class DistributedTrainStep:
                 # run that dies mid-compile leaves "compiling X" as its
                 # last event — exactly what the postmortem doctor needs.
                 flight.record_event("compile", critical=False, **entry)
-            else:
-                out = fn(state, batch)
             return self._chaos_metrics(out, num_steps)
         except Exception as e:
             # Black-box the failure before re-raising: an XLA OOM
@@ -1804,7 +1814,8 @@ class DistributedTrainStep:
             if stacked:
                 batch_sh = self.plan.window_shardings(batch)
 
-                def multi(st, bs):
+                # Named for the trace's module line: jit_train_window.
+                def train_window(st, bs):
                     if unroll:
                         return unrolled(st, lambda i: jax.tree.map(
                             lambda x: x[i], bs))
@@ -1813,13 +1824,13 @@ class DistributedTrainStep:
             else:
                 batch_sh = self.plan.batch_shardings(batch)
 
-                def multi(st, b):
+                def train_window(st, b):
                     if unroll:
                         return unrolled(st, lambda i: b)
                     return lax.scan(lambda s, _: self._step(s, b), st, None,
                                     length=num_steps)
             fn = jax.jit(
-                multi,
+                train_window,
                 in_shardings=(self._state_shardings, batch_sh),
                 out_shardings=(self._state_shardings, None),
                 donate_argnums=(0,) if self._donate else (),
@@ -2094,7 +2105,7 @@ class DistributedTrainStep:
             else:
                 host_sh = dev_sh = None
 
-            def eval_fn(params, b):
+            def eval_step(params, b):    # the trace's module: jit_eval_step
                 if host_sh is not None:
                     params = _stream(params, host_sh, dev_sh)
                 out = self.loss_fn(params, b)
@@ -2104,7 +2115,7 @@ class DistributedTrainStep:
                 return {"loss": out}
 
             fn = jax.jit(
-                eval_fn,
+                eval_step,
                 in_shardings=(self._state_shardings.params,
                               self.plan.batch_shardings(batch, strict=False)),
                 out_shardings=None,
@@ -2191,13 +2202,13 @@ class DistributedTrainStep:
         fresh = self._compiled is None
         fn = self._compiled or self._compile(state, batch)
         batch = self._chaos_batch(batch, num_steps=1, stacked=False)
-        if fresh:
-            t0 = time.perf_counter()
+        t0 = time.perf_counter()
+        with obs_spans.span("train.window_dispatch", program="step",
+                            fresh=fresh):
             out = fn(state, batch)
+        if fresh:
             self.compile_log.append(
                 {"program": "step", "first_call_s": time.perf_counter() - t0})
-        else:
-            out = fn(state, batch)
         return self._chaos_metrics(out, num_steps=1)
 
     def lower_text(self, state: TrainState, batch) -> str:

@@ -17,6 +17,17 @@ Mechanics:
   batcher after the fact). Timestamps are wall-clock (``time.time`` —
   the only clock comparable across processes on one host fleet to span
   precision); durations come from ``time.perf_counter`` deltas.
+- Every span has an ``id`` and a ``parent``: the id of the span that was
+  open on the same thread when it was opened (None at the top), kept on a
+  thread-local stack. :func:`self_time` takes a layer's own time from
+  that tree: a span's duration minus the part its children cover.
+- A span opened with :meth:`SpanTracer.span` also lies on the device
+  trace's clock: while ``jax`` is already imported it holds a
+  ``jax.profiler.TraceAnnotation`` of the same name open for its life, so
+  under a running ``jax.profiler`` trace the span shows on the host plane
+  beside the device's operations. This module is the one place of the
+  package that opens such a region. With no profile running the
+  annotation is one flag test.
 - The **trace id** rides the ``AUTODIST_TRACE_ID`` env var: the launcher
   generates one and exports it to every process it starts
   (``runtime/launcher.py``), so launcher → coordinator → worker spans all
@@ -29,16 +40,18 @@ Mechanics:
   into the shared dir at exit; :func:`stitch` merges the parts into ONE
   ``trace-<id>.json`` (the launcher calls it after the fleet exits).
 
-The tracer is dependency-free (no jax import): the launcher — which never
-initializes a backend — traces through the same module.
+The tracer never imports jax (it only uses one that is already in
+``sys.modules``): the launcher — which never initializes a backend —
+traces through the same module.
 """
 from __future__ import annotations
 
 import atexit
-import contextlib
 import functools
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -57,12 +70,15 @@ __all__ = [
     "events_for_request",
     "export",
     "get_tracer",
+    "self_time",
     "span",
     "stitch",
     "traced",
 ]
 
 _PART_PREFIX = "obs-part-"
+_span_ids = itertools.count(1)
+_open = threading.local()        # .stack: ids of the spans open on this thread
 
 
 def current_trace_id() -> str:
@@ -91,6 +107,8 @@ class Span:
     os_pid: int
     tid: int
     attrs: Dict[str, Any] = field(default_factory=dict)
+    id: int = 0                      # unique in this process
+    parent: Optional[int] = None     # id of the enclosing span, same thread
 
     def to_event(self) -> Dict[str, Any]:
         """Chrome-trace "X" (complete) event, microsecond units.
@@ -106,8 +124,46 @@ class Span:
             "pid": self.os_pid,
             "tid": self.tid,
             "args": {**self.attrs, "trace_id": self.trace_id,
-                     "process": self.process},
+                     "process": self.process, "id": self.id,
+                     "parent": self.parent},
         }
+
+
+class _OpenSpan:
+    """The context manager behind :meth:`SpanTracer.span` (a class, not a
+    generator: a serving tick opens ten of them)."""
+
+    __slots__ = ("tracer", "name", "attrs", "id", "parent", "region",
+                 "t_wall", "t0")
+
+    def __init__(self, tracer: "SpanTracer", name: str, attrs: Dict[str, Any]):
+        self.tracer, self.name, self.attrs = tracer, name, attrs
+
+    def __enter__(self) -> Dict[str, Any]:
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        self.id = next(_span_ids)
+        self.parent = stack[-1] if stack else None
+        stack.append(self.id)
+        jax = sys.modules.get("jax")
+        self.region = None
+        if jax is not None:
+            self.region = jax.profiler.TraceAnnotation(self.name)
+            self.region.__enter__()
+        self.t_wall = time.time()
+        self.t0 = time.perf_counter()
+        return self.attrs
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        dur = time.perf_counter() - self.t0
+        if self.region is not None:
+            self.region.__exit__(None, None, None)
+        _open.stack.pop()
+        if exc_type is not None:
+            self.attrs["error"] = True
+        self.tracer._add(self.name, self.t_wall, dur, self.id, self.parent,
+                         self.attrs)
 
 
 class SpanTracer:
@@ -136,19 +192,14 @@ class SpanTracer:
         return self._process
 
     # ------------------------------------------------------------- recording
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs):
+    def span(self, name: str, **attrs) -> "_OpenSpan":
         """``with tracer.span("phase", key=val): ...`` — monotonic-clocked,
-        recorded on exit (exceptions mark the span ``error: true``)."""
-        t_wall = time.time()
-        t0 = time.perf_counter()
-        try:
-            yield self
-        except BaseException:
-            attrs = {**attrs, "error": True}
-            raise
-        finally:
-            self.add_span(name, t_wall, time.perf_counter() - t0, **attrs)
+        recorded on exit (exceptions mark the span ``error: true``). The
+        span's parent is the span open on this thread; under a running
+        ``jax.profiler`` trace the span also shows on the host plane.
+        Yields the span's attributes, for one known only at the end:
+        ``with span("tick") as attrs: attrs["progressed"] = work()``."""
+        return _OpenSpan(self, name, attrs)
 
     def traced(self, name: Optional[str] = None):
         """Decorator form of :meth:`span` (span named after the function)."""
@@ -166,14 +217,19 @@ class SpanTracer:
         return deco
 
     def add_span(self, name: str, t_start_s: float, dur_s: float,
-                 **attrs) -> Span:
+                 parent: Optional[int] = None, **attrs) -> Span:
         """Record a span measured elsewhere (retroactive — e.g. queue wait
-        computed at admission time). ``t_start_s`` is wall-clock seconds."""
+        computed at admission time). ``t_start_s`` is wall-clock seconds;
+        ``parent`` is the id of the span that caused it, if any."""
+        return self._add(name, t_start_s, dur_s, next(_span_ids), parent,
+                         attrs)
+
+    def _add(self, name, t_start_s, dur_s, span_id, parent, attrs) -> Span:
         sp = Span(
             name=name, t_start_s=float(t_start_s), dur_s=float(dur_s),
             trace_id=self.trace_id, process=self.process,
             os_pid=os.getpid(), tid=threading.get_ident() % 1_000_000,
-            attrs=attrs,
+            attrs=attrs, id=span_id, parent=parent,
         )
         with self._lock:
             if len(self._spans) == self._spans.maxlen:
@@ -283,8 +339,10 @@ def traced(name: Optional[str] = None):
     return get_tracer().traced(name)
 
 
-def add_span(name: str, t_start_s: float, dur_s: float, **attrs) -> Span:
-    return get_tracer().add_span(name, t_start_s, dur_s, **attrs)
+def add_span(name: str, t_start_s: float, dur_s: float,
+             parent: Optional[int] = None, **attrs) -> Span:
+    return get_tracer().add_span(name, t_start_s, dur_s, parent=parent,
+                                 **attrs)
 
 
 def export(path: str) -> str:
@@ -313,6 +371,28 @@ def events_for_request(trace: Dict[str, Any], request_id: str,
                 and request_id in args["request_ids"]):
             out.append(ev)
     out.sort(key=lambda e: float(e.get("ts", 0.0)))
+    return out
+
+
+def self_time(spans: List[Span]) -> Dict[int, float]:
+    """``{span id: seconds}``: each span's duration minus the part of its
+    interval that its children (the spans naming it as ``parent``) cover.
+    Children that overlap each other are counted once; a child that
+    reaches past its parent (a retroactive span) is cut to it."""
+    children: Dict[int, List[Span]] = {}
+    for sp in spans:
+        if sp.parent is not None:
+            children.setdefault(sp.parent, []).append(sp)
+    out = {}
+    for sp in spans:
+        lo, hi = sp.t_start_s, sp.t_start_s + sp.dur_s
+        covered, end = 0.0, lo
+        for ch in sorted(children.get(sp.id, ()), key=lambda c: c.t_start_s):
+            s, e = max(ch.t_start_s, end), min(ch.t_start_s + ch.dur_s, hi)
+            if e > s:
+                covered += e - s
+                end = e
+        out[sp.id] = max(sp.dur_s - covered, 0.0)
     return out
 
 

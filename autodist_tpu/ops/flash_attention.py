@@ -251,6 +251,7 @@ def _pallas_forward(q3, k3, v3, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, seq_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q3, k3, v3)
     return out, lse
 
@@ -343,6 +344,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
             jax.ShapeDtypeStruct((bh, seq, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q3, k3, v3, do3, lse, delta)
 
     dq3 = pl.pallas_call(
@@ -359,6 +361,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
         out_specs=pl.BlockSpec((1, bq, d), lambda b_, i: (b_, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q3, k3, v3, do3, lse, delta)
 
     return (

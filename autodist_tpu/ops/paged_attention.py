@@ -256,6 +256,7 @@ def _kernel_attention(q4, k_pages, v_pages, page_tables, q_positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_q, h, d), q4.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(tables, *operands)
 
 

@@ -30,10 +30,11 @@ Metrics (through :mod:`autodist_tpu.metrics`' registry):
 ``serve_queue_depth`` / ``serve_active_slots`` /
 ``serve_page_pool_utilization`` / ``serve_page_fragmentation`` gauges,
 ``serve_requests_{submitted,completed,timeout,rejected}_total`` counters,
-``serve_tokens_generated_total`` counter, ``serve_tokens_per_sec`` and
-``serve_decode_tokens_per_sec`` gauges (rolling), and
-``serve_request_latency_s`` / ``serve_ttft_s`` histograms (p50/p99
-exported by the registry). Engines exposing ``spec_stats()``
+``serve_tokens_generated_total`` / ``serve_decode_tokens_generated_total``
+counters (a rate is their increase over the reader's own interval), and
+``serve_request_latency_s`` / ``serve_ttft_s`` / ``serve_itl_s`` histograms
+(p50/p99 exported by the registry; ``serve_itl_s`` observes every
+inter-token gap, from ``GenRequest.t_tokens``). Engines exposing ``spec_stats()``
 (speculative decode, serve/spec.py) additionally publish
 ``serve_spec_acceptance_rate`` / ``serve_spec_tokens_per_step`` and feed
 the SLO tracker's rolling acceptance window; decode rounds then emit
@@ -113,6 +114,10 @@ class GenRequest:
     # the identical draws from (request_id, seed, position) alone.
     sampling: Optional[serve_sampling.SamplingParams] = None
     tokens: List[int] = field(default_factory=list)
+    # time.monotonic() at which each entry of ``tokens`` was appended by
+    # the scheduler (tokens of one spec round share a tick and lie
+    # microseconds apart); the inter-token gaps are its differences.
+    t_tokens: List[float] = field(default_factory=list)
     state: RequestState = RequestState.QUEUED
     error: str = ""
     # Typed rejection cause: True when the request can NEVER be served by
@@ -161,7 +166,8 @@ class GenRequest:
     @property
     def itl_s(self) -> Optional[float]:
         """Mean inter-token latency over the decode phase (needs a
-        terminal request with >= 2 tokens)."""
+        terminal request with >= 2 tokens). The single gaps are the
+        differences of ``t_tokens``; ``serve_itl_s`` observes each."""
         if (self.t_done is None or self.t_first_token is None
                 or len(self.tokens) < 2):
             return None
@@ -260,8 +266,6 @@ class ContinuousBatcher:
         self._stopped = False
         self._draining = False  # quiesced: no new admissions, finish active
         self._thread: Optional[threading.Thread] = None
-        self._tick_tokens: deque = deque(maxlen=64)   # (t, n) for tokens/sec
-        self._decode_tokens: deque = deque(maxlen=64)  # decode-only window
         self._shed_lock = threading.Lock()
         self._shed_last = -1e9   # monotonic stamp of the last shed
         self._shed_count = 0
@@ -310,8 +314,8 @@ class ContinuousBatcher:
         self._m_timeout = reg.counter("serve_requests_timeout_total")
         self._m_rejected = reg.counter("serve_requests_rejected_total")
         self._m_tokens = reg.counter("serve_tokens_generated_total")
-        self._m_tps = reg.gauge("serve_tokens_per_sec")
-        self._m_decode_tps = reg.gauge("serve_decode_tokens_per_sec")
+        self._m_decode_tokens = reg.counter(
+            "serve_decode_tokens_generated_total")
         self._m_latency = reg.histogram("serve_request_latency_s")
         self._m_ttft = reg.histogram("serve_ttft_s")
         self._m_itl = reg.histogram("serve_itl_s")
@@ -636,7 +640,8 @@ class ContinuousBatcher:
                     continue
             try:
                 t_tick = time.monotonic()
-                progressed = self._tick()
+                with obs_spans.span("serve.tick", seq=self._tick_seq) as tick:
+                    progressed = tick["progressed"] = self._tick()
                 if progressed:
                     self._tick_seq += 1
                     if self._tick_seq % 32 == 1:
@@ -657,11 +662,12 @@ class ContinuousBatcher:
                                 self.engine, "page_utilization", 0.0)), 4),
                             queue_depth=len(self._queue))
                 if progressed and self.on_tick is not None:
-                    try:
-                        self.on_tick(time.monotonic() - t_tick)
-                    except Exception:  # noqa: BLE001 - observer only
-                        logging.warning("on_tick observer raised",
-                                        exc_info=True)
+                    with obs_spans.span("serve.on_tick"):
+                        try:
+                            self.on_tick(time.monotonic() - t_tick)
+                        except Exception:  # noqa: BLE001 - observer only
+                            logging.warning("on_tick observer raised",
+                                            exc_info=True)
                 if not progressed:
                     # Queue non-empty but nothing progressed (a page-
                     # pressure window with an empty active set, or a
@@ -739,6 +745,26 @@ class ContinuousBatcher:
         # happens chunk-by-chunk below), and runs OUTSIDE self._lock: only
         # this scheduler thread ever pops, so the peeked head is stable,
         # and submit()/the asyncio event loop never block on it.
+        if self._queue:
+            with obs_spans.span("serve.admit"):
+                progress = self._admit_queued() or progress
+
+        progress = self._prefill_round() or progress
+        progress = self._decode_round() or progress
+        with obs_spans.span("serve.tick_metrics"):
+            self._update_spec_metrics()
+            self._update_prefix_metrics()
+            self._update_quant_metrics()
+            with self._lock:
+                self._m_active.set(len(self._active))
+            self._m_pool_util.set(self.engine.page_utilization)
+            self._m_frag.set(self.engine.page_fragmentation)
+        return progress
+
+    def _admit_queued(self) -> bool:
+        """The admission loop of one tick (host-only reservation); returns
+        whether anything was admitted, rejected or timed out."""
+        progress = False
         while True:
             dead = None
             with self._lock:
@@ -791,11 +817,14 @@ class ContinuousBatcher:
                 head.state = RequestState.ACTIVE
                 self._active[admitted] = head
             progress = True
+        return progress
 
-        # Chunked prefill: every mid-prefill slot advances ONE chunk per
-        # tick, so a long prompt interleaves with (never stalls) the
-        # decode step below. The first generated token arrives with the
-        # final chunk — prefill emits it, exactly like the unpaged design.
+    def _prefill_round(self) -> bool:
+        """Chunked prefill: every mid-prefill slot advances ONE chunk per
+        tick, so a long prompt interleaves with (never stalls) the decode
+        round. The first generated token arrives with the final chunk —
+        prefill emits it, exactly like the unpaged design."""
+        progress = False
         for slot in self.engine.prefill_pending():
             with self._lock:
                 req = self._active.get(slot)
@@ -810,47 +839,54 @@ class ContinuousBatcher:
             progress = True
             if first is None:
                 continue
-            req.t_first_token = time.monotonic()
-            req.tokens.append(first)
-            # cached flag is read BEFORE release resets the slot arrays;
-            # it rides the request for the retire-time flight record/SLO.
-            slot_cached = getattr(self.engine, "slot_cached", None)
-            req.cached = (bool(slot_cached(slot))
-                          if callable(slot_cached) else False)
-            ttft = req.ttft_s
-            self._m_ttft.observe(ttft)
-            if getattr(self.engine, "prefix_cache", None) is not None:
-                if self._m_ttft_cached is None:
-                    self._m_ttft_cached = self._registry.histogram(
-                        "serve_ttft_cached_s")
-                    self._m_ttft_uncached = self._registry.histogram(
-                        "serve_ttft_uncached_s")
-                (self._m_ttft_cached if req.cached
-                 else self._m_ttft_uncached).observe(ttft)
-            self._count_tokens(1)
-            self._maybe_retire(slot, req)
+            with obs_spans.span("serve.emit"):
+                req.t_first_token = time.monotonic()
+                self._append_token(req, first, req.t_first_token)
+                # cached flag is read BEFORE release resets the slot
+                # arrays; it rides the request for the retire-time flight
+                # record/SLO.
+                slot_cached = getattr(self.engine, "slot_cached", None)
+                req.cached = (bool(slot_cached(slot))
+                              if callable(slot_cached) else False)
+                ttft = req.ttft_s
+                self._m_ttft.observe(ttft)
+                if getattr(self.engine, "prefix_cache", None) is not None:
+                    if self._m_ttft_cached is None:
+                        self._m_ttft_cached = self._registry.histogram(
+                            "serve_ttft_cached_s")
+                        self._m_ttft_uncached = self._registry.histogram(
+                            "serve_ttft_uncached_s")
+                    (self._m_ttft_cached if req.cached
+                     else self._m_ttft_uncached).observe(ttft)
+                self._m_tokens.inc(1)
+                self._maybe_retire(slot, req)
+        return progress
 
-        # One decode round over every decoding slot (ONE compiled program
-        # — plain greedy emits one token per slot; a speculative round
-        # emits 1..k+1 greedy-identical tokens per slot). Tokens are
-        # appended one at a time so EOS / max_new_tokens / deadline
-        # truncate a multi-token burst at exactly the token plain decode
-        # would have stopped on — the engine's overshoot is discarded
-        # with the retiring slot.
+    def _decode_round(self) -> bool:
+        """One decode round over every decoding slot (ONE compiled program
+        — plain greedy emits one token per slot; a speculative round
+        emits 1..k+1 greedy-identical tokens per slot). Tokens are
+        appended one at a time so EOS / max_new_tokens / deadline
+        truncate a multi-token burst at exactly the token plain decode
+        would have stopped on — the engine's overshoot is discarded
+        with the retiring slot."""
         with self._lock:
-            have_active = bool(self._active)
-        if have_active:
-            emitted = self.engine.step_many()
-            progress = progress or bool(emitted)
+            if not self._active:
+                return False
+        emitted = self.engine.step_many()
+        if not emitted:
+            return False
+        with obs_spans.span("serve.emit"):
             n_appended = 0
+            eos = self.engine.decode_model.eos_id
             for slot, tokens in emitted.items():
                 with self._lock:
                     req = self._active.get(slot)
                 if req is None:
                     continue
-                eos = self.engine.decode_model.eos_id
                 for token in tokens:
-                    req.tokens.append(token)
+                    now = time.monotonic()
+                    self._append_token(req, token, now)
                     n_appended += 1
                     if (len(req.tokens) >= req.max_new_tokens
                             or (eos is not None and token == eos)):
@@ -860,19 +896,21 @@ class ContinuousBatcher:
                     # then the request times out — the burst's remaining
                     # tokens are exactly the ones plain decode would
                     # never have computed.
-                    if (req.deadline is not None
-                            and time.monotonic() > req.deadline):
+                    if req.deadline is not None and now > req.deadline:
                         break
                 self._maybe_retire(slot, req)
-            self._count_tokens(n_appended, decode=True)
-        self._update_spec_metrics()
-        self._update_prefix_metrics()
-        self._update_quant_metrics()
-        with self._lock:
-            self._m_active.set(len(self._active))
-        self._m_pool_util.set(self.engine.page_utilization)
-        self._m_frag.set(self.engine.page_fragmentation)
-        return progress
+            self._m_tokens.inc(n_appended)
+            self._m_decode_tokens.inc(n_appended)
+        return True
+
+    def _append_token(self, req: GenRequest, token: int, now: float) -> None:
+        """Deliver one token: stamp its time on the request and observe
+        the gap since the request's previous token (``serve_itl_s`` holds
+        every inter-token gap, so one stalled tick shows in its tail)."""
+        if req.t_tokens:
+            self._m_itl.observe(now - req.t_tokens[-1])
+        req.tokens.append(token)
+        req.t_tokens.append(now)
 
     def _update_spec_metrics(self) -> None:
         """Publish speculative-decode gauges + feed the SLO tracker's
@@ -995,8 +1033,6 @@ class ContinuousBatcher:
         req._finish(state, why)
         self._m_latency.observe(time.monotonic() - req.t_submit)
         itl = req.itl_s
-        if itl is not None:
-            self._m_itl.observe(itl)
         # One request-level flight record: the SLO inputs (TTFT, ITL,
         # queue wait, outcome) survive the process — obs/slo.py's
         # replay_flight_records recomputes the SLO position postmortem.
@@ -1019,20 +1055,3 @@ class ContinuousBatcher:
                              cached=req.cached, temperature=temp)
         with self._wake:
             self._wake.notify()  # pages freed: admission may proceed
-
-    def _count_tokens(self, n: int, decode: bool = False) -> None:
-        self._m_tokens.inc(n)
-        now = time.monotonic()
-        self._tick_tokens.append((now, n))
-        window = [(t, k) for t, k in self._tick_tokens if now - t <= 5.0]
-        if len(window) >= 2:
-            dt = now - window[0][0]
-            if dt > 0:
-                self._m_tps.set(sum(k for _, k in window) / dt)
-        if decode:
-            self._decode_tokens.append((now, n))
-            dwin = [(t, k) for t, k in self._decode_tokens if now - t <= 5.0]
-            if len(dwin) >= 2:
-                dt = now - dwin[0][0]
-                if dt > 0:
-                    self._m_decode_tps.set(sum(k for _, k in dwin) / dt)

@@ -295,30 +295,36 @@ class SpecDecodeEngine(InferenceEngine):
         token_sh = NamedSharding(self.mesh, P())
         # One target verify program: donate-through the target cache with
         # its output sharding pinned to the canonical pool sharding, the
-        # same drift-proofing the plain decode/prefill programs keep.
-        self._verify_fn = jax.jit(
-            lambda p, toks, pos, cache, tables, samp: dm.verify_paged(
+        # same drift-proofing the plain decode/prefill programs keep. Named
+        # functions, so a device trace reads jit_serve_spec_verify etc.
+        def serve_spec_verify(p, toks, pos, cache, tables, samp):
+            return dm.verify_paged(
                 self.plan.unpad_params(p), toks, pos, cache, tables,
-                samp=samp),
-            donate_argnums=(3,),
-            out_shardings=(token_sh, token_sh, self._cache_sh))
-        self._draft_prefill_fn = jax.jit(
-            lambda p, tokens, start, length, cache, table: ddm.prefill_chunk(
+                samp=samp)
+
+        def serve_draft_prefill(p, tokens, start, length, cache, table):
+            return ddm.prefill_chunk(
                 self.draft_plan.unpad_params(p), tokens, start, length,
-                cache, table),
-            donate_argnums=(4,),
-            out_shardings=(token_sh, self._draft_cache_sh))
+                cache, table)
+
         # The draft decode takes the SAME per-slot sampling arrays as the
         # target: proposing with the target's (request key, position)
         # Gumbel noise over its own distribution is the coupling that
         # keeps stochastic spec decode lossless AND high-acceptance
         # (serve/sampling.py — when draft == target the draws coincide).
-        self._draft_decode_fn = jax.jit(
-            lambda p, tokens, positions, cache, tables, samp:
-            ddm.decode_paged(
+        def serve_draft_decode(p, tokens, positions, cache, tables, samp):
+            return ddm.decode_paged(
                 self.draft_plan.unpad_params(p), tokens, positions, cache,
-                tables, samp=samp),
-            donate_argnums=(3,),
+                tables, samp=samp)
+
+        self._verify_fn = jax.jit(
+            serve_spec_verify, donate_argnums=(3,),
+            out_shardings=(token_sh, token_sh, self._cache_sh))
+        self._draft_prefill_fn = jax.jit(
+            serve_draft_prefill, donate_argnums=(4,),
+            out_shardings=(token_sh, self._draft_cache_sh))
+        self._draft_decode_fn = jax.jit(
+            serve_draft_decode, donate_argnums=(3,),
             out_shardings=(token_sh, self._draft_cache_sh))
 
     @property

@@ -5,10 +5,10 @@
    model, the reference citations that used to live here, and the export
    formats). This module keeps two things:
 
-   - the ``jax.profiler`` device-timeline wrappers (:func:`trace`,
-     :func:`annotate`) and the per-compile HLO dumps (:func:`dump_hlo`,
-     :func:`dump_compiled`) — xplane/TensorBoard tooling, distinct from
-     the host-side span tracer in ``obs.spans``;
+   - the ``jax.profiler`` device-timeline wrapper (:func:`trace`) and the
+     per-compile HLO dumps (:func:`dump_hlo`, :func:`dump_compiled`) —
+     xplane/TensorBoard tooling. A named region inside a trace is a span
+     of ``obs.spans``, which lies on the profiler's clock;
    - a **compat shim** for :class:`StepTimer`, which moved to
      :mod:`autodist_tpu.obs.profiler` — import it from there in new code.
 """
@@ -54,13 +54,6 @@ def trace(name: str = "trace", trace_dir: Optional[str] = None):
     logging.info("profiler trace -> %s", trace_dir)
     with jax.profiler.trace(trace_dir):
         yield trace_dir
-
-
-def annotate(name: str):
-    """Named region inside a trace (`jax.profiler.TraceAnnotation`)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 # ------------------------------------------------------------------ HLO dump

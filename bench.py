@@ -459,6 +459,7 @@ def _serve_decode_bench(n_requests: int = 48, max_new: int = 10,
     tokens = sum(len(r.tokens) for r in results)
     completed = sum(1 for r in results if r.state is RequestState.DONE)
     snap = registry.snapshot()
+    decode_tokens = float(snap.get("serve_decode_tokens_generated_total", 0.0))
     lat = snap.get("serve_request_latency_s", {})
     ttft = snap.get("serve_ttft_s", {})
     itl = snap.get("serve_itl_s", {})
@@ -468,8 +469,7 @@ def _serve_decode_bench(n_requests: int = 48, max_new: int = 10,
     hit_rate = snap.get("serve_prefix_hit_rate", float("nan"))
     slo_report = slo.report()
     return {"bench_serve": {
-        "decode_tokens_per_sec": round(
-            float(snap.get("serve_decode_tokens_per_sec", 0.0)), 1),
+        "decode_tokens_per_sec": round(decode_tokens / dt, 1) if dt > 0 else None,
         "tokens_per_sec": round(tokens / dt, 1) if dt > 0 else None,
         "p50_latency_s": round(lat.get("p50", float("nan")), 4),
         "p99_latency_s": round(lat.get("p99", float("nan")), 4),

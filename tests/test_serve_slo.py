@@ -830,29 +830,45 @@ def test_batcher_emits_itl_queue_wait_and_request_records(tmp_path):
 
 
 def test_recorder_overhead_guard_with_serve_records(tmp_path):
-    """The <1%/step recorder bar re-asserted with the serve record mix on
-    (tick + request + route records, the PR's new stream)."""
-    rec = FlightRecorder(str(tmp_path), fsync_every=64)
-    t0 = time.perf_counter()
-    for i in range(512):
-        rec.record_step(surface="serve", event="tick", tick_wall_s=0.01,
-                        active=8, prefilling=2, decoding=6,
-                        pool_utilization=0.7, queue_depth=3)
-        rec.record_step(surface="serve", event="request",
-                        request_id=f"g{i}", state="done", n_tokens=16,
-                        ttft_s=0.2, itl_s=0.01, queue_wait_s=0.05)
-        rec.record_step(surface="serve", event="route", request_id=f"g{i}",
-                        replica=i % 3, resume_from=0, reroutes=0,
-                        loads={0: 1, 1: 2, 2: 0},
-                        straggler_scores={0: 1.0, 1: 1.2, 2: 1.0},
-                        states={0: "ready", 1: "ready", 2: "ready"})
-        # Simulate a 0.5ms serving tick: the bar is relative to wall.
-        t_busy = time.perf_counter()
-        while time.perf_counter() - t_busy < 0.0005:
-            pass
-    wall = time.perf_counter() - t0
-    rec.close()
-    stats = rec.stats()
-    assert stats["records"] >= 3 * 512
-    assert stats["append_s"] / wall < 0.25  # generous CI bound; prod ~1%
+    """The recorder's cost on the serving path is bounded by how often it
+    writes, so the guard counts writes (a wall-clock ratio on a shared CPU
+    is a flake by construction): per N progressing ticks one tick record
+    in 32, one decode record in 64 rounds, one request record a request,
+    and an fsync only every ``fsync_every`` records."""
+    from collections import Counter
+
+    from autodist_tpu.serve.batcher import ContinuousBatcher
+    from autodist_tpu.serve.server import _tiny_engine
+
+    n_requests, max_new = 8, 24
+    rec = obs_recorder.enable(str(tmp_path / "flight"), fsync_every=64,
+                              fsync_interval_s=1e9)
+    try:
+        engine, _, _ = _tiny_engine(n_slots=8, n_pages=41)
+        ticks = []
+        batcher = ContinuousBatcher(engine, registry=M.MetricsRegistry(),
+                                    on_tick=ticks.append)
+        batcher.start()
+        try:
+            for _ in range(n_requests):   # one at a time: the ticks repeat
+                req = batcher.submit(np.arange(1, 6, dtype=np.int32), max_new)
+                assert req.wait(120.0).state is RequestState.DONE
+        finally:
+            batcher.stop()
+        stats = rec.stats()
+    finally:
+        obs_recorder.disable(ok=True)
+    # One chunk prefills the 5-token prompt and the same tick decodes the
+    # second token: max_new - 1 ticks and decode rounds a request.
+    assert len(ticks) == engine.decode_invocations == n_requests * (max_new - 1)
+    records = obs_recorder.read_records(str(tmp_path / "flight"))
+    by_event = Counter(r.get("event") for r in records
+                       if r.get("kind") == "step")
+    assert by_event["tick"] == -(-len(ticks) // 32)
+    assert by_event["decode"] == -(-engine.decode_invocations // 64)
+    assert by_event["request"] == n_requests
+    # Everything else is per request (admit, prefilled), never per tick.
+    per_tick = by_event["tick"] + by_event["decode"]
+    assert sum(by_event.values()) - per_tick <= 3 * n_requests
     assert stats["errors"] == 0
+    assert stats["fsyncs"] <= stats["events"] + stats["records"] // 64

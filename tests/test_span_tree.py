@@ -1,0 +1,418 @@
+"""The program's own span tree (ISSUE 26): parent/id/self time, spans on
+the ``jax.profiler`` host plane, the serving tick's tree, the train
+window's spans, and the names the programs and kernels carry in a device
+trace."""
+import os
+import sys
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from autodist_tpu import analysis
+from autodist_tpu import metrics as M
+from autodist_tpu.obs import spans as obs_spans
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ------------------------------------------------------- parent, id, self time
+def test_nested_spans_know_their_parent():
+    tracer = obs_spans.SpanTracer()
+    with tracer.span("outer", k=1) as attrs:
+        with tracer.span("a"):
+            with tracer.span("a.inner"):
+                pass
+        with tracer.span("b"):
+            pass
+        attrs["late"] = True
+    by_name = {s.name: s for s in tracer.spans()}
+    assert len({s.id for s in tracer.spans()}) == 4
+    assert by_name["outer"].parent is None
+    assert by_name["a"].parent == by_name["b"].parent == by_name["outer"].id
+    assert by_name["a.inner"].parent == by_name["a"].id
+    assert by_name["outer"].attrs == {"k": 1, "late": True}
+    ev = by_name["a"].to_event()
+    assert ev["args"]["id"] == by_name["a"].id
+    assert ev["args"]["parent"] == by_name["outer"].id
+    retro = tracer.add_span("wait", time.time(), 0.5, parent=by_name["b"].id)
+    assert retro.parent == by_name["b"].id and retro.id not in {
+        s.id for s in by_name.values()}
+
+
+def test_parent_is_per_thread():
+    tracer = obs_spans.SpanTracer()
+    inside = threading.Event()
+    release = threading.Event()
+
+    def other():
+        with tracer.span("other.top"):
+            inside.set()
+            release.wait(10)
+
+    t = threading.Thread(target=other)
+    with tracer.span("main.top"):
+        t.start()
+        assert inside.wait(10)
+        with tracer.span("main.child"):
+            pass
+        release.set()
+        t.join()
+    by_name = {s.name: s for s in tracer.spans()}
+    assert by_name["other.top"].parent is None
+    assert by_name["main.child"].parent == by_name["main.top"].id
+    # the stack unwinds: a span opened afterwards is a root again
+    with tracer.span("after"):
+        pass
+    assert tracer.spans()[-1].parent is None
+
+
+def test_span_closed_by_an_exception_still_unwinds():
+    tracer = obs_spans.SpanTracer()
+    with pytest.raises(RuntimeError):
+        with tracer.span("boom"):
+            raise RuntimeError("x")
+    with tracer.span("next"):
+        pass
+    boom, nxt = tracer.spans()
+    assert boom.attrs == {"error": True} and nxt.parent is None
+
+
+def _span(name, start, dur, sid, parent=None):
+    return obs_spans.Span(name=name, t_start_s=start, dur_s=dur, trace_id="t",
+                          process=0, os_pid=1, tid=1, id=sid, parent=parent)
+
+
+def test_self_time_takes_the_children_out_once():
+    spans = [
+        _span("tick", 10.0, 1.0, 1),
+        _span("dispatch", 10.1, 0.3, 2, parent=1),
+        _span("fetch", 10.3, 0.3, 3, parent=1),      # overlaps dispatch 0.1
+        _span("late", 10.9, 0.5, 4, parent=1),       # reaches past the parent
+        _span("leaf", 10.15, 0.1, 5, parent=2),
+        _span("orphan", 20.0, 0.2, 6, parent=99),
+    ]
+    own = obs_spans.self_time(spans)
+    assert own[1] == pytest.approx(1.0 - 0.5 - 0.1)
+    assert own[2] == pytest.approx(0.2)
+    assert own[3] == pytest.approx(0.3)
+    assert own[5] == pytest.approx(0.1)
+    assert own[6] == pytest.approx(0.2)
+
+
+# ------------------------------------------------------- the profiler's clock
+def test_spans_module_never_imports_jax(monkeypatch):
+    """``obs/spans.py`` uses a jax that is already imported and never
+    imports one. (The package's ``__init__`` imports jax at the parent
+    commit already, so ``import autodist_tpu.obs.spans`` as a statement
+    cannot show it: the module's own imports and a span opened with no
+    jax in ``sys.modules`` do.)"""
+    import ast
+
+    with open(obs_spans.__file__, encoding="utf-8") as f:
+        tree = ast.parse(f.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported |= {a.name.split(".")[0] for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            imported.add((node.module or "").split(".")[0])
+    assert "jax" not in imported
+    monkeypatch.delitem(sys.modules, "jax")
+    tracer = obs_spans.SpanTracer()
+    with tracer.span("no.jax"):
+        assert "jax" not in sys.modules
+    assert [s.name for s in tracer.spans()] == ["no.jax"]
+    assert "jax" not in sys.modules
+
+
+def test_only_spans_opens_a_trace_annotation():
+    hits = []
+    for base, _, files in os.walk(os.path.join(ROOT, "autodist_tpu")):
+        for name in files:
+            if name.endswith(".py"):
+                path = os.path.join(base, name)
+                with open(path, encoding="utf-8") as f:
+                    if "TraceAnnotation(" in f.read():
+                        hits.append(os.path.relpath(path, ROOT))
+    assert hits == [os.path.join("autodist_tpu", "obs", "spans.py")]
+
+
+def test_span_shows_on_the_profilers_host_plane(tmp_path):
+    from perfbench.harness import trace as bench_trace
+
+    tracer = obs_spans.SpanTracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracer.span("probe.outer"):
+            with tracer.span("probe.inner"):
+                jnp.ones((64, 64)).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    tr = bench_trace.Trace.from_file(bench_trace.find_xplane(str(tmp_path)))
+    found = {name: (start, start + dur) for name, start, dur in tr.host_events
+             if name in ("probe.outer", "probe.inner")}
+    assert set(found) == {"probe.outer", "probe.inner"}
+    assert (found["probe.outer"][0] <= found["probe.inner"][0]
+            <= found["probe.inner"][1] <= found["probe.outer"][1])
+    # the ring holds the same two, parented
+    outer, = [s for s in tracer.spans() if s.name == "probe.outer"]
+    inner, = [s for s in tracer.spans() if s.name == "probe.inner"]
+    assert inner.parent == outer.id
+
+
+# ---------------------------------------------------------- the serving tick
+TICK_CHILDREN = {"serve.admit", "serve.prefill_chunk", "serve.decode_step",
+                 "serve.emit", "serve.tick_metrics", "serve.token_fetch"}
+
+
+@pytest.fixture(scope="module")
+def tiny_engine():
+    from autodist_tpu.serve.server import _tiny_engine
+
+    engine, _, _ = _tiny_engine(n_slots=4, n_pages=33)
+    return engine
+
+
+def test_one_request_yields_the_tick_tree(tiny_engine):
+    from autodist_tpu.serve.batcher import ContinuousBatcher, RequestState
+
+    tracer = obs_spans.get_tracer()
+    tracer.clear()
+    seen = []
+    batcher = ContinuousBatcher(tiny_engine, registry=M.MetricsRegistry(),
+                                on_tick=seen.append)
+    batcher.start()
+    try:
+        # 20 prompt tokens at a chunk of 8: two dispatch-only chunks, then
+        # the final one, which fetches the first token.
+        req = batcher.submit(np.arange(1, 21, dtype=np.int32), 4)
+        assert req.wait(120.0).state is RequestState.DONE
+    finally:
+        batcher.stop()
+    spans = tracer.spans()
+    by_id = {s.id: s for s in spans}
+    ticks = [s for s in spans if s.name == "serve.tick"]
+    assert len(ticks) >= len(seen) == 5      # 3 prefill ticks, 2 more decode
+    assert [t.attrs["seq"] for t in ticks if t.attrs["progressed"]][:5] == [
+        0, 1, 2, 3, 4]
+
+    def children(parent):
+        return [s for s in spans if s.parent == parent.id]
+
+    def inside(child, parent):
+        eps = 1e-3   # start is wall clock, duration perf_counter
+        return (parent.t_start_s - eps <= child.t_start_s and
+                child.t_start_s + child.dur_s
+                <= parent.t_start_s + parent.dur_s + eps)
+
+    first = ticks[0]
+    assert [c.name for c in children(first)] == [
+        "serve.admit", "serve.prefill_chunk", "serve.tick_metrics"]
+    for s in spans:
+        if s.parent is not None and s.name != "serve.queue_wait":
+            assert inside(s, by_id[s.parent]), (s.name, by_id[s.parent].name)
+        if s.name in TICK_CHILDREN - {"serve.token_fetch"}:
+            assert by_id[s.parent].name == "serve.tick", s.name
+    chunks = [s for s in spans if s.name == "serve.prefill_chunk"]
+    assert [c.attrs["final"] for c in chunks] == [False, False, True]
+    # the final chunk's tick: chunk, its fetch, the first token's
+    # book-keeping, then a decode round in the same tick
+    third = by_id[chunks[2].parent]
+    assert [c.name for c in children(third)] == [
+        "serve.prefill_chunk", "serve.token_fetch", "serve.emit",
+        "serve.decode_step", "serve.emit", "serve.tick_metrics"]
+    assert children(third)[1].attrs["program"] == "prefill_chunk"
+    step = children(third)[3]
+    assert [(c.name, c.attrs.get("program")) for c in children(step)] == [
+        ("serve.decode_dispatch", None), ("serve.token_fetch", "decode_step")]
+    hooks = [s for s in spans if s.name == "serve.on_tick"]
+    assert len(hooks) == len(seen) and all(h.parent is None for h in hooks)
+    # a tick's self time is what its children leave
+    own = obs_spans.self_time(spans)
+    assert 0.0 <= own[third.id] <= third.dur_s - sum(
+        c.dur_s for c in children(third)) + 1e-6
+    # every token has its time, in order, the first at t_first_token
+    assert len(req.t_tokens) == len(req.tokens) == 4
+    assert req.t_tokens == sorted(req.t_tokens)
+    assert req.t_tokens[0] == req.t_first_token
+
+
+def test_itl_histogram_holds_every_gap(tiny_engine):
+    from autodist_tpu.serve.batcher import ContinuousBatcher, RequestState
+
+    registry = M.MetricsRegistry()
+    batcher = ContinuousBatcher(tiny_engine, registry=registry)
+    batcher.start()
+    try:
+        reqs = [batcher.submit(np.arange(1, 6, dtype=np.int32), n)
+                for n in (6, 3)]
+        for r in reqs:
+            assert r.wait(120.0).state is RequestState.DONE
+    finally:
+        batcher.stop()
+    snap = registry.snapshot()
+    assert snap["serve_itl_s"]["count"] == (6 - 1) + (3 - 1)
+    assert snap["serve_tokens_generated_total"] == 9
+    # each request's first token comes from prefill, the rest from decode
+    assert snap["serve_decode_tokens_generated_total"] == 9 - 2
+    assert "serve_tokens_per_sec" not in snap
+    assert "serve_decode_tokens_per_sec" not in snap
+    for r in reqs:
+        gaps = np.diff(r.t_tokens)
+        assert r.itl_s == pytest.approx(gaps.mean(), abs=1e-3)
+
+
+# ---------------------------------------------------------- the train window
+@pytest.fixture(scope="module")
+def train_step():
+    from autodist_tpu.api import AutoDist
+    from autodist_tpu.strategy import AllReduce
+
+    AutoDist.reset_default()
+    try:
+        autodist = AutoDist(strategy_builder=AllReduce())
+
+        def loss_fn(p, b):
+            return ((b["x"] @ p["w"]) ** 2).mean()
+
+        params = {"w": np.ones((3, 1), np.float32)}
+        batch = {"x": np.ones((16, 3), np.float32)}
+        step = autodist.build(loss_fn, params, batch)
+        yield step, step.init(params), batch
+    finally:
+        AutoDist.reset_default()
+
+
+def test_train_window_spans(train_step):
+    from autodist_tpu.data import DataLoader
+
+    step, state, batch = train_step
+    tracer = obs_spans.get_tracer()
+    tracer.clear()
+    loader = DataLoader({"x": np.ones((64, 3), np.float32)}, batch_size=16,
+                        shuffle=False, epochs=1, drop_remainder=True)
+    n = 0
+    for b in loader.host_batches():
+        window = step.plan.window_from_local({"x": b["x"][None]})
+        state, _ = step.run(state, window, 1, stacked=True)
+        n += 1
+    state, _ = step(state, batch)
+    names = [s.name for s in tracer.spans()]
+    assert n == 4
+    assert names.count("input.next") == n + 1     # the last finds the end
+    assert names.count("input.stage") == n
+    dispatch = [s for s in tracer.spans() if s.name == "train.window_dispatch"]
+    assert [(d.attrs["program"], d.attrs["fresh"]) for d in dispatch] == [
+        ("run[1/stacked]", True)] + [("run[1/stacked]", False)] * 3 + [
+        ("step", True)]
+    assert all(d.parent is None for d in dispatch)
+
+
+# ------------------------------------------- names of programs and kernels
+def _module_line(text):
+    return text.split("\n", 1)[0]
+
+
+def test_train_modules_are_named(train_step):
+    step, state, batch = train_step
+    assert "jit_train_step," in _module_line(
+        analysis.compiled_hlo(step, state, batch))
+    _, text = analysis.compiled_window(step, state, batch, 2)
+    assert "jit_train_window," in _module_line(text)
+
+
+def test_serving_modules_are_named(tiny_engine):
+    e = tiny_engine
+    if e._decode_fn is None:
+        e._compile()
+    cache = jax.eval_shape(lambda: e._cache)
+    decode = analysis.compiled_text(
+        e._decode_fn, e.params, jnp.asarray(e._last_token),
+        jnp.asarray(e._lengths), cache, jnp.asarray(e._decode_table_np),
+        e._samp_dev())
+    assert "jit_serve_decode_step," in _module_line(decode)
+    chunk = np.zeros((1, e.prefill_chunk), np.int32)
+    prefill = analysis.compiled_text(
+        e._prefill_fn, e.params, jnp.asarray(chunk), np.int32(0), np.int32(5),
+        cache, jnp.asarray(e._table_np[0]), e._samp_dev(0))
+    assert "jit_serve_prefill_chunk," in _module_line(prefill)
+    copy = e._make_page_copy_fn(e.pool.n_pages, e._cache_sh)
+    assert "jit_serve_cow_copy," in _module_line(analysis.compiled_text(
+        copy, cache, jnp.int32(1), jnp.int32(2)))
+
+
+def test_spec_and_eval_functions_are_named():
+    """The module name is ``jit_`` + the function's name: these programs
+    are pinned by name without compiling them again."""
+    import inspect
+
+    from autodist_tpu.kernel import lowering
+    from autodist_tpu.serve import spec
+
+    src = inspect.getsource(spec.SpecDecodeEngine._compile_spec)
+    for name in ("serve_spec_verify", "serve_draft_prefill",
+                 "serve_draft_decode"):
+        assert f"def {name}(" in src and f"{name}, donate_argnums" in src
+    assert "lambda" not in src
+    src = inspect.getsource(lowering.DistributedTrainStep)
+    assert "def eval_step(" in src and "eval_step,\n" in src
+
+
+# The kernels' names as a device trace shows them: compiled for a described
+# v5e with the chip's own compiler (no chip needed). The topology is
+# described inside a fixture, never at import (one libtpu per process).
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _custom_calls(text):
+    """Names of the instructions that are Mosaic calls."""
+    import re
+
+    return re.findall(r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                      text, flags=re.M)
+
+
+def test_kernel_names_in_the_compiled_programs(one_chip):
+    import importlib
+
+    F = importlib.import_module("autodist_tpu.ops.flash_attention")
+    PA = importlib.import_module("autodist_tpu.ops.paged_attention")
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def train_window(q, k, v):
+        return jax.grad(lambda *a: F.flash_attention(
+            *a, True, 128, 128, False).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    qkv = sds((2, 1024, 16, 64))
+    calls = _custom_calls(analysis.compiled_text(
+        jax.jit(train_window), qkv, qkv, qkv))
+    assert len(calls) == 3
+    for want in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert sum(want in c for c in calls) == 1, calls
+
+    def serve_decode_step(q, kp, vp, tables, pos):
+        return PA.paged_decode_attention(q, kp, vp, tables, pos,
+                                         impl="kernel", interpret=False)
+
+    pages = sds((65, 16, 25, 64))
+    calls = _custom_calls(analysis.compiled_text(
+        jax.jit(serve_decode_step), sds((4, 25, 64)), pages, pages,
+        sds((4, 16), jnp.int32), sds((4,), jnp.int32)))
+    assert len(calls) == 1 and calls[0].startswith("paged_attention"), calls
