@@ -7,6 +7,12 @@ The shape of that table (TPU v5e, bf16): XLA's fused dot-product attention
 wins at short sequences (the flash kernel's block bookkeeping costs more
 than the O(s²) logits it avoids materializing), and the Pallas kernel wins
 once the logits matrix stops fitting in VMEM — 2× step time at s=4096.
+That table predates the present kernel: it was recorded with the flash
+kernels of before PR 28 (128 x 128 tiles, float32 operands into the backward
+products, the mask built in every tile), on a model of 8-12 heads. The
+kernels are since about twice as fast at seq 1,024 (PERF.md § 6, PR 28), so
+the true breakeven lies lower than the recorded one; the table is read by the
+program and is re-recorded, not edited.
 
 This module turns the table into the ONE decision rule the transformer's
 ``attention_impl="auto"`` uses: the smallest measured sequence length from

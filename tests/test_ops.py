@@ -16,29 +16,99 @@ def _make_qkv(rng, b=2, s=256, h=2, d=64, dtype=jnp.float32):
     return q, k, v
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_forward_matches_reference(causal):
-    q, k, v = _make_qkv(jax.random.PRNGKey(0))
-    out = flash_attention(q, k, v, causal)
-    ref = mha_reference(q, k, v, causal)
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+def _float32_reference(q, k, v, causal, cotangent):
+    """Output and the three gradients of the jnp reference on the float32
+    copies of the inputs: what every input dtype is held against."""
+    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+    out, vjp = jax.vjp(lambda *a: mha_reference(*a, causal), q32, k32, v32)
+    return out, vjp(cotangent.astype(jnp.float32))
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_gradients_match_reference(causal):
-    q, k, v = _make_qkv(jax.random.PRNGKey(1), s=256)
+# float32: the tolerances the kernel has always been held to. bfloat16: the
+# reference is float32 on the same (bfloat16-rounded) inputs, so the gap is
+# the kernel's own rounding: p and ds cast to bfloat16 before their products,
+# and a bfloat16 result. Worst readings over the cases below (CPU, interpret
+# mode, PR 28): forward 0.0074 absolute where |out| reaches 2.9, gradients
+# 0.0137 absolute where |grad| reaches 4.0 (float32: 6.6e-7 and 3.6e-6). The
+# bfloat16 tolerances are absolute and about twice those readings.
+_TOL = {
+    jnp.float32: dict(out=dict(atol=2e-5, rtol=2e-5), grad=dict(atol=5e-4, rtol=5e-4)),
+    jnp.bfloat16: dict(out=dict(atol=1.5e-2, rtol=0), grad=dict(atol=3e-2, rtol=0)),
+}
 
-    def f_flash(q, k, v):
-        return (flash_attention(q, k, v, causal) ** 2).sum()
 
-    def f_ref(q, k, v):
-        return (mha_reference(q, k, v, causal) ** 2).sum()
+@pytest.mark.parametrize("blocks", [(128, 128), (None, None)],
+                         ids=["explicit128", "derived"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("seq", [256, 384, 1024])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_forward_and_gradients_match_reference(causal, seq, dtype, blocks):
+    """Forward and dQ, dK, dV against the float32 reference, over masked and
+    unmasked tiles, one tile and many, float32 and bfloat16 operands, a
+    caller's tiles and the derived ones. 384 is a multiple of 128 that the
+    largest derived tile does not divide: the tile function must."""
+    q, k, v = _make_qkv(jax.random.PRNGKey(seq + causal), b=1, s=seq, dtype=dtype)
+    cot = jax.random.normal(jax.random.PRNGKey(7), q.shape, dtype)
+    out, vjp = jax.vjp(lambda *a: flash_attention(*a, causal, *blocks), q, k, v)
+    grads = vjp(cot)
+    ref_out, ref_grads = _float32_reference(q, k, v, causal, cot)
+    assert out.dtype == dtype and all(g.dtype == dtype for g in grads)
+    tol = _TOL[dtype]
+    np.testing.assert_allclose(np.asarray(out, np.float32), ref_out, **tol["out"])
+    for got, want, name in zip(grads, ref_grads, "qkv"):
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   **tol["grad"], err_msg=f"d{name} mismatch")
 
-    g_flash = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
-        np.testing.assert_allclose(gf, gr, atol=5e-4, rtol=5e-4,
-                                   err_msg=f"d{name} mismatch")
+
+def test_derived_tiles_divide_the_sequence():
+    """The tile function answers from the shape alone, with 128-multiples
+    that divide the sequence, whatever the cap."""
+    from autodist_tpu.ops.flash_attention import _tiles
+
+    for seq in (128, 256, 384, 640, 1024, 1152, 4096):
+        for tile in _tiles(seq, 64, jnp.bfloat16):
+            assert tile % 128 == 0 and seq % tile == 0 and tile <= seq
+
+
+def _eqns(jaxpr, primitive):
+    """Every equation of one primitive under a jaxpr, in order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_eqns(sub, primitive))
+    return found
+
+
+def test_every_kernel_product_takes_bfloat16_operands():
+    """The mechanism: on bfloat16 inputs all seven products of the three
+    kernels (two forward, four in dK/dV, three in dQ, each traced once with
+    and once without the mask) enter the MXU as bfloat16 x bfloat16 with a
+    float32 result, and the calls keep the names and the first operand the
+    benchmark's readers look for."""
+    b, s, h, d = 2, 1024, 4, 64
+    x = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash_attention(*a, True).astype(
+            jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    calls = _eqns(jax.make_jaxpr(grads)(x, x, x).jaxpr, "pallas_call")
+    names = [c.params["name"] for c in calls]
+    assert names == ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"], names
+    products = {"flash_fwd": 2, "flash_bwd_dkv": 4, "flash_bwd_dq": 3}
+    for call in calls:
+        first = call.invars[0].aval
+        assert first.shape == (b * h, s, d) and first.dtype == jnp.bfloat16
+        dots = _eqns(call.params["jaxpr"], "dot_general")
+        # one body without the mask and one with it
+        assert len(dots) == 2 * products[call.params["name"]], len(dots)
+        for dot in dots:
+            lhs, rhs = (v.aval.dtype for v in dot.invars)
+            assert lhs == rhs == jnp.bfloat16, (call.params["name"], lhs, rhs)
+            assert dot.outvars[0].aval.dtype == jnp.float32
 
 
 def test_nonaligned_seq_falls_back():

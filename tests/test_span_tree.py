@@ -416,3 +416,34 @@ def test_kernel_names_in_the_compiled_programs(one_chip):
         jax.jit(serve_decode_step), sds((4, 25, 64)), pages, pages,
         sds((4, 16), jnp.int32), sds((4,), jnp.int32)))
     assert len(calls) == 1 and calls[0].startswith("paged_attention"), calls
+
+
+@pytest.mark.parametrize("shape, dtype", [
+    ((12, 1024, 16, 64), jnp.bfloat16),    # medium-train-1chip
+    ((8, 1024, 25, 64), jnp.bfloat16),     # gpt2-xl's 25 heads
+    ((1, 8192, 8, 128), jnp.bfloat16),     # a long sequence: operands crowd VMEM
+    ((2, 1152, 4, 64), jnp.float32),       # 9 x 128: the largest tile does not divide it
+], ids=["medium", "xl-heads", "seq8192", "seq1152-f32"])
+def test_derived_flash_tiles_compile_for_the_chip(one_chip, shape, dtype):
+    """The tiles and heads per step the kernel derives from the shape fit the
+    chip's VMEM and pass Mosaic, and the calls keep the first operand the
+    benchmark's roofline reader finds them by."""
+    import importlib
+
+    F = importlib.import_module("autodist_tpu.ops.flash_attention")
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: F.flash_attention(
+            *a, True, None, None, False).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    text = analysis.compiled_text(jax.jit(grads), x, x, x)
+    calls = _custom_calls(text)
+    assert len(calls) == 3, calls
+    for want in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert sum(want in c for c in calls) == 1, calls
+    b, s, h, d = shape
+    tile = F._tiles(s, d, dtype)[0]
+    assert s % tile == 0 and tile % 128 == 0
+    assert F._step_bytes(F._heads_per_step(b * h, tile, s, d, jnp.dtype(dtype).itemsize),
+                         tile, s, d, jnp.dtype(dtype).itemsize) <= F._VMEM_BYTES
