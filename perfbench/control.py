@@ -5,6 +5,12 @@ the planted faults) beside the program's own numbers, and can print what a
 profile holds. The limits in ``limits/`` were set from these readings.
 
     python3 perfbench/control.py --workload <cell> --seed <n> --seconds <s> [--trace 1] [--dump-trace FILE]
+
+With ``--selections`` (and ``--trace 1``) it is the plain run, no control,
+that also prints which device operations the readers select in its profile
+beside what the selection by shape of before PR 29 picks there (the oracle
+kept in ``tests/perfbench/test_selection.py``): counts, summed seconds and
+whether the two are the same operations.
 """
 import sys
 import time
@@ -24,8 +30,11 @@ def main() -> int:
         i = argv.index("--dump-trace")
         dump = argv[i + 1]
         del argv[i:i + 2]
+    selections = "--selections" in argv
+    if selections:
+        argv.remove("--selections")
     from perfbench import run
-    from perfbench.harness import runtime, trace
+    from perfbench.harness import manifest, runtime, trace
 
     if dump:
         read = runtime.ProfilerSession.read
@@ -43,6 +52,18 @@ def main() -> int:
         "controls": {"fp8": {"precision": "fp8"},
                      "half_batch": {"rows_used": "half"}},
     }
+    if selections:
+        oracle = manifest.load_module(os.path.join(
+            ROOT, "tests", "perfbench", "test_selection.py"), "perfbench_selection_oracle")
+
+        def say_selections(result, ctx):
+            for what, (old, new) in oracle.selections(result, ctx).items():
+                seconds = [sum(op[2] for op in side) for side in (old, new)]
+                print(f"selection of {what}: old {len(old)} in {seconds[0]:.9f} s, "
+                      f"new {len(new)} in {seconds[1]:.9f} s, "
+                      f"the same operations: {old == new}", flush=True)
+
+        hooks = {"run": say_selections}
     return run.main(argv, hooks=hooks, t0=_T0)
 
 

@@ -11,7 +11,10 @@ The traffic file gives ``clients``, the lengths (see ``harness/traffic.py``)
 and ``check_requests``, how many finished requests the reference follows.
 The configuration file's ``serving`` group gives what a deployment states
 (``n_slots``, ``max_len``); page size, pool size, prefill chunk and the
-attention paths are the program's own choice.
+attention paths are the program's own choice. Everything that is the
+model's (weights from the seed, the decode model handed to the program, the
+vocabulary, the plain reference) is the configuration's family's
+(``perfbench/families/<family>.py``).
 """
 from __future__ import annotations
 
@@ -21,7 +24,7 @@ import time
 
 import numpy as np
 
-from perfbench.harness import device, reference, runtime, traffic, weights
+from perfbench.harness import device, runtime, traffic
 
 TOKENS_COUNTER = "serve_tokens_generated_total"
 POOL_GAUGE = "serve_page_pool_utilization"
@@ -134,29 +137,28 @@ class Observer:
             self.closed.set()
 
 
-def build_engine(model, params, ctx):
+def build_engine(fam, model, params, ctx):
     """The program, entered as a deployment would: ``build_inference``
-    with what the configuration's ``serving`` group states and nothing else."""
-    from autodist_tpu.models import transformer as T
+    with the family's decode model, what the configuration's ``serving``
+    group states and nothing else."""
     from autodist_tpu.strategy import AllReduce
 
-    cfg = runtime.transformer_config(model)
     autodist = runtime.make_autodist(AllReduce(), ctx["cell"].chips)
     serving = model["serving"]
-    engine = autodist.build_inference(
-        params, decode_model=T.decode_model(cfg),
+    return autodist.build_inference(
+        params, decode_model=fam.decode_model(model),
         n_slots=serving["n_slots"], max_len=serving["max_len"])
-    return engine
 
 
-def check_served(finished, model, seed, n_check, precisions=("float32",), pad_to=None):
+def check_served(fam, finished, model, seed, n_check, precisions=("float32",),
+                 pad_to=None):
     """Widest gap by which a served token's reference logit lies below the
     reference's best, over a sample of the finished requests drawn from
     the seed, the longest always in it. With more ``precisions`` also the
     same gap for the token each lower precision puts first (the control)."""
     import jax.numpy as jnp
 
-    params = weights.make_params(model, seed)
+    params, vocab = fam.reference_params(model, seed), fam.vocab_size(model)
     order = np.random.default_rng(int(seed) % (2 ** 32)).permutation(len(finished))
     longest = max(range(len(finished)), key=lambda i: (
         len(finished[i]["prompt"]) + len(finished[i]["tokens"])))
@@ -170,26 +172,26 @@ def check_served(finished, model, seed, n_check, precisions=("float32",), pad_to
     for i in picks:
         r = finished[i]
         served = np.asarray(r["tokens"], np.int32)
-        bad_vocab += int(((served < 0) | (served >= model["vocab_size"])).sum())
-        served = np.clip(served, 0, model["vocab_size"] - 1)
+        bad_vocab += int(((served < 0) | (served >= vocab)).sum())
+        served = np.clip(served, 0, vocab - 1)
         seq = np.zeros(pad, np.int32)
         n_p, n_t = len(r["prompt"]), len(served)
         seq[:n_p] = r["prompt"]
         seq[n_p:n_p + n_t] = served
         pos = np.arange(n_p - 1, n_p + n_t - 1)
-        best, _, table = reference.next_token_logits(
+        best, _, table = fam.next_token_logits(
             params, jnp.asarray(seq), model, "float32")
         picked = np.zeros(pad, np.int32)
         picked[pos] = served
-        gap = np.asarray(reference.logit_gaps(best, table, jnp.asarray(picked)))[pos]
+        gap = np.asarray(fam.logit_gaps(best, table, jnp.asarray(picked)))[pos]
         worst["float32"] = max(worst["float32"], float(gap.max()))
         n_tokens += n_t
         for prec in precisions:
             if prec == "float32":
                 continue
-            _, top, _ = reference.next_token_logits(
+            _, top, _ = fam.next_token_logits(
                 params, jnp.asarray(seq), model, prec)
-            gap = np.asarray(reference.logit_gaps(best, table, top))[pos]
+            gap = np.asarray(fam.logit_gaps(best, table, top))[pos]
             worst[prec] = max(worst[prec], float(gap.max()))
     return worst, n_tokens, bad_vocab, len(picks)
 
@@ -202,12 +204,12 @@ def run(ctx):
     from autodist_tpu.serve.batcher import ContinuousBatcher
 
     cell, say, hooks = ctx["cell"], ctx["say"], ctx["hooks"]
-    model, mix, seed = cell.model, cell.traffic, ctx["seed"]
-    gen = traffic.ServeTraffic(mix, model["vocab_size"], seed)
+    fam, model, mix, seed = cell.family(), cell.model, cell.traffic, ctx["seed"]
+    gen = traffic.ServeTraffic(mix, fam.vocab_size(model), seed)
 
-    params = weights.make_params(model, seed)
+    params = fam.make_params(model, seed)
     t_build = time.perf_counter()
-    engine = build_engine(model, params, ctx)
+    engine = build_engine(fam, model, params, ctx)
     plan_build_s = time.perf_counter() - t_build
     del params
     if "engine" in hooks:
@@ -273,7 +275,7 @@ def run(ctx):
         precisions = ("float32",) + tuple(hooks.get("control_precisions", ()))
         t_ref = time.perf_counter()
         worst, n_tok, bad, n_req = check_served(
-            finished, model, seed, int(mix.get("check_requests", 8)), precisions,
+            fam, finished, model, seed, int(mix.get("check_requests", 8)), precisions,
             pad_to=gen.longest_timeline())
         numbers["logit_gap"] = worst["float32"]
         numbers["out_of_vocab"] = float(bad)

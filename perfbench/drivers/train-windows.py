@@ -12,6 +12,9 @@ compile it) and hands it on to the measured window.
 The traffic file is the job: ``strategy`` (name and arguments of a
 builder), ``optimizer``, ``batch_per_chip``, ``seq_len``, ``remat``
 (false, or "block" for the model's per-block checkpoint), ``rows``.
+Everything that is the model's (weights from the seed and their shardings,
+the loss handed to the program, the vocabulary, the reference that follows
+Adam) is the configuration's family's (``perfbench/families/<family>.py``).
 """
 from __future__ import annotations
 
@@ -21,23 +24,17 @@ from collections import deque
 
 import numpy as np
 
-from perfbench.harness import device, reference, runtime, traffic, weights
+from perfbench.harness import compare, device, runtime, traffic
 
 
-def build_step(model, mix, params, rows, ctx):
+def build_step(fam, model, mix, params, rows, ctx):
     from autodist_tpu import strategy as S
     from autodist_tpu.model_item import OptimizerSpec
-    from autodist_tpu.models import transformer as T
 
     chips = ctx["cell"].chips
-    cfg = runtime.transformer_config(model, remat=mix.get("remat") == "block")
     builder = getattr(S, mix["strategy"]["name"])(**mix["strategy"].get("kwargs", {}))
     autodist = runtime.make_autodist(builder, chips)
-
-    def loss_fn(p, b):
-        return T.loss_fn(p, b, cfg)
-
-    loss_fn = ctx["hooks"].get("loss_fn", lambda f: f)(loss_fn)
+    loss_fn = ctx["hooks"].get("loss_fn", lambda f: f)(fam.loss_fn(model, mix))
     batch = mix["batch_per_chip"] * chips
     opt = mix["optimizer"]
     step = autodist.build(
@@ -57,14 +54,14 @@ def _adam_mu(opt_state):
     return found[0].mu
 
 
-def reference_numbers(model, mix, seed, rows, batch, n_steps, precision="float32",
-                      rows_used=slice(None), shardings=None):
-    params = weights.make_params(model, seed, shardings)
+def reference_numbers(fam, model, mix, seed, rows, batch, n_steps,
+                      precision="float32", rows_used=slice(None), shardings=None):
+    params = fam.make_params(model, seed, shardings)
     if rows_used == "half":
         rows_used = slice(0, batch // 2)
     batches = [rows[i * batch:(i + 1) * batch] for i in range(n_steps)]
     opt = mix["optimizer"]
-    losses, grads, change = reference.adam_reference(
+    losses, grads, change = fam.adam_reference(
         params, batches, model, learning_rate=opt["learning_rate"],
         block_rows=int(mix.get("reference_block_rows", 2)),
         precision=precision, rows_used=rows_used, shardings=shardings)
@@ -78,15 +75,16 @@ def run(ctx):
     from autodist_tpu.data import DataLoader
 
     cell, say, hooks = ctx["cell"], ctx["say"], ctx["hooks"]
-    model, mix, seed, chips = cell.model, cell.traffic, ctx["seed"], cell.chips
-    rows = traffic.train_rows(mix, model["vocab_size"], seed)
+    fam, model, mix, seed, chips = (cell.family(), cell.model, cell.traffic,
+                                    ctx["seed"], cell.chips)
+    rows = traffic.train_rows(mix, fam.vocab_size(model), seed)
     n_check = int(mix.get("check_steps", 3))
     b1 = 0.9
 
-    shardings = weights.row_shardings(model, ctx["devices"])
-    params = weights.make_params(model, seed, shardings)
+    shardings = fam.row_shardings(model, ctx["devices"])
+    params = fam.make_params(model, seed, shardings)
     t_build = time.perf_counter()
-    step, batch = build_step(model, mix, params, rows, ctx)
+    step, batch = build_step(fam, model, mix, params, rows, ctx)
     plan_build_s = time.perf_counter() - t_build
     state = step.init(params)
     del params
@@ -113,7 +111,7 @@ def run(ctx):
             mu = step.plan.unpad_params(_adam_mu(state.opt_state))
             prog["grad_norms"] = [float(x) / (1 - b1) for x in leaf_norms(mu)]
             del mu
-    prog["change_norms"] = weights.change_norms(model, seed, step.logical_params(state))
+    prog["change_norms"] = fam.change_norms(model, seed, step.logical_params(state))
     say(f"followed steps: losses {prog['losses']}")
 
     session = runtime.ProfilerSession(ctx["root"]) if ctx["trace"] else None
@@ -168,9 +166,8 @@ def run(ctx):
     gc.collect()
 
     t_ref = time.perf_counter()
-    ref = reference_numbers(model, mix, seed, rows, batch, n_check, shardings=shardings)
-    from perfbench.harness import compare
-
+    ref = reference_numbers(fam, model, mix, seed, rows, batch, n_check,
+                            shardings=shardings)
     numbers = compare.train_numbers(prog, ref)
     numbers["nonfinite"] = float(nonfinite)
     say(f"numbers against the reference: {numbers}")
@@ -179,7 +176,7 @@ def run(ctx):
     for name, kw in hooks.get("controls", {}).items():
         jax.clear_caches()      # unload the last variant's programs first
         gc.collect()
-        alt = reference_numbers(model, mix, seed, rows, batch, n_check,
+        alt = reference_numbers(fam, model, mix, seed, rows, batch, n_check,
                                 shardings=shardings, **kw)
         for k, v in compare.train_numbers(alt, ref).items():
             numbers[f"control.{name}.{k}"] = v

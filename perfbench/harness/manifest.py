@@ -1,8 +1,11 @@
 """``BENCHMARK.json`` and the files it names: everything that belongs to
-one configuration, one traffic mix, one per-layer metric or one driver sits
-in a file of its own, found by the name in the manifest."""
+one configuration, one model family, one traffic mix, one per-layer metric
+or one driver sits in a file of its own, found by the name in the manifest
+(the family by the ``"family"`` its configuration file states:
+``perfbench/families/<family>.py``, everything that is the model's)."""
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -39,13 +42,23 @@ def load_module(path: str, name: str):
     return mod
 
 
+@functools.lru_cache(maxsize=None)
+def load_family(path: str):
+    """A family file, loaded once a process like any import: it holds
+    jitted functions, and a second copy would compile them again."""
+    name = os.path.splitext(os.path.basename(path))[0]
+    return load_module(path, "perfbench_family_" + "".join(
+        c if c.isalnum() else "_" for c in name))
+
+
 class Cell:
     """One entry of ``workloads`` with everything found for it."""
 
     def __init__(self, manifest: dict, workload: str, root: str = ROOT,
                  data_dir: str = None):
         # ``data_dir`` lets a test bring tiny traffic and limits of its
-        # own; drivers and metric readers are always the benchmark's.
+        # own, and families beside the benchmark's; drivers and metric
+        # readers are always the benchmark's.
         bench_dir = BENCH_DIR
         data_dir = data_dir or bench_dir
         self.manifest = manifest
@@ -53,7 +66,11 @@ class Cell:
         self.name = workload
         self.chips = int(self.entry["chips"])
         cfg = _by_name(manifest["configs"], self.entry["config"], "config")
+        self.config = cfg
         self.model = load_json(os.path.join(root, cfg["file"]))
+        own = os.path.join(data_dir, "families", self.model["family"] + ".py")
+        self.family_path = own if os.path.isfile(own) else os.path.join(
+            bench_dir, "families", self.model["family"] + ".py")
         self.traffic = load_json(os.path.join(
             data_dir, "traffic", self.entry["traffic"] + ".json"))
         limits = os.path.join(data_dir, "limits", workload + ".json")
@@ -74,6 +91,9 @@ class Cell:
         reported = {m["name"] for m in self.end_to_end}
         return [m for m in self.manifest["per_layer"]
                 if self._mine(m) and m["moves"] in reported]
+
+    def family(self):
+        return load_family(self.family_path)
 
     def driver(self):
         return load_module(self.driver_path,

@@ -5,12 +5,22 @@ A reader is ``read(run, ctx)``: ``run`` is what the driver returned
 the cell, the model and the chip's peaks. A reader that finds nothing to
 read returns None and the metric is left out of the line; it never returns
 0 for a share of a roofline or of a peak.
+
+Required work (a model's operations per token, a kernel's operations and
+bytes) is the cell's family's to count (``perfbench/families/<family>.py``);
+programs and kernels are found in the trace by the names the program gives
+them (``harness/spanread.py``), never by a shape.
 """
 from __future__ import annotations
 
 import math
 
-from perfbench.harness import counts, runtime
+from perfbench.harness import counts, runtime, spanread
+
+PREFILL_PROGRAM = "jit_serve_prefill_chunk"
+DECODE_PROGRAM = "jit_serve_decode_step"
+PAGED_KERNELS = ("paged_attention",)
+FLASH_KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
 
 
 def data(run, key):
@@ -31,6 +41,10 @@ def peak_flops(ctx):
     return ctx["peaks"]["flops_per_s"] if ctx.get("peaks") else None
 
 
+def family(ctx):
+    return ctx["cell"].family()
+
+
 # ------------------------------------------------------------------ device
 def idle_share(run, ctx):
     tr, w = run.get("trace"), run.get("trace_window_s")
@@ -48,7 +62,7 @@ def mfu_train(run, ctx):
     d, peak = run["data"], peak_flops(ctx)
     if peak is None:
         return None
-    per_token = counts.train_flops_per_token(ctx["cell"].model, d["seq"])
+    per_token = family(ctx).train_flops_per_token(ctx["cell"].model, d["seq"])
     return 100.0 * per_token * d["tokens"] / d["window_s"] / d["chips"] / peak
 
 
@@ -60,9 +74,9 @@ def collective_share(run, ctx):
 
 
 # ----------------------------------------------------------------- serving
-def _request_flops(model, prompt, n_out):
-    return counts.prefill_flops(model, prompt) + sum(
-        counts.decode_flops(model, prompt + i) for i in range(1, n_out))
+def _request_flops(fam, model, prompt, n_out):
+    return fam.prefill_flops(model, prompt) + sum(
+        fam.decode_flops(model, prompt + i) for i in range(1, n_out))
 
 
 def flops_per_served_token(run, ctx):
@@ -71,8 +85,8 @@ def flops_per_served_token(run, ctx):
     done = [(p, n) for p, n in run["data"]["finished"] if n > 0]
     if not done:
         return None
-    model = ctx["cell"].model
-    return (sum(_request_flops(model, p, n) for p, n in done)
+    fam, model = family(ctx), ctx["cell"].model
+    return (sum(_request_flops(fam, model, p, n) for p, n in done)
             / sum(n for _, n in done))
 
 
@@ -95,15 +109,15 @@ def mfu_tpot(run, ctx):
     if not p95 or peak is None or c is None:
         return None
     rows = run["data"]["engine"]["n_slots"]
-    return 100.0 * rows * counts.decode_flops(ctx["cell"].model, int(c)) / p95 / peak
+    return 100.0 * rows * family(ctx).decode_flops(ctx["cell"].model, int(c)) / p95 / peak
 
 
 def mfu_ttft(run, ctx):
     first, peak = run["data"]["first"], peak_flops(ctx)
     if not first or peak is None:
         return None
-    model = ctx["cell"].model
-    need = sum(counts.prefill_flops(model, n) for _, n in first)
+    fam, model = family(ctx), ctx["cell"].model
+    need = sum(fam.prefill_flops(model, n) for _, n in first)
     return 100.0 * need / sum(t for t, _ in first) / peak
 
 
@@ -133,78 +147,77 @@ def prefill_ticks_share(run, ctx):
 
 
 # ------------------------------------------------------------ device trace
-def _serving_modules(run, ctx):
-    """Tell the two serving programs apart in the trace: the prefill-chunk
-    program works on ``[chunk, d]`` activations, the decode program on
-    ``[slots, d]``. Returns ``(prefill runs, decode runs)`` or None where
-    the two sizes are equal or the trace holds no such program."""
+def serving_runs(run):
+    """``(prefill runs, decode runs)``: the device runs of the two serving
+    programs, found by their modules' names; None without a trace."""
     tr = run.get("trace")
     if tr is None:
         return None
-    eng, d = run["data"]["engine"], ctx["cell"].model["n_embd"]
-    if eng["prefill_chunk"] == eng["n_slots"]:
-        return None
-    prefill, decode = [], []
-    for name, runs in tr.module_runs().items():
-        texts = [op[0] for op in tr.ops_within(runs[:1])]
-        if any(f"[{eng['prefill_chunk']},{d}]" in t for t in texts):
-            prefill += runs
-        elif any(f"[{eng['n_slots']},{d}]" in t for t in texts):
-            decode += runs
-    return prefill, decode
+    return (spanread.module_runs(tr, PREFILL_PROGRAM),
+            spanread.module_runs(tr, DECODE_PROGRAM))
 
 
 def prefill_busy_share(run, ctx):
-    found = _serving_modules(run, ctx)
+    found = serving_runs(run)
     if not found or not (found[0] or found[1]):
         return None
     pre = sum(e - s for s, e in found[0])
     return positive(100.0 * pre / (pre + sum(e - s for s, e in found[1])))
 
 
+def kernel_calls(run, kernels, program=None):
+    """``{kernel: [operation, ...]}`` of the traced window: the device
+    operations named as one of ``kernels``, inside the runs of the module
+    named ``program`` where one is given."""
+    tr = run.get("trace")
+    if tr is None:
+        return {}
+    runs = None
+    if program is not None:
+        runs = spanread.module_runs(tr, program)
+        if not runs:
+            return {}
+    return spanread.kernel_ops(tr, kernels, runs)
+
+
+def run_facts(run):
+    """What the run knows of the shapes a kernel worked on, for the
+    family's ``kernel_work``: a train step's rows a chip and ``seq``; a
+    serving window's rows (slots in use, averaged over its ticks), mean
+    ``context`` of the tokens it served, and the ``engine``'s own facts."""
+    d = run["data"]
+    if d["kind"] == "train":
+        return {"rows": d["batch"] // d["chips"], "seq": d["seq"]}
+    ticks, eng = d["ticks"], d["engine"]
+    rows = sum(t[2] for t in ticks) / len(ticks) if ticks else eng["n_slots"]
+    return {"rows": rows, "context": mean_context(run), "engine": eng}
+
+
+def kernel_roofline(run, ctx, kernels, program=None):
+    """The least time the chip could take for the traced calls of
+    ``kernels`` (per call the family's ``kernel_work`` against the peaks:
+    whichever of operations and bytes bounds it) / their device time, %."""
+    if not ctx.get("peaks"):
+        return None
+    calls = kernel_calls(run, kernels, program)
+    facts = run_facts(run) if calls else {}
+    if not calls or any(v is None for v in facts.values()):
+        return None
+    fam, model = family(ctx), ctx["cell"].model
+    least = sum(len(ops) * counts.roofline_seconds(
+        *fam.kernel_work(kernel, model, facts), ctx["peaks"])[0]
+        for kernel, ops in calls.items())
+    return 100.0 * least / sum(op[2] for ops in calls.values() for op in ops)
+
+
 def paged_decode_roofline(run, ctx):
     """Mosaic page-walking calls inside the decode program: the least
     time the chip could take for the live keys and values (bytes at
     819 GB/s against FLOPs at 197 TFLOP/s: memory bounds it) / their time."""
-    found, c = _serving_modules(run, ctx), mean_context(run)
-    if not found or not found[1] or c is None or not ctx.get("peaks"):
-        return None
-    tr, model, eng = run["trace"], ctx["cell"].model, run["data"]["engine"]
-    hd = model["n_embd"] // model["n_head"]
-    pool = f"[{eng['n_pages'] + 1},{eng['page_len']},{model['n_head']},{hd}]"
-    calls = [op for op in tr.ops_within(found[1])
-             if " custom-call(" in op[0] and pool in op[0]]
-    if not calls:
-        return None
-    ticks = run["data"]["ticks"]
-    rows = sum(t[2] for t in ticks) / len(ticks) if ticks else eng["n_slots"]
-    one_layer = dict(model, n_layer=1)
-    least, _ = counts.roofline_seconds(
-        rows * counts.attention_flops(one_layer, c),
-        rows * counts.decode_kv_bytes(one_layer, c), ctx["peaks"])
-    return 100.0 * least * len(calls) / sum(op[2] for op in calls)
+    return kernel_roofline(run, ctx, PAGED_KERNELS, program=DECODE_PROGRAM)
 
 
 def flash_roofline(run, ctx):
     """Flash Mosaic calls (forward, dk/dv, dq) of the traced steps: required
     causal FLOPs at 197 TFLOP/s (compute bounds them) / their device time."""
-    tr = run.get("trace")
-    if tr is None or not ctx.get("peaks"):
-        return None
-    model, d = ctx["cell"].model, run["data"]
-    rows = d["batch"] // d["chips"]
-    hd = model["n_embd"] // model["n_head"]
-    operand = f"custom-call(bf16[{rows * model['n_head']},{d['seq']},{hd}]"
-    calls = [op for op in tr.device_ops.get(min(tr.device_ops), ())
-             if operand in op[0]] if tr.device_ops else []
-    steps = sum(len(r) for r in tr.module_runs().values())
-    if not calls or not steps:
-        return None
-    one_layer = dict(model, n_layer=1)
-    least = 0.0
-    for backward in (False, True):
-        t, _ = counts.roofline_seconds(
-            counts.flash_flops(one_layer, rows, d["seq"], backward),
-            counts.flash_bytes(one_layer, rows, d["seq"], backward), ctx["peaks"])
-        least += t
-    return 100.0 * least * model["n_layer"] * steps / sum(op[2] for op in calls)
+    return kernel_roofline(run, ctx, FLASH_KERNELS)

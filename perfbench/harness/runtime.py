@@ -84,21 +84,6 @@ def percentile(values, q: float) -> float:
     return xs[k]
 
 
-
-
-def transformer_config(model: dict, **more):
-    """The program's ``TransformerConfig`` for a configuration file: the
-    published sizes and nothing the program chooses for itself, unless the
-    file pins a path under ``assumed.transformer_config``."""
-    from autodist_tpu.models import transformer as T
-
-    return T.TransformerConfig(
-        vocab_size=model["vocab_size"], num_layers=model["n_layer"],
-        d_model=model["n_embd"], num_heads=model["n_head"],
-        d_ff=model["n_inner"], max_seq_len=model["n_positions"], **more,
-        **model.get("assumed", {}).get("transformer_config", {}))
-
-
 def make_autodist(strategy_builder, chips: int):
     """One ``AutoDist`` over the cell's chips. On the machine a cell is
     measured on, the chips are all the devices there are and the program

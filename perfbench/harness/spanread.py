@@ -264,30 +264,51 @@ def dispatch_only_p50_ms(run):
 
 
 # -------------------------------------------------- modules and kernels
+def module_runs(trace, module):
+    """Device runs ``[(start, end)]`` of the program whose module is called
+    ``module`` (``jit_`` + the jitted function's name; the trace adds a
+    fingerprint), by start."""
+    return sorted((s, e) for name, rs in trace.module_runs().items()
+                  if re.match(re.escape(module) + r"(\(|$)", name) for s, e in rs)
+
+
 def module_p50_ms(run, module):
-    """Median device run of the program whose module is called ``module``
-    (``jit_`` + the jitted function's name; the trace adds a fingerprint)."""
+    """Median device run of the program whose module is called ``module``."""
     trace = run.get("trace")
-    if trace is None:
-        return None
-    runs = [e - s for name, rs in trace.module_runs().items()
-            if re.match(re.escape(module) + r"(\(|$)", name) for s, e in rs]
+    runs = [e - s for s, e in module_runs(trace, module)] if trace else []
     return 1e3 * runtime.median(runs) if runs else None
 
 
+def kernel_ops(trace, kernels, runs=None):
+    """``{kernel: [(name, start, seconds)]}``: the operations of the lowest
+    device whose own name holds one of ``kernels`` (the ``name=`` of a
+    ``pl.pallas_call``; transforms wrap it: ``jvp_flash_fwd_``,
+    ``transpose_jvp_flash_bwd_dq__``), those that start inside ``runs``
+    where runs are given. An operation is named by the instruction it is,
+    never by an operand it reads or a shape it has."""
+    if device(trace) is None:
+        return {}
+    # the longest name first, so that a kernel whose name holds another's
+    # is not taken for it
+    rx = re.compile(r"^%?[\w.\-]*?(" + "|".join(
+        map(re.escape, sorted(kernels, key=len, reverse=True))) + r")[\w.\-]*( = |$)")
+    ops = trace.device_ops[device(trace)] if runs is None else trace.ops_within(runs)
+    found = defaultdict(list)
+    for op in ops:
+        m = rx.match(op[0])
+        if m:
+            found[m.group(1)].append(op)
+    return dict(found)
+
+
 def kernel_share(run, *kernels):
-    """Device time of the operations whose own name holds one of
-    ``kernels`` (the ``name=`` of a ``pl.pallas_call``; transforms wrap it:
-    ``jvp_flash_fwd_``, ``transpose_jvp_flash_bwd_dq__``) / the device's
-    busy time, in %. An operation is named by the instruction it is, never
-    by an operand it reads."""
+    """Device time of the operations named as ``kernels`` (``kernel_ops``)
+    / the device's busy time, in %."""
     trace = run.get("trace")
-    if trace is None or device(trace) is None:
+    if trace is None:
         return None
-    dev = device(trace)
-    rx = re.compile(r"^%?[\w.\-]*(" + "|".join(map(re.escape, kernels)) + r")[\w.\-]*( = |$)")
-    sec = sum(d for name, _, d in trace.device_ops[dev] if rx.match(name))
-    whole = sum(e - s for s, e in busy_intervals(trace))
+    sec = sum(op[2] for ops in kernel_ops(trace, kernels).values() for op in ops)
+    whole = sum(e - s for s, e in busy_intervals(trace)) if sec else 0.0
     if not sec or not whole:
         return None
     return 100.0 * sec / whole

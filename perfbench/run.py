@@ -4,9 +4,12 @@
     python3 perfbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A new process that finds the cell's files by the names in
-``BENCHMARK.json``, sets up (weights from the seed, the program built and
+``BENCHMARK.json`` (its configuration, the model family that file states
+under ``families/``, its traffic mix and that mix's driver, its limits, its
+metric readers), sets up (weights from the seed, the program built and
 warmed up), measures for ``--seconds``, checks what the timed path produced
-against the plain reference, and prints one JSON object as its last line.
+against the family's plain reference, and prints one JSON object as its
+last line.
 With ``--trace 0`` the metrics are the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics. There is no fallback: without a TPU
 that the peak table knows, or with fewer chips than the cell asks for, it
@@ -70,6 +73,8 @@ def main(argv=None, *, manifest_path=None, data_dir=None, require_chip=True,
         "t0": t0, "hooks": hooks or {}, "say": _say, "root": ROOT,
     }
     run = cell.driver().run(ctx)
+    if "run" in ctx["hooks"]:       # a test or the builder's tool looks on
+        ctx["hooks"]["run"](run, ctx)
 
     correct, checks = compare.decide(run["numbers"], cell.limits)
     dev = dict(device_info, memory_peak_bytes=run["memory_peak_bytes"])

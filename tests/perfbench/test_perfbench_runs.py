@@ -64,7 +64,7 @@ def test_serve_run_is_well_formed_and_control_fails(capsys):
     finished = [{"prompt": rng.integers(0, 4096, 8, dtype=np.int32),
                  "tokens": list(rng.integers(0, 4096, 100))} for _ in range(16)]
     worst, n_tokens, _, _ = cell.driver().check_served(
-        finished, cell.model, 7, 16, ("float32", "bfloat16", "fp8"))
+        cell.family(), finished, cell.model, 7, 16, ("float32", "bfloat16", "fp8"))
     limit = line["checks"]["logit_gap"][1]
     assert n_tokens == 1600
     assert worst["bfloat16"] <= limit, "the stated precision passes"

@@ -1,6 +1,7 @@
 """The benchmark's own parts, on the CPU: the manifest and the files it
-names, the traffic generator, the counts of operations and bytes, the
-trace reduction, and the plain reference against the program's forward."""
+names (each configuration's family among them), the traffic generator, the
+counts of operations and bytes, the trace reduction, and the plain
+reference against the program's forward."""
 import json
 import os
 import re
@@ -13,14 +14,17 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from perfbench.harness import (compare, counts, device, manifest, reference,  # noqa: E402
-                               runtime, trace, traffic, weights)
+from perfbench.harness import (compare, counts, device, manifest, readers,  # noqa: E402
+                               runtime, trace, traffic)
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 MANIFEST = manifest.load()
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 PER_LAYER = [m["name"] for m in MANIFEST["per_layer"]]
+TINY_DIR = os.path.join(ROOT, "tests", "perfbench", "tiny")
+TINY_MANIFEST = manifest.load(os.path.join(TINY_DIR, "BENCHMARK.tiny.json"))
+GPT2 = manifest.load_family(os.path.join(manifest.BENCH_DIR, "families", "gpt2.py"))
 
 
 def test_manifest_keys_and_names():
@@ -55,7 +59,7 @@ def test_cell_files_found_by_name(cell):
     assert os.path.isfile(c.driver_path)
     assert hasattr(c.driver(), "run")
     assert c.traffic["driver"] and c.limits
-    assert c.model["n_embd"] % c.model["n_head"] == 0
+    c.family().check_config(c.model, c.config["reduced"])
     reported = {m["name"] for m in c.end_to_end}
     assert "setup_s" in reported and len(reported) >= 2
     assert c.per_layer, "every cell reports a per-layer metric"
@@ -82,10 +86,77 @@ def test_every_config_file_is_under_paths_and_used():
     for c in MANIFEST["configs"]:
         assert c["name"] in used
         assert any(c["file"].startswith(p + "/") for p in MANIFEST["paths"])
-        cfg = manifest.load_json(os.path.join(ROOT, c["file"]))
-        assert cfg["n_inner"] == 4 * cfg["n_embd"]
-        for key in c["reduced"]:
-            assert not key.endswith(("_dim", "_rank")) and key not in ("n_embd", "n_inner")
+        cell = manifest.Cell(MANIFEST, next(
+            w["name"] for w in MANIFEST["workloads"] if w["config"] == c["name"]))
+        assert cell.model == manifest.load_json(os.path.join(ROOT, c["file"]))
+        cell.family().check_config(cell.model, c["reduced"])
+
+
+# What a cell's driver asks of its configuration's family, beside what
+# every cell asks; and the kernels the roofline readers of this tree name.
+ASKED_BY_ALL = ("check_config", "vocab_size", "make_params", "make_leaf",
+                "param_shapes", "reference_params", "kernel_work")
+ASKED_BY_DRIVER = {
+    "serve-closed": ("decode_model", "next_token_logits", "logit_gaps",
+                     "prefill_flops", "decode_flops"),
+    "train-windows": ("loss_fn", "row_shardings", "change_norms", "adam_reference",
+                      "train_flops_per_token"),
+}
+KERNELS_OF_METRIC = {"kernel.paged_decode_roofline.decode": readers.PAGED_KERNELS,
+                     "kernel.flash_roofline.train": readers.FLASH_KERNELS}
+FACTS_OF_DRIVER = {"serve-closed": {"rows": 3.5, "context": 200.5, "engine": {}},
+                   "train-windows": {"rows": 4, "seq": 128}}
+
+
+def _cells_by_config():
+    out = []
+    for mf, data_dir in ((MANIFEST, None), (TINY_MANIFEST, TINY_DIR)):
+        for c in mf["configs"]:
+            cells = [w["name"] for w in mf["workloads"] if w["config"] == c["name"]]
+            out.append(pytest.param(mf, data_dir, cells, id=c["name"]))
+    return out
+
+
+@pytest.mark.parametrize("mf,data_dir,cells", _cells_by_config())
+def test_every_configs_family_file_gives_what_its_cells_ask(mf, data_dir, cells):
+    assert cells, "a configuration no cell uses"
+    for name in cells:
+        c = manifest.Cell(mf, name, data_dir=data_dir)
+        assert os.path.isfile(c.family_path), c.family_path
+        assert os.path.basename(c.family_path) == c.model["family"] + ".py"
+        fam, driver = c.family(), c.traffic["driver"]
+        assert fam is c.family(), "loaded once a process"
+        for asked in ASKED_BY_ALL + ASKED_BY_DRIVER[driver]:
+            assert callable(getattr(fam, asked, None)), (c.model["family"], asked)
+        fam.check_config(c.model, c.config["reduced"])
+        assert fam.vocab_size(c.model) > 0
+        import jax
+
+        dtypes = {str(leaf.dtype) for leaf in jax.tree.leaves(fam.param_shapes(c.model))}
+        assert c.model.get("param_dtype", "float32") in dtypes
+        # every kernel that a roofline reader of this cell names
+        for metric in c.per_layer:
+            for kernel in KERNELS_OF_METRIC.get(metric["name"], ()):
+                flops, bytes_ = fam.kernel_work(kernel, c.model, FACTS_OF_DRIVER[driver])
+                assert flops > 0 and bytes_ > 0, kernel
+        with pytest.raises(KeyError):
+            fam.kernel_work("no_such_kernel", c.model, FACTS_OF_DRIVER[driver])
+        if driver == "train-windows":
+            assert fam.train_flops_per_token(c.model, 128) > 0
+        else:
+            assert fam.decode_flops(c.model, 64) < fam.prefill_flops(c.model, 64)
+
+
+def test_gpt2_refuses_a_file_that_is_not_gpt2_and_a_reduced_width():
+    model = manifest.Cell(MANIFEST, CELLS[0]).model
+    GPT2.check_config(model, ["layer_norm_epsilon"])
+    for reduced in (["n_embd"], ["n_inner"], ["head_dim"], ["kv_lora_rank"]):
+        with pytest.raises(ValueError):
+            GPT2.check_config(model, reduced)
+    with pytest.raises(ValueError):
+        GPT2.check_config(dict(model, n_inner=model["n_inner"] + 64), [])
+    with pytest.raises(ValueError):
+        GPT2.check_config(dict(model, n_head=model["n_head"] + 1), [])
 
 
 # ------------------------------------------------------------------ traffic
@@ -142,22 +213,33 @@ XL = dict(n_layer=48, n_embd=1600, n_head=25, n_inner=6400, vocab_size=50257)
     (XL, 1_474_560_000, 9.8017, 3.18856),
 ])
 def test_flop_counts_against_hand_worked_values(model, mm, train_gf, decode_gf):
-    assert counts.matmul_params(model) == mm
+    assert GPT2.matmul_params(model) == mm
     d, v, layers = model["n_embd"], model["vocab_size"], model["n_layer"]
     # by hand: 2 N per token, attention 4 d per pair per layer over
     # S (S + 1) / 2 pairs, the head at S - 1 of S positions; x 3 for training
     s = 1024
     fwd = 2 * mm * s + layers * 4 * d * (s * (s + 1) // 2) + 2 * d * v * (s - 1)
-    assert counts.train_flops_per_token(model, s) == pytest.approx(3 * fwd / s)
-    assert counts.train_flops_per_token(model, s) / 1e9 == pytest.approx(train_gf, rel=1e-3)
-    assert counts.decode_flops(model, 256) == 2 * mm + layers * 4 * d * 256 + 2 * d * v
-    assert counts.decode_flops(model, 256) / 1e9 == pytest.approx(decode_gf, rel=1e-4)
-    assert counts.prefill_flops(model, 512) == (
+    assert GPT2.train_flops_per_token(model, s) == pytest.approx(3 * fwd / s)
+    assert GPT2.train_flops_per_token(model, s) / 1e9 == pytest.approx(train_gf, rel=1e-3)
+    assert GPT2.decode_flops(model, 256) == 2 * mm + layers * 4 * d * 256 + 2 * d * v
+    assert GPT2.decode_flops(model, 256) / 1e9 == pytest.approx(decode_gf, rel=1e-4)
+    assert GPT2.prefill_flops(model, 512) == (
         2 * mm * 512 + layers * 4 * d * (512 * 513 // 2) + 2 * d * v)
-    assert counts.decode_kv_bytes(model, 256) == layers * 2 * 256 * d * 2
-    assert counts.flash_flops(model, 2, s, False) == 4 * d * (s * (s + 1) // 2) * 2
-    assert counts.flash_flops(model, 2, s, True) == 2 * counts.flash_flops(model, 2, s, False)
-    assert counts.flash_bytes(model, 2, s, False) == 4 * 2 * s * d * 2
+    assert GPT2.decode_kv_bytes(model, 256) == layers * 2 * 256 * d * 2
+    assert GPT2.flash_flops(model, 2, s, False) == 4 * d * (s * (s + 1) // 2) * 2
+    assert GPT2.flash_flops(model, 2, s, True) == 2 * GPT2.flash_flops(model, 2, s, False)
+    assert GPT2.flash_bytes(model, 2, s, False) == 4 * 2 * s * d * 2
+    # a kernel's work is one layer's, whatever the depth; the two backward
+    # kernels together are the whole backward
+    one = dict(model, n_layer=1)
+    train = {"rows": 2, "seq": s}
+    assert GPT2.kernel_work("flash_fwd", model, train) == (
+        GPT2.flash_flops(one, 2, s, False), GPT2.flash_bytes(one, 2, s, False))
+    back = [GPT2.kernel_work(k, model, train) for k in ("flash_bwd_dkv", "flash_bwd_dq")]
+    assert tuple(map(sum, zip(*back))) == (
+        GPT2.flash_flops(one, 2, s, True), GPT2.flash_bytes(one, 2, s, True))
+    assert GPT2.kernel_work("paged_attention", model, {"rows": 3.5, "context": 200.5}) == (
+        3.5 * 4 * d * 200.5, 3.5 * 2 * 200.5 * d * 2)
 
 
 def test_roofline_says_which_peak_bounds():
@@ -222,35 +304,41 @@ def test_reference_matches_the_programs_forward_and_loss():
     import jax.numpy as jnp
     from autodist_tpu.models import transformer as T
 
-    params = weights.make_params(TINY, 2 ** 31 + 77)
+    params = GPT2.make_params(TINY, 2 ** 31 + 77)
     cfg = T.TransformerConfig(vocab_size=211, num_layers=2, d_model=64, num_heads=4,
                               d_ff=256, max_seq_len=32, dtype=jnp.float32,
                               attention_impl="dot")
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, 211, (3, 32)), jnp.int32)
-    got = np.asarray(reference.logits(params, tokens, TINY))
+    got = np.asarray(GPT2.logits(params, tokens, TINY))
     want = np.asarray(T.forward(params, tokens, cfg))
     assert np.abs(got - want).max() < 1e-5
-    s, n = reference.loss_sum(params, tokens, TINY)
+    s, n = GPT2.loss_sum(params, tokens, TINY)
     assert float(s) / n == pytest.approx(float(T.loss_fn(params, {"tokens": tokens}, cfg)), rel=1e-5)
-    again = weights.change_norms(TINY, 2 ** 31 + 77, params)
+    again = GPT2.change_norms(TINY, 2 ** 31 + 77, params)
     assert len(again) == 36 and max(again) < 1e-6, "a leaf made again alone is the same leaf"
-    assert max(weights.change_norms(TINY, 5, params)) > 0
+    assert max(GPT2.change_norms(TINY, 5, params)) > 0
+    path = ("layers_1", "mlp", "fc2", "kernel")
+    alone = GPT2.make_leaf(TINY, 2 ** 31 + 77, path)
+    assert np.array_equal(np.asarray(alone), np.asarray(params["layers_1"]["mlp"]["fc2"]["kernel"]))
+    half = GPT2.make_leaf(dict(TINY, param_dtype="bfloat16"), 2 ** 31 + 77, path)
+    assert half.dtype == jnp.bfloat16
+    assert np.array_equal(np.asarray(half), np.asarray(alone.astype(jnp.bfloat16)))
 
 
 def test_reference_adam_matches_optax():
     import jax
     import optax
 
-    params = weights.make_params(TINY, 11)
+    params = GPT2.make_params(TINY, 11)
     rows = traffic.train_rows({"rows": 12, "seq_len": 32}, 211, 3)
     batches = [rows[0:4], rows[4:8], rows[8:12]]
-    losses, grads, change = reference.adam_reference(
+    losses, grads, change = GPT2.adam_reference(
         params, batches, TINY, learning_rate=3e-4, block_rows=2)
     tx = optax.adam(3e-4)
     p, o = params, tx.init(params)
 
     def loss(q, b):
-        s, n = reference.loss_sum(q, b, TINY)
+        s, n = GPT2.loss_sum(q, b, TINY)
         return s / n
 
     for i, b in enumerate(batches):
