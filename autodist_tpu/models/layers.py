@@ -274,3 +274,64 @@ def sigmoid_xent(logits, labels):
     return jnp.mean(
         jnp.maximum(logits, 0) - logits * labels + jnp.log1p(jnp.exp(-jnp.abs(logits)))
     )
+
+
+# ---------------------------------------------------------------------- rmsnorm
+def rmsnorm_init(dim: int, unit_offset: bool = False):
+    """RMSNorm weight: zeros where the norm adds a unit offset (the scale
+    is ``1 + weight``), ones where it does not."""
+    return {"weight": jnp.zeros((dim,)) if unit_offset else jnp.ones((dim,))}
+
+
+def rmsnorm(p, x, eps: float, unit_offset: bool = False):
+    """``x / sqrt(mean(x^2) + eps) * scale`` with ``scale = weight`` or
+    ``1 + weight``; mean and scale in float32, result in ``x``'s type."""
+    x32 = x.astype(jnp.float32)
+    w = p["weight"].astype(jnp.float32)
+    y = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
+    return (y * (1.0 + w if unit_offset else w)).astype(x.dtype)
+
+
+# ------------------------------------------------------------------------- rope
+def rope(x, positions, theta: float):
+    """Rotary position embedding over the whole head dimension, in the
+    rotate-half convention: ``x [..., H, D]`` at ``positions [...]`` (one
+    per leading index). Pair ``(i, i + D/2)`` turns by ``positions *
+    theta^(-2i/D)``; angles and the rotation in float32, result in ``x``'s
+    type."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions.astype(jnp.float32)[..., None, None] * inv_freq
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., : d // 2], x32[..., d // 2:]
+    return (x32 * cos + jnp.concatenate([-x2, x1], axis=-1) * sin).astype(x.dtype)
+
+
+# -------------------------------------------------------------------- gated mlp
+def gated_mlp_init(rng, dim: int, hidden: int):
+    k = jax.random.split(rng, 3)
+    return {"gate": dense_init(k[0], dim, hidden, use_bias=False),
+            "up": dense_init(k[1], dim, hidden, use_bias=False),
+            "down": dense_init(k[2], hidden, dim, use_bias=False)}
+
+
+def gated_mlp(p, x, *, compute_dtype=None):
+    """``down(silu(gate x) * up x)``: the products accumulate in float32
+    and come back in ``compute_dtype``."""
+    g = dense(p["gate"], x, compute_dtype=compute_dtype)
+    u = dense(p["up"], x, compute_dtype=compute_dtype)
+    return dense(p["down"], jax.nn.silu(g) * u, compute_dtype=compute_dtype)
+
+
+# ------------------------------------------------------------------ untied head
+def lm_head(p, x, columns=None, *, compute_dtype=None):
+    """Logits from a head of its own (no tie to the embedding), in
+    float32: ``x [..., D] @ kernel [D, V']``, the first ``columns`` of the
+    kernel where only those are wanted (a head that holds several
+    next-offset predictions side by side)."""
+    k = p["kernel"] if columns is None else p["kernel"][:, :columns]
+    if compute_dtype is not None:
+        x, k = x.astype(compute_dtype), k.astype(compute_dtype)
+    return jnp.matmul(x, k, preferred_element_type=jnp.float32)

@@ -58,6 +58,9 @@ class TransformerConfig:
     kv_quant: bool = False
     remat: bool = False
     mlm_mask_token: int = 0             # [MASK] id for the MLM objective
+    # LayerNorm epsilon of every norm in the model (GPT-2 publishes 1e-5;
+    # the default is what this model always ran with).
+    layer_norm_eps: float = 1e-6
 
     @property
     def head_dim(self) -> int:
@@ -157,7 +160,7 @@ def _attention(q, k, v, cfg: TransformerConfig):
 
 def _block(block_params, x, cfg: TransformerConfig):
     b, s, _ = x.shape
-    h = L.layernorm(block_params["ln1"], x)
+    h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
     attn_p = block_params["attn"]
     q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
     k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
@@ -168,7 +171,7 @@ def _block(block_params, x, cfg: TransformerConfig):
     o = _attention(q, k, v, cfg).reshape(b, s, cfg.d_model)
     x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
 
-    h = L.layernorm(block_params["ln2"], x)
+    h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
     h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
     h = jax.nn.gelu(h)
     h = L.dense(block_params["mlp"]["fc2"], h, compute_dtype=cfg.dtype)
@@ -186,7 +189,7 @@ def forward(params, tokens, cfg: TransformerConfig):
         block = jax.checkpoint(block)
     for i in range(cfg.num_layers):
         x = block(params[f"layers_{i}"], x)
-    x = L.layernorm(params["ln_f"], x)
+    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
     # Tied output embedding: one big [B*S, D] x [D, V] matmul on the MXU.
     logits = x.astype(cfg.dtype) @ params["embed"]["embedding"].T.astype(cfg.dtype)
     return logits.astype(jnp.float32)
@@ -247,7 +250,7 @@ def forward_prefill(params, tokens, length, cache, slot, cfg: TransformerConfig)
     x = x + L.embedding_lookup(params["pos_embed"], pos).astype(cfg.dtype)
     for i in range(cfg.num_layers):
         block_params = params[f"layers_{i}"]
-        h = L.layernorm(block_params["ln1"], x)
+        h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
         attn_p = block_params["attn"]
         q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
         k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
@@ -264,12 +267,12 @@ def forward_prefill(params, tokens, length, cache, slot, cfg: TransformerConfig)
             (i, slot, 0, 0, 0))
         o = _dot_attention(q, k, v, causal=True).reshape(b, s, cfg.d_model)
         x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
-        h = L.layernorm(block_params["ln2"], x)
+        h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
         h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
         h = jax.nn.gelu(h)
         h = L.dense(block_params["mlp"]["fc2"], h, compute_dtype=cfg.dtype)
         x = x + h
-    x = L.layernorm(params["ln_f"], x)
+    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
     last = x[jnp.arange(b), length - 1]                      # [B, D]
     logits = (last.astype(cfg.dtype)
               @ params["embed"]["embedding"].T.astype(cfg.dtype))
@@ -296,7 +299,7 @@ def forward_decode_step(params, tokens, positions, cache, cfg: TransformerConfig
     mask = pa_ops.position_mask(max_len, positions)              # [B, L]
     for i in range(cfg.num_layers):
         block_params = params[f"layers_{i}"]
-        h = L.layernorm(block_params["ln1"], x)
+        h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
         attn_p = block_params["attn"]
         q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
         k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
@@ -315,12 +318,12 @@ def forward_decode_step(params, tokens, positions, cache, cfg: TransformerConfig
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         o = jnp.einsum("bhl,blhd->bhd", probs, cv).reshape(b, cfg.d_model)
         x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
-        h = L.layernorm(block_params["ln2"], x)
+        h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
         h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
         h = jax.nn.gelu(h)
         h = L.dense(block_params["mlp"]["fc2"], h, compute_dtype=cfg.dtype)
         x = x + h
-    x = L.layernorm(params["ln_f"], x)
+    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
     logits = (x.astype(cfg.dtype)
               @ params["embed"]["embedding"].T.astype(cfg.dtype))
     return jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32), cache
@@ -435,7 +438,7 @@ def forward_paged_prefill_chunk(params, tokens, start, length, cache,
     x = x + L.embedding_lookup(params["pos_embed"], emb_pos).astype(cfg.dtype)
     for i in range(cfg.num_layers):
         block_params = params[f"layers_{i}"]
-        h = L.layernorm(block_params["ln1"], x)
+        h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
         attn_p = block_params["attn"]
         q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
         k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
@@ -450,12 +453,12 @@ def forward_paged_prefill_chunk(params, tokens, start, length, cache,
             k_scale=ks, v_scale=vs, impl=impl,
             compute_dtype=cfg.dtype).reshape(b, c, cfg.d_model)
         x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
-        h = L.layernorm(block_params["ln2"], x)
+        h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
         h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
         h = jax.nn.gelu(h)
         h = L.dense(block_params["mlp"]["fc2"], h, compute_dtype=cfg.dtype)
         x = x + h
-    x = L.layernorm(params["ln_f"], x)
+    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
     frontier = jnp.clip(length - 1 - start, 0, c - 1)
     last = x[jnp.arange(b), frontier]                             # [1, D]
     logits = (last.astype(cfg.dtype)
@@ -498,7 +501,7 @@ def forward_paged_decode_step(params, tokens, positions, cache, page_tables,
     x = x + L.embedding_lookup(params["pos_embed"], emb_pos).astype(cfg.dtype)
     for i in range(cfg.num_layers):
         block_params = params[f"layers_{i}"]
-        h = L.layernorm(block_params["ln1"], x)
+        h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
         attn_p = block_params["attn"]
         q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
         k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
@@ -513,12 +516,12 @@ def forward_paged_decode_step(params, tokens, positions, cache, page_tables,
             k_scale=ks, v_scale=vs, impl=impl,
             compute_dtype=cfg.dtype).reshape(b, cfg.d_model)
         x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
-        h = L.layernorm(block_params["ln2"], x)
+        h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
         h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
         h = jax.nn.gelu(h)
         h = L.dense(block_params["mlp"]["fc2"], h, compute_dtype=cfg.dtype)
         x = x + h
-    x = L.layernorm(params["ln_f"], x)
+    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
     logits = (x.astype(cfg.dtype)
               @ params["embed"]["embedding"].T.astype(cfg.dtype))
     if return_logits:
@@ -599,7 +602,7 @@ def forward_paged_verify(params, tokens, positions, cache, page_tables,
     x = x + L.embedding_lookup(params["pos_embed"], emb_pos).astype(cfg.dtype)
     for i in range(cfg.num_layers):
         block_params = params[f"layers_{i}"]
-        h = L.layernorm(block_params["ln1"], x)
+        h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
         attn_p = block_params["attn"]
         q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
         k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
@@ -614,12 +617,12 @@ def forward_paged_verify(params, tokens, positions, cache, page_tables,
             k_scale=ks, v_scale=vs, impl=impl,
             compute_dtype=cfg.dtype).reshape(b, k1, cfg.d_model)
         x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
-        h = L.layernorm(block_params["ln2"], x)
+        h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
         h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
         h = jax.nn.gelu(h)
         h = L.dense(block_params["mlp"]["fc2"], h, compute_dtype=cfg.dtype)
         x = x + h
-    x = L.layernorm(params["ln_f"], x)
+    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
     logits = (x.astype(cfg.dtype)
               @ params["embed"]["embedding"].T.astype(cfg.dtype))
     if samp is None:

@@ -8,7 +8,9 @@ page (Dao et al., arXiv 2205.14135, rendered over pages instead of contiguous
 K blocks), the position mask folded into the block loop, no materialized
 ``[B, P * page_len, H, D]`` timeline. Three entry points match the engine's
 compiled programs: decode step (one query per row), spec verify (K+1 queries
-per row), and prefill-chunk (one row, C queries).
+per row), and prefill-chunk (one row, C queries). A second kernel,
+``eva_paged_attention``, attends over a window ring and chunk summaries (two
+table segments with their own valid counts, one softmax; bottom of the file).
 
 Underneath either impl sits optional int8 KV quantization with per-position
 per-head scales (``quantize_kv`` / ``dequantize_kv``): pages store int8 plus
@@ -36,6 +38,7 @@ exercises the same kernel logic the TPU compiles).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Optional
 
 import jax
@@ -344,3 +347,256 @@ def paged_verify_attention(q, k_pages, v_pages, page_tables, rows_pos, *,
     logits = apply_mask(logits, mask[:, None, :, :])
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqt,bthd->bqhd", probs, cv)
+
+
+# ------------------------------------------------ window ring + chunk summaries
+# A cache whose timeline is not one page per ``page_len`` positions (EVA,
+# arXiv 2302.04542): each row's table is ``[ring | summaries]``. The ring
+# holds the exact keys of the open window (position ``p`` at slot
+# ``p % window``), a summary page holds ``page_len`` chunk summaries
+# (chunk ``j`` at row ``j``, one chunk = ``page_len`` positions). Pages are
+# laid out heads-major, ``[n_pages, H, page_len, D]`` a layer, which is how
+# the kernel below contracts them: no transpose in either program. This is a
+# kernel of its own beside ``paged_attention`` (whose text, and so the
+# programs of the models that call it, stay as they were): it masks two
+# segments with their own valid counts under one softmax, walks several
+# pages a grid step (8 for a decode step, 64 for a chunk of queries, which
+# it takes a tile at a time), and multiplies bfloat16 operands.
+def eva_entry_counts(positions, window: int, chunk: int):
+    """``(exact, summaries)`` a query at ``positions`` sees: the keys of
+    its own window up to itself, and every chunk of every earlier window."""
+    return positions % window + 1, (positions // window) * (window // chunk)
+
+
+def chunk_summaries(k, v, mu, phi):
+    """One summary pair per chunk: ``k, v [..., H, c, D]`` (a chunk's
+    rotated keys and its values, as a page holds them), ``mu, phi [H, D]``.
+    ``k~ = sum_n softmax_n(s k_n.mu) k_n``, ``v~ = sum_n softmax_n(s
+    k_n.phi) v_n``, both softmaxes and sums in float32. Returns float32
+    ``[..., H, D]`` twice."""
+    hi = jax.lax.Precision.HIGHEST
+    scale = k.shape[-1] ** -0.5
+    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+
+    def pool(x, w):
+        logits = jnp.einsum("...hnd,hd->...hn", k32, w.astype(jnp.float32),
+                            precision=hi) * scale
+        return jnp.einsum("...hn,...hnd->...hd",
+                          jax.nn.softmax(logits, axis=-1), x, precision=hi)
+
+    return pool(k32, mu), pool(v32, phi)
+
+
+def _eva_limits(q_positions, ring_pages: int, page_len: int, window: int):
+    """Per query, in table-entry order (entry ``t`` of page ``e`` is
+    ``e * page_len + t``): the first masked ring entry and the first masked
+    summary entry."""
+    exact, n_sum = eva_entry_counts(q_positions, window, page_len)
+    return exact, n_sum + ring_pages * page_len
+
+
+def _eva_gather_attention(q4, k_pages, v_pages, page_tables, q_positions,
+                          ring_pages, window):
+    """The materialize-then-attend rendering: ``q4 [B, H, Q, D]``."""
+    b, h, n_q, d = q4.shape
+    page_len = k_pages.shape[2]
+    n_tables = page_tables.shape[1]
+
+    def timeline(pages):
+        g = pages[page_tables]                      # [B, P, H, L, D]
+        return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(
+            b, h, n_tables * page_len, d)
+
+    lim_ring, lim_sum = _eva_limits(q_positions, ring_pages, page_len, window)
+    t = jnp.arange(n_tables * page_len)
+    is_ring = t < ring_pages * page_len
+    admit = jnp.where(is_ring, t < lim_ring[..., None], t < lim_sum[..., None])
+    ck = timeline(k_pages).astype(q4.dtype)
+    # An entry no query of the row sees may hold anything (a recycled page,
+    # a summary whose window is open): 0 x NaN must not reach the sum.
+    seen = admit.any(axis=1)[:, None, :, None]                    # [B,1,T,1]
+    cv = jnp.where(seen, timeline(v_pages), 0).astype(q4.dtype)
+    logits = jnp.einsum("bhqd,bhtd->bhqt", q4, ck,
+                        preferred_element_type=jnp.float32) * (d ** -0.5)
+    logits = apply_mask(logits, admit[:, None])
+    probs = jax.nn.softmax(logits, axis=-1).astype(q4.dtype)
+    return jnp.einsum("bhqt,bhtd->bhqd", probs, cv,
+                      preferred_element_type=jnp.float32).astype(q4.dtype)
+
+
+def _eva_kernel(tables_ref, ring_max_ref, sum_max_ref, tile_lo_ring_ref,
+                tile_hi_ring_ref, tile_lo_sum_ref, tile_hi_sum_ref,
+                lim_ring_ref, lim_sum_ref, q_ref, *rest, page_len: int,
+                ring_pages: int, group: int, n_groups: int, q_tile: int,
+                scale: float):
+    """One (row, head block, page group) program. The page groups of a row
+    run in order, ring first, and carry float32 (m, l, acc) in VMEM. A
+    group past every query's valid count is skipped whole: its index map
+    points at the scratch page and nothing is computed. Inside a group the
+    queries go a tile of ``q_tile`` at a time: a tile none of whose queries
+    reaches the group is skipped (a chunk's early queries against the
+    ring's later keys), and only a tile the counts cut through builds a
+    mask."""
+    k_refs, v_refs = rest[:group], rest[group:2 * group]
+    o_ref, m_ref, l_ref, acc_ref = rest[2 * group:]
+    bi, gi = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(gi == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    span = group * page_len
+    base = gi * span
+    is_ring = gi * group < ring_pages
+    # ring entry 0 is every query's (its own window starts there), so the
+    # first group is live for every tile and seeds ``m`` with a finite logit
+    reach = jnp.where(is_ring, ring_max_ref[bi], sum_max_ref[bi])
+
+    @pl.when(base < reach)
+    def _attend():
+        k = jnp.concatenate([r[0] for r in k_refs], axis=1)  # [Hb, T, D]
+        v = jnp.concatenate([r[0] for r in v_refs], axis=1)
+        t_col = base + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
+        v = jnp.where((t_col < reach)[None], v, jnp.zeros_like(v))
+
+        def tile(rows, masked):
+            q = q_ref[0, :, rows, :]                         # [Hb, Tq, D]
+            s = jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * scale  # [Hb, Tq, T]
+            if masked:
+                t_abs = base + jax.lax.broadcasted_iota(
+                    jnp.int32, (q_tile, span), 1)
+                limit = jnp.where(is_ring, lim_ring_ref[0, rows],
+                                  lim_sum_ref[0, rows])      # [Tq, 1]
+                s = jnp.where((t_abs < limit)[None], s, NEG_INF)
+            m = m_ref[:, rows]                               # [Hb, Tq, 1]
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            pexp = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_ref[:, rows] = alpha * l_ref[:, rows] + pexp.sum(
+                axis=-1, keepdims=True)
+            acc_ref[:, rows] = acc_ref[:, rows] * alpha + jax.lax.dot_general(
+                pexp.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)          # [Hb, Tq, D]
+            m_ref[:, rows] = m_new
+
+        for j in range(q_ref.shape[2] // q_tile):
+            rows = slice(j * q_tile, (j + 1) * q_tile)
+            lo = jnp.where(is_ring, tile_lo_ring_ref[bi, j],
+                           tile_lo_sum_ref[bi, j])
+            hi = jnp.where(is_ring, tile_hi_ring_ref[bi, j],
+                           tile_hi_sum_ref[bi, j])
+            whole = base + span <= lo
+            pl.when(whole)(functools.partial(tile, rows, False))
+            pl.when((base < hi) & jnp.logical_not(whole))(
+                functools.partial(tile, rows, True))
+
+    @pl.when(gi == n_groups - 1)
+    def _finalize():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _eva_blocking(h: int, n_q: int, d: int, n_tables: int, ring_pages: int,
+                  page_len: int):
+    """``(pages a grid step, heads a grid step, queries a tile)``. A step
+    walks as many pages as divide both segments, up to 128 entries for a
+    handful of queries (one MXU tile of keys) and up to 1,024 for a chunk
+    of them: the running maximum, the sum and the accumulator's rescaling
+    are paid once a step, and with them a chunk's attention costs 0.57 ms
+    for every 128 entries it sees at 128 a step, 0.40 at 512 and 0.34 at
+    1,024 (16 calls at 32 heads of 128 on one v5e; PERF.md section 6, PR
+    30). Queries go in tiles of up to 256, and a step takes as many heads
+    as keep a tile's float32 scores and the accumulator within 4 MB each
+    (two heads a step read a third slower than four)."""
+    keys = 128 if n_q <= 16 else 1024
+    group = math.gcd(math.gcd(ring_pages, n_tables - ring_pages),
+                     max(1, keys // page_len))
+    q_tile = math.gcd(n_q, 256)
+    hb = h
+    while hb % 2 == 0 and max(
+            hb * q_tile * group * page_len, hb * n_q * max(d, 128)) * 4 > (4 << 20):
+        hb //= 2
+    return group, hb, q_tile
+
+
+def _eva_kernel_attention(q4, k_pages, v_pages, page_tables, q_positions,
+                          ring_pages, window, interpret):
+    if interpret is None:
+        interpret = _should_interpret()
+    b, h, n_q, d = q4.shape
+    page_len = k_pages.shape[2]
+    n_tables = page_tables.shape[1]
+    group, hb, q_tile = _eva_blocking(
+        h, n_q, d, n_tables, ring_pages, page_len)
+    n_groups = n_tables // group
+    lim_ring, lim_sum = _eva_limits(q_positions.astype(jnp.int32),
+                                    ring_pages, page_len, window)
+    ring_max = lim_ring.max(axis=1)
+    sum_max = lim_sum.max(axis=1)
+    # per tile of queries, the least and the largest count in each segment
+    ring_t = lim_ring.reshape(b, n_q // q_tile, q_tile)
+    sum_t = lim_sum.reshape(b, n_q // q_tile, q_tile)
+    tile_lims = (ring_t.min(axis=2), ring_t.max(axis=2),
+                 sum_t.min(axis=2), sum_t.max(axis=2))
+
+    def page_spec(j):
+        def index(bi, hi, gi, t, rmax, smax, *_):
+            e = gi * group + j
+            reach = jnp.where(e < ring_pages, rmax[bi], smax[bi])
+            return (jnp.where(e * page_len < reach, t[bi, e], 0), hi, 0, 0)
+
+        return pl.BlockSpec((1, hb, page_len, d), index)
+
+    lim_spec = pl.BlockSpec((1, n_q, 1), lambda bi, hi, gi, *_: (bi, 0, 0))
+    q_spec = pl.BlockSpec((1, hb, n_q, d), lambda bi, hi, gi, *_: (bi, hi, 0, 0))
+    pages = [page_spec(j) for j in range(group)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(b, h // hb, n_groups),
+        in_specs=[lim_spec, lim_spec, q_spec] + pages + pages,
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((hb, n_q, 1), jnp.float32),   # m
+            pltpu.VMEM((hb, n_q, 1), jnp.float32),   # l
+            pltpu.VMEM((hb, n_q, d), jnp.float32),   # acc
+        ],
+    )
+    kernel = functools.partial(
+        _eva_kernel, page_len=page_len, ring_pages=ring_pages, group=group,
+        n_groups=n_groups, q_tile=q_tile, scale=d ** -0.5)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, n_q, d), q4.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name="eva_paged_attention",
+    )(page_tables.astype(jnp.int32), ring_max, sum_max, *tile_lims,
+      lim_ring[..., None], lim_sum[..., None], q4,
+      *([k_pages] * group), *([v_pages] * group))
+
+
+def eva_paged_attention(q, k_pages, v_pages, page_tables, q_positions, *,
+                        ring_pages: int, window: int, impl: str = "gather",
+                        interpret: Optional[bool] = None):
+    """Attention over a window ring and chunk summaries, one softmax:
+    ``q [B, H, Q, D]`` (``Q`` 1 for a decode step over ``B`` rows; a
+    prefill chunk is ``B`` 1 with ``Q`` queries of one window),
+    ``k_pages, v_pages [n_pages, H, page_len, D]`` one layer's pool,
+    ``page_tables [B, ring_pages + summary pages]``, ``q_positions [B, Q]``
+    absolute. A query at ``p`` sees ring entries ``0..p % window`` and the
+    first ``(p // window) * (window // page_len)`` summary rows; what lies
+    past a row's counts is never read into the sum, whatever it holds.
+    Returns ``[B, H, Q, D]`` in the query's type."""
+    _check_impl(impl)
+    if impl == "kernel":
+        return _eva_kernel_attention(q, k_pages, v_pages, page_tables,
+                                     q_positions, ring_pages, window,
+                                     interpret)
+    return _eva_gather_attention(q, k_pages, v_pages, page_tables,
+                                 q_positions, ring_pages, window)

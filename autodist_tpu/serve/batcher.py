@@ -303,6 +303,15 @@ class ContinuousBatcher:
         self._m_quant_physical = None
         self._m_quant_fp_equiv = None
 
+        # Window-ring accounting (a model whose CacheLayout states a
+        # window, serve/pages.py): lazily registered so plain paged
+        # engines add no metric families.
+        self._m_rolls = None
+        self._m_rolls_decode = None
+        self._m_summaries = None
+        self._m_ring_pages = None
+        self._m_summary_pages = None
+
         reg = registry or M.registry
         self._registry = reg
         self._m_depth = reg.gauge("serve_queue_depth")
@@ -751,10 +760,11 @@ class ContinuousBatcher:
 
         progress = self._prefill_round() or progress
         progress = self._decode_round() or progress
-        with obs_spans.span("serve.tick_metrics"):
+        with obs_spans.span("serve.tick_metrics") as sp:
             self._update_spec_metrics()
             self._update_prefix_metrics()
             self._update_quant_metrics()
+            self._update_ring_metrics(sp)
             with self._lock:
                 self._m_active.set(len(self._active))
             self._m_pool_util.set(self.engine.page_utilization)
@@ -979,6 +989,45 @@ class ContinuousBatcher:
         self._m_prefix_shared.set(float(stats.get("shared_pages", 0)))
         self._m_sharing_ratio.set(
             float(getattr(self.engine, "sharing_ratio", 1.0)))
+
+    def _update_ring_metrics(self, sp) -> None:
+        """Publish what a window ring adds to the pool's accounting, from
+        the engine's cumulative counts. No-op on a plain paged timeline —
+        the families exist only where a model states a ring, mirroring
+        the spec/prefix/quant pattern. Counters: ``serve_window_rolls_total``
+        (a row's position reached a multiple of the window, in a prefill
+        chunk or a decode step), ``serve_window_rolls_decode_total`` (the
+        decode step's part of them), ``serve_summary_chunks_total`` (chunk
+        summaries made). Gauges: ``serve_ring_pages_in_use`` and
+        ``serve_summary_pages_in_use``, the two kinds of page behind
+        ``serve_page_pool_utilization``. The same five readings ride the
+        ``serve.tick_metrics`` span, so a reader of the span ring has the
+        counters on the spans' clock. There is no ``serve.window_roll``
+        span: the host does nothing at a boundary."""
+        layout = getattr(self.engine, "layout", None)
+        if layout is None or not layout.window:
+            return
+        if self._m_rolls is None:
+            reg = self._registry
+            self._m_rolls = reg.counter("serve_window_rolls_total")
+            self._m_rolls_decode = reg.counter(
+                "serve_window_rolls_decode_total")
+            self._m_summaries = reg.counter("serve_summary_chunks_total")
+            self._m_ring_pages = reg.gauge("serve_ring_pages_in_use")
+            self._m_summary_pages = reg.gauge("serve_summary_pages_in_use")
+        eng = self.engine
+        for counter, now in ((self._m_rolls, eng.window_rolls),
+                             (self._m_rolls_decode, eng.window_rolls_decode),
+                             (self._m_summaries, eng.summary_chunks)):
+            counter.inc(now - counter.value)
+        ring, summary = eng.ring_pages_in_use, eng.summary_pages_in_use
+        self._m_ring_pages.set(ring)
+        self._m_summary_pages.set(summary)
+        sp["window_rolls"] = eng.window_rolls
+        sp["window_rolls_decode"] = eng.window_rolls_decode
+        sp["summary_chunks"] = eng.summary_chunks
+        sp["ring_pages"] = ring
+        sp["summary_pages"] = summary
 
     def _update_quant_metrics(self) -> None:
         """Publish the physical-vs-quantized pool byte split. No-op on fp

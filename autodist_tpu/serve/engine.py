@@ -12,16 +12,21 @@ path), batches shard over the mesh data axis, and GSPMD inserts the
 collectives for model-sharded parameters exactly as in training.
 
 Decode state is a **paged KV-cache** (the vLLM rendering of GSPMD-style
-static annotations, arXiv 2105.04663): ONE fixed pool of
-``[layers, n_pages, page_len, heads, head_dim]`` device pages sized from
+static annotations, arXiv 2105.04663): ONE fixed pool of device pages
+(the zoo transformer's ``[layers, n_pages, page_len, heads, head_dim]``, or
+whatever leaves the model's ``init_paged_cache`` gives, the page dim where
+its :class:`~autodist_tpu.serve.pages.CacheLayout` says) sized from
 ``ResourceSpec`` HBM headroom and donated through the compiled steps, with
 per-request page tables (host int32 lists, ``serve/pages.py`` — the one
 allocator home) padded to a static width. The engine compiles exactly TWO
 serving programs regardless of the request-length mix: one decode step over
 every slot row, and one fixed-size prefill chunk — long prompts prefill
 chunk by chunk, interleaved with decode ticks by the batcher, so a 4k-token
-prompt never stalls in-flight decodes. Admission reserves pages
-all-or-nothing; retirement recycles them in the same tick.
+prompt never stalls in-flight decodes. Admission reserves, all-or-nothing,
+the pages the whole ``prompt + max_new`` timeline needs under the model's
+layout (one per ``page_len`` positions; or, over a window ring, the ring's
+pages and one summary page per ``page_len**2`` positions); retirement
+recycles them in the same tick.
 
 :class:`BucketedInferenceEngine` keeps the previous length-bucketed stacked
 slot pools as the comparison baseline the serve selftest measures the paged
@@ -69,8 +74,9 @@ class DecodeModel:
     Paged surface (the production engine; all three required):
 
     - ``init_paged_cache(n_pages, page_len) -> cache`` pytree whose
-      rank>=2 leaves carry the page dim at dim 1 (the engine shards it
-      over the mesh data axis);
+      leaves carry the page dim at ``cache_layout.page_axis`` (dim 1 of
+      rank>=2 leaves where the model states no layout; the engine shards
+      it over the mesh data axis);
     - ``prefill_chunk(params, tokens [1,C], start, length, cache,
       page_table [P]) -> (next_token [1], cache)`` — writes prompt
       positions ``[start, start+C)`` through the page table; the returned
@@ -85,6 +91,14 @@ class DecodeModel:
 
     ``eos_id``: generation stops when emitted (None = length-only);
     ``max_len``: the model's positional ceiling.
+
+    ``cache_layout`` (:class:`~autodist_tpu.serve.pages.CacheLayout`): what
+    the model states about a cache that is not one page per ``page_len``
+    positions: its page length, a window ring with chunk summaries behind
+    it, the prefill chunk it asks for and the constraint any chunk must
+    keep, the page dim of its leaves. None is the plain paged timeline.
+    The engine sizes tables and reservations from it; it refuses prefix
+    sharing, int8 pages and speculative verification over a ring.
 
     ``autodist_tpu.models.transformer.decode_model(cfg)`` builds one for
     the zoo transformer; any model matching the contract serves the same
@@ -105,6 +119,7 @@ class DecodeModel:
     verify_paged: Optional[Callable[..., Tuple[Any, Any, Any]]] = None
     eos_id: Optional[int] = None
     max_len: Optional[int] = None
+    cache_layout: Optional[serve_pages.CacheLayout] = None
 
 
 @dataclass(frozen=True)
@@ -222,8 +237,23 @@ class InferenceEngine(_EngineBase):
     Exactly two programs compile (``compiled_programs`` counts them): the
     decode step over all ``n_slots`` rows and the fixed-``prefill_chunk``
     prefill — admission, chunking, retirement and any request-length mix
-    never recompile anything.
+    never recompile anything, whatever layout the model's cache has (a
+    window closing over a ring is the same two programs: they read which
+    entries a query sees off ``positions``).
+
+    A row mid-prefill has its next chunk dispatched right behind each
+    decode step (``prefill_lookahead``), before the host waits for that
+    step's tokens: the device then goes from the decode step into the chunk
+    while the host emits, keeps its books and prepares the next tick, and a
+    tick that carries a chunk is as long as its two programs run. The
+    programs reach the device in the order they always did (decode step,
+    chunk, decode step, ...); only a prompt's final chunk, whose token the
+    tick waits for, is left to :meth:`prefill_step`.
     """
+
+    # Subclasses whose prefill_step does more than the target's chunk turn
+    # this off (the speculative engine's draft shadows every chunk).
+    prefill_lookahead = True
 
     def __init__(
         self,
@@ -260,8 +290,20 @@ class InferenceEngine(_EngineBase):
         if n_slots % self._data_degree:
             n_slots += self._data_degree - n_slots % self._data_degree
         self.n_slots = n_slots
-        self.page_len = int(page_len)
-        self.prefill_chunk = int(prefill_chunk or page_len)
+        # The model's statement of its cache; none is the plain paged
+        # timeline at the page length asked for.
+        layout = decode_model.cache_layout
+        if layout is None:
+            layout = serve_pages.CacheLayout(page_len=int(page_len))
+        elif int(page_len) != layout.page_len:
+            raise ValueError(
+                f"this model's cache has pages of {layout.page_len} "
+                f"positions; page_len={page_len} was asked for")
+        self.layout = layout
+        self.page_len = layout.page_len
+        self.prefill_chunk = int(
+            prefill_chunk or layout.prefill_chunk or self.page_len)
+        layout.check_chunk(self.prefill_chunk)
         # Static timeline ceiling: the positional limit rounded DOWN to a
         # multiple of lcm(page_len, chunk) — guarantees every chunk's pad
         # positions stay inside the static page-table width (see
@@ -275,7 +317,7 @@ class InferenceEngine(_EngineBase):
             raise ValueError(
                 f"max_len {ceiling} cannot fit one page_len={page_len} x "
                 f"prefill_chunk={self.prefill_chunk} quantum ({quantum})")
-        self.max_pages = self.max_len // self.page_len
+        self.max_pages = layout.table_width(self.max_len)
 
         # Pool sizing: explicit n_pages wins; else ResourceSpec HBM
         # headroom funds it (capped at the point more pages cannot help —
@@ -298,6 +340,12 @@ class InferenceEngine(_EngineBase):
         # cost unquantized" figure the capacity-x metrics divide by.
         self.kv_quant = isinstance(page_shaped, dict) and \
             "k_scale" in page_shaped
+        if layout.window and (self.kv_quant or prefix_cache):
+            raise serve_pages.CacheFeatureRefused(
+                ("int8 pages" if self.kv_quant else "prefix sharing")
+                + " over a window ring: the ring is overwritten as the "
+                "window moves and its summaries are made from unquantised "
+                "keys (ROADMAP.md Queue 2)")
         if self.kv_quant:
             fp_itemsize = np.dtype(jax.tree_util.tree_leaves(jax.eval_shape(
                 lambda: decode_model.init_cache(1, self.page_len)
@@ -375,6 +423,8 @@ class InferenceEngine(_EngineBase):
         # request's prefill chunks and decode steps by id (PR 14).
         self._request_ids: List[str] = [""] * n_slots
         self._prefill_pos = np.zeros(n_slots, np.int32)
+        # the chunk before _prefill_pos went out behind the last decode step
+        self._chunk_ahead = np.zeros(n_slots, bool)
         self._prefill_start = np.zeros(n_slots, np.int32)
         self._prefill_t0 = np.zeros(n_slots, np.float64)
         # Prefix-sharing bookkeeping: the slot's Lease on tree pages and
@@ -396,6 +446,14 @@ class InferenceEngine(_EngineBase):
         # per emitted token than plain greedy — serve/spec.py counts its
         # verify program through the same ledger).
         self.decode_invocations = 0
+        # Over a window ring: boundaries that rows' positions crossed (the
+        # window behind closes and its summaries become visible), those of
+        # them crossed by the decode step, and summaries made. Cumulative;
+        # the batcher publishes them (serve_window_rolls_total, ...). The
+        # host does nothing at a boundary: these only count.
+        self.window_rolls = 0
+        self.window_rolls_decode = 0
+        self.summary_chunks = 0
         # Replica identity carried into the chaos seams so a schedule can
         # target ONE replica of a fleet (replica_death injects
         # EngineDeadError only where host matches — docs/chaos.md).
@@ -452,16 +510,18 @@ class InferenceEngine(_EngineBase):
 
     # ------------------------------------------------------------ decode pool
     def _cache_shardings(self, init_cache, n_pages: int):
-        """Page dim (dim 1 of rank>=2 leaves) over the data axis; scalars
-        and vectors replicate. Evaluated on abstract shapes — no device
-        cache is built to derive its own sharding."""
+        """Page dim (where the model's layout says: dim 1 of rank>=2
+        leaves on the stacked pool, dim 0 of a leaf a layer) over the data
+        axis; scalars and vectors replicate. Evaluated on abstract shapes
+        — no device cache is built to derive its own sharding."""
         from autodist_tpu.kernel.mesh import data_sharding
 
         shaped = jax.eval_shape(lambda: init_cache(n_pages, self.page_len))
+        axis = self.layout.page_axis
 
         def leaf_sh(leaf):
-            if len(leaf.shape) >= 2 and leaf.shape[1] == n_pages:
-                return data_sharding(self.mesh, len(leaf.shape), dim=1)
+            if len(leaf.shape) >= 2 and leaf.shape[axis] == n_pages:
+                return data_sharding(self.mesh, len(leaf.shape), dim=axis)
             return NamedSharding(self.mesh, P())
 
         return jax.tree_util.tree_map(leaf_sh, shaped)
@@ -536,18 +596,34 @@ class InferenceEngine(_EngineBase):
 
     @property
     def written_tokens(self) -> int:
-        """Tokens actually resident in reserved pages (prompt progress for
-        prefilling slots, full timeline length for decoding ones)."""
+        """Rows actually resident in reserved pages (prompt progress for
+        prefilling slots, full timeline length for decoding ones; over a
+        window ring what the ring holds of them plus their summaries)."""
         total = 0
         for idx in np.flatnonzero(self._phase != _FREE):
             idx = int(idx)
             if self._phase[idx] == _PREFILL:
                 prompt = self._prompts[idx]
-                total += min(int(self._prefill_pos[idx]),
-                             len(prompt) if prompt is not None else 0)
+                n = min(int(self._prefill_pos[idx]),
+                        len(prompt) if prompt is not None else 0)
             else:
-                total += int(self._lengths[idx])
+                n = int(self._lengths[idx])
+            total += self.layout.resident_rows(n)
         return total
+
+    @property
+    def ring_pages_in_use(self) -> int:
+        """Pages that live tables hold in their ring segment (0 on a
+        plain timeline, whose pages are all exact)."""
+        if not self.layout.window:
+            return 0
+        return sum(t.n_exact for t in self._tables if t is not None)
+
+    @property
+    def summary_pages_in_use(self) -> int:
+        """Pages that live tables hold for chunk summaries."""
+        return sum(len(t.pages) - t.n_exact
+                   for t in self._tables if t is not None)
 
     @property
     def page_utilization(self) -> float:
@@ -702,7 +778,7 @@ class InferenceEngine(_EngineBase):
         lease: Optional[serve_prefix.Lease] = None
         start_pos = 0
         if self._prefix_cache is None:
-            table = self.pool.alloc(total)
+            table = self.pool.alloc(total, self.layout)
         else:
             # Prefix sharing: matched full blocks ride the SAME physical
             # pages (refcount++ under the lease); only the unmatched
@@ -732,7 +808,7 @@ class InferenceEngine(_EngineBase):
             return AdmissionDenied(
                 f"page pool exhausted ({self.pool.free_pages} of "
                 f"{self.pool.usable_pages} pages free; need "
-                f"{serve_pages.pages_for_tokens(total, self.page_len)})",
+                f"{self.layout.pages_for(total)})",
                 retryable=True)
         idx = int(free[0])
         self._phase[idx] = _PREFILL
@@ -817,18 +893,13 @@ class InferenceEngine(_EngineBase):
         one chunk per tick (chunked prefill interleaves with decode)."""
         return [Slot(int(i)) for i in np.flatnonzero(self._phase == _PREFILL)]
 
-    def prefill_step(self, slot: Slot) -> Optional[int]:
-        """Run ONE prefill chunk for ``slot``. Returns the first generated
-        token when the prompt is fully prefilled (the slot then joins the
-        decode batch next :meth:`step`), else None.
+    def _dispatch_chunk(self, idx: int):
+        """Dispatch the next chunk of row ``idx``'s prompt and advance its
+        position; returns ``(first token, still on the device; final)``.
 
         The ``serve.prefill_chunk`` span covers the host's preparation and
         the asynchronous DISPATCH of the chunk, not its run on the device
-        (that is the module ``jit_serve_prefill_chunk`` in a device trace);
-        only a final chunk waits, under ``serve.token_fetch``."""
-        idx = slot.index
-        if self._phase[idx] != _PREFILL:
-            raise ValueError(f"slot {idx} is not prefilling")
+        (that is the module ``jit_serve_prefill_chunk`` in a device trace)."""
         prompt = self._prompts[idx]
         start = int(self._prefill_pos[idx])
         c = self.prefill_chunk
@@ -845,8 +916,42 @@ class InferenceEngine(_EngineBase):
                 self.params, jnp.asarray(chunk), np.int32(start),
                 np.int32(len(prompt)), self._cache,
                 jnp.asarray(self._table_np[idx]), self._samp_dev(idx))
-        start += c
-        self._prefill_pos[idx] = start
+        if self.layout.window:
+            rolls, chunks = self.layout.rolls(
+                start, min(start + c, len(prompt)))
+            self.window_rolls += rolls
+            self.summary_chunks += chunks
+        self._prefill_pos[idx] = start + c
+        return first, final
+
+    def _dispatch_chunks_ahead(self) -> None:
+        """Behind a decode step that has just been dispatched: the next
+        chunk of every row mid-prefill, unless it is the prompt's last
+        (its token is fetched by the tick that owns it)."""
+        for idx in np.flatnonzero(self._phase == _PREFILL):
+            idx = int(idx)
+            if self._chunk_ahead[idx] or (
+                    int(self._prefill_pos[idx]) + self.prefill_chunk
+                    >= len(self._prompts[idx])):
+                continue
+            self._dispatch_chunk(idx)
+            self._chunk_ahead[idx] = True
+
+    def prefill_step(self, slot: Slot) -> Optional[int]:
+        """Run ONE prefill chunk for ``slot``. Returns the first generated
+        token when the prompt is fully prefilled (the slot then joins the
+        decode batch next :meth:`step`), else None. A chunk that already
+        went out behind the last decode step counts as this call's; only
+        a final chunk waits, under ``serve.token_fetch``."""
+        idx = slot.index
+        if self._phase[idx] != _PREFILL:
+            raise ValueError(f"slot {idx} is not prefilling")
+        if self._chunk_ahead[idx]:
+            self._chunk_ahead[idx] = False
+            return None
+        prompt = self._prompts[idx]
+        c = self.prefill_chunk
+        first, final = self._dispatch_chunk(idx)
         if not final:
             return None
         with obs_spans.span("serve.token_fetch", program="prefill_chunk"):
@@ -913,10 +1018,18 @@ class InferenceEngine(_EngineBase):
                     self._cache,
                     jnp.asarray(self._decode_table_np),
                     self._samp_dev())
+            if self.prefill_lookahead:
+                self._dispatch_chunks_ahead()
             with obs_spans.span("serve.token_fetch", program="decode_step"):
                 tokens = np.asarray(jax.device_get(tokens))
         for idx in decoding:
             idx = int(idx)
+            if self.layout.window:
+                rolls, chunks = self.layout.rolls(
+                    int(self._lengths[idx]), int(self._lengths[idx]) + 1)
+                self.window_rolls += rolls
+                self.window_rolls_decode += rolls
+                self.summary_chunks += chunks
             self._lengths[idx] += 1
             self._last_token[idx] = tokens[idx]
             out[Slot(idx)] = int(tokens[idx])
@@ -976,6 +1089,7 @@ class InferenceEngine(_EngineBase):
         self._prompts[idx] = None
         self._request_ids[idx] = ""
         self._prefill_pos[idx] = 0
+        self._chunk_ahead[idx] = False
         self._samp["temperature"][idx] = 0.0
         self._samp["top_k"][idx] = 0
         self._samp["top_p"][idx] = 1.0

@@ -24,6 +24,7 @@ capacity must shed typed, never hang or OOM; docs/chaos.md).
 from __future__ import annotations
 
 import threading
+from dataclasses import dataclass
 from typing import List, Optional
 
 import numpy as np
@@ -33,6 +34,8 @@ from autodist_tpu.chaos import hooks as chaos_hooks
 __all__ = [
     "DEFAULT_PAGE_LEN",
     "SCRATCH_PAGE",
+    "CacheFeatureRefused",
+    "CacheLayout",
     "PagePool",
     "PageTable",
     "build_pool",
@@ -49,19 +52,123 @@ def pages_for_tokens(n_tokens: int, page_len: int) -> int:
     return max(1, -(-int(n_tokens) // int(page_len)))
 
 
+class CacheFeatureRefused(ValueError):
+    """A feature of the serving stack was asked for over a cache that
+    cannot carry it (prefix sharing, int8 pages or speculative verification
+    over a window ring). Raised when the engine is built, never under
+    load."""
+
+
+@dataclass(frozen=True)
+class CacheLayout:
+    """What a model states about how its timeline lies on pages; the
+    engine derives table width, reservation, accounting and the prefill
+    chunk from it and knows no model by name.
+
+    ``window`` None is the plain paged timeline: position ``p`` lives on
+    table entry ``p // page_len``, for as long as the request does.
+
+    With a ``window`` the table is two segments, ``[ring | summaries]``.
+    The ring is ``window // page_len`` pages that the open window's exact
+    keys are written round (position ``p`` on entry ``(p % window) //
+    page_len``, overwriting what lay a window back); behind it every
+    ``page_len`` positions leave one summary row, ``page_len`` rows a page
+    (chunk ``j`` on entry ``ring_pages + j // page_len``, row ``j %
+    page_len``). The programs work out from ``positions`` alone which
+    entries a query sees, so the host does nothing when a window closes.
+
+    ``prefill_chunk`` is the chunk the model asks for; any chunk has to be
+    a multiple of ``page_len`` and, with a window, divide it (no chunk
+    straddles a window). ``page_axis`` is where the cache's leaves carry
+    the page dim: 1 for a pool stacked over layers, 0 for a leaf a layer.
+    """
+
+    page_len: int = DEFAULT_PAGE_LEN
+    window: Optional[int] = None
+    prefill_chunk: Optional[int] = None
+    page_axis: int = 1
+
+    def __post_init__(self):
+        if self.window is not None and self.window % self.page_len:
+            raise ValueError(f"window {self.window} is not a whole number "
+                             f"of {self.page_len}-position pages")
+        if self.prefill_chunk is not None:
+            self.check_chunk(self.prefill_chunk)
+
+    @property
+    def ring_pages(self) -> int:
+        """Table entries of the ring segment (0 on a plain timeline)."""
+        return self.window // self.page_len if self.window else 0
+
+    @property
+    def summary_span(self) -> int:
+        """Positions one summary page stands for."""
+        return self.page_len * self.page_len
+
+    def check_chunk(self, chunk: int) -> None:
+        if chunk % self.page_len or (self.window and self.window % chunk):
+            raise ValueError(
+                f"prefill chunk {chunk} has to be a multiple of the page "
+                f"({self.page_len})" + (f" that divides the window "
+                                        f"({self.window})" if self.window else ""))
+
+    def split(self, n_tokens: int):
+        """``(exact pages, summary pages)`` a timeline of ``n_tokens``
+        needs."""
+        exact = pages_for_tokens(n_tokens, self.page_len)
+        if not self.window:
+            return exact, 0
+        return (min(self.ring_pages, exact),
+                pages_for_tokens(n_tokens, self.summary_span))
+
+    def pages_for(self, n_tokens: int) -> int:
+        return sum(self.split(n_tokens))
+
+    def table_width(self, max_len: int) -> int:
+        """Static table entries a row of ``max_len`` positions needs."""
+        if not self.window:
+            return max_len // self.page_len
+        return self.ring_pages + -(-max_len // self.summary_span)
+
+    def resident_rows(self, n_tokens: int) -> int:
+        """Rows of reserved pages that ``n_tokens`` written positions
+        fill: the positions themselves, or what the ring holds of them
+        plus their finished summaries."""
+        if not self.window:
+            return n_tokens
+        return min(n_tokens, self.window) + n_tokens // self.page_len
+
+    def rolls(self, start: int, end: int):
+        """``(window boundaries, chunk ends)`` that writing positions
+        ``[start, end)`` passes: a boundary is a multiple of the window
+        reached (the window behind it closes), a chunk end a multiple of
+        the page (one summary made)."""
+        if not self.window:
+            return 0, 0
+        return (end // self.window - start // self.window,
+                end // self.page_len - start // self.page_len)
+
+
 class PageTable:
     """One request's page list: ``capacity`` timeline tokens of KV rows.
 
     Token position ``p`` lives at device page ``pages[p // page_len]``,
     offset ``p % page_len``. :meth:`padded` renders the static-shape int32
     row the compiled programs consume (pad entries point at scratch).
+
+    Under a :class:`CacheLayout` with a window the first ``n_exact`` pages
+    are the ring's and the rest hold summaries; :meth:`padded` then puts
+    the two runs at the start of their segments.
     """
 
-    __slots__ = ("pages", "page_len")
+    __slots__ = ("pages", "page_len", "n_exact", "ring_pages")
 
-    def __init__(self, pages: List[int], page_len: int):
+    def __init__(self, pages: List[int], page_len: int,
+                 n_exact: Optional[int] = None, ring_pages: int = 0):
         self.pages = list(pages)
         self.page_len = int(page_len)
+        self.n_exact = len(self.pages) if n_exact is None else int(n_exact)
+        self.ring_pages = int(ring_pages)
 
     @property
     def capacity(self) -> int:
@@ -71,7 +178,12 @@ class PageTable:
     def padded(self, max_pages: int) -> np.ndarray:
         """Static ``[max_pages]`` int32 row, padded with the scratch page."""
         row = np.full(max_pages, SCRATCH_PAGE, np.int32)
-        row[: len(self.pages)] = self.pages
+        if not self.ring_pages:
+            row[: len(self.pages)] = self.pages
+            return row
+        row[: self.n_exact] = self.pages[: self.n_exact]
+        rest = self.pages[self.n_exact:]
+        row[self.ring_pages: self.ring_pages + len(rest)] = rest
         return row
 
     def rewind(self, n_tokens: int) -> List[int]:
@@ -181,11 +293,16 @@ class PagePool:
         return max(0.0, 1.0 - float(written_tokens) / alloc)
 
     # ------------------------------------------------------------- allocation
-    def alloc(self, n_tokens: int) -> Optional[PageTable]:
+    def alloc(self, n_tokens: int,
+              layout: Optional[CacheLayout] = None) -> Optional[PageTable]:
         """Reserve pages for an ``n_tokens`` timeline, or None when the
         pool cannot cover it (all-or-nothing; the chaos seam may force
-        the None path to exercise the exhaustion contract)."""
-        need = pages_for_tokens(n_tokens, self.page_len)
+        the None path to exercise the exhaustion contract). ``layout``
+        says how many pages such a timeline needs where that is not one
+        per ``page_len`` positions."""
+        n_exact, n_summary = (layout or CacheLayout(self.page_len)).split(
+            n_tokens)
+        need = n_exact + n_summary
         if chaos_hooks.fire(chaos_hooks.SEAM_SERVE_PAGES,
                             need=need, tokens=int(n_tokens)) == "exhaust":
             return None
@@ -194,7 +311,8 @@ class PagePool:
                 return None
             got = [self._free.pop() for _ in range(need)]
             self._allocated.update(got)
-        return PageTable(got, self.page_len)
+        return PageTable(got, self.page_len, n_exact=n_exact,
+                         ring_pages=layout.ring_pages if layout else 0)
 
     def extend(self, table: PageTable, n_tokens: int) -> bool:
         """Grow ``table`` so it covers an ``n_tokens`` timeline.

@@ -129,6 +129,10 @@ class SpecDecodeEngine(InferenceEngine):
     all slot state, so the two cadences interleave correctly.
     """
 
+    # prefill_step advances the draft's prefill beside every target chunk,
+    # so no chunk may go out without it
+    prefill_lookahead = False
+
     def __init__(
         self,
         params: Any,
@@ -142,6 +146,13 @@ class SpecDecodeEngine(InferenceEngine):
         apply_fn: Optional[Callable] = None,
         **engine_kwargs,
     ):
+        for dm in (decode_model, draft_decode_model):
+            if dm is not None and dm.cache_layout is not None \
+                    and dm.cache_layout.window:
+                raise serve_pages.CacheFeatureRefused(
+                    "speculative verification over a window ring: a "
+                    "rejected draft would have to take back ring entries "
+                    "it overwrote (ROADMAP.md Queue 2)")
         super().__init__(params, plan, apply_fn=apply_fn,
                          decode_model=decode_model, **engine_kwargs)
         if decode_model is None or decode_model.verify_paged is None:

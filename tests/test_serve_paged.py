@@ -161,6 +161,67 @@ def test_chunked_prefill_interleaves_with_decode(paged, bucketed):
     assert got2 == ref_long
 
 
+def test_next_chunk_goes_out_behind_the_decode_step(paged):
+    """With a row decoding, a prompt's next chunk is dispatched inside the
+    decode step, between its dispatch and the wait for its tokens, so the
+    device never waits for the host between the two programs; the final
+    chunk stays with ``prefill_step``. The programs reach the device in the
+    order they always did, so the streams are those of an engine that
+    looks no chunk ahead; a row released mid-prefill leaves no chunk
+    owed."""
+    from autodist_tpu.obs import spans as obs_spans
+
+    p_short = np.array([5, 17, 3, 88, 2], np.int32)
+    p_long = np.arange(1, 29, dtype=np.int32)           # 4 chunks of 8
+
+    def serve(lookahead):
+        paged.prefill_lookahead = lookahead
+        tracer = obs_spans.get_tracer()
+        tracer.clear()
+        s1 = paged.admit(p_short, 8)
+        got1 = [prefill_all(paged, s1)]
+        s2 = paged.admit(p_long, 4)
+        got2, calls = [], 0
+        while not got2:
+            first = paged.prefill_step(s2)
+            calls += 1
+            if first is not None:
+                got2.append(first)
+            got1.append(paged.step()[s1])
+        chunks = [s for s in tracer.spans() if s.name == "serve.prefill_chunk"
+                  and s.attrs.get("prompt_len") == len(p_long)]
+        by_id = {s.id: s for s in tracer.spans()}
+        parents = [by_id[s.parent].name if s.parent in by_id else None
+                   for s in chunks]
+        paged.release(s1)
+        paged.release(s2)
+        return got1, got2, calls, parents, [s.attrs["final"] for s in chunks]
+
+    try:
+        ahead = serve(True)
+        plain = serve(False)
+    finally:
+        paged.prefill_lookahead = True
+    assert ahead[:3] == plain[:3] and ahead[2] == 4
+    assert ahead[4] == plain[4] == [False, False, False, True]
+    assert ahead[3] == [None, "serve.decode_step", "serve.decode_step", None]
+    assert plain[3] == [None] * 4
+
+    s1 = paged.admit(p_short, 8)
+    prefill_all(paged, s1)
+    s2 = paged.admit(p_long, 4)
+    paged.prefill_step(s2)
+    paged.step()                                # chunk 2 is out already
+    assert paged._chunk_ahead[s2.index]
+    paged.release(s2)
+    assert not paged._chunk_ahead.any()
+    s3 = paged.admit(p_long, 4)                 # the row again, from 0
+    assert s3.index == s2.index
+    assert prefill_all(paged, s3) == ahead[1][0]
+    paged.release(s1)
+    paged.release(s3)
+
+
 # ---------------------------------------------------------- page recycling
 def test_page_recycling_after_retirement(paged, bucketed):
     """Retired pages return to the pool and are REUSED (LIFO) by the next

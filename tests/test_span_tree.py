@@ -447,3 +447,33 @@ def test_derived_flash_tiles_compile_for_the_chip(one_chip, shape, dtype):
     assert s % tile == 0 and tile % 128 == 0
     assert F._step_bytes(F._heads_per_step(b * h, tile, s, d, jnp.dtype(dtype).itemsize),
                          tile, s, d, jnp.dtype(dtype).itemsize) <= F._VMEM_BYTES
+
+
+@pytest.mark.parametrize("rows, queries", [(4, 1), (1, 1024)],
+                         ids=["decode-step", "prefill-chunk"])
+def test_eva_kernel_compiles_for_the_chip_at_published_widths(one_chip, rows, queries):
+    """``eva_paged_attention`` at 32 heads of 128, pages of 16 heads-major, a
+    table of 128 ring + 128 summary pages and a pool of four rows: Mosaic
+    takes the page groups and head blocks the kernel derives, the call keeps
+    the name the benchmark's readers find it by, and nothing the size of a
+    pool leaf is copied around it."""
+    import importlib
+
+    PA = importlib.import_module("autodist_tpu.ops.paged_attention")
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(q, kp, vp, tables, pos):
+        return PA.eva_paged_attention(q, kp, vp, tables, pos, ring_pages=128,
+                                      window=2048, impl="kernel", interpret=False)
+
+    pool = sds((1025, 32, 16, 128))
+    text = analysis.compiled_text(
+        jax.jit(attend), sds((rows, 32, queries, 128)), pool, pool,
+        sds((rows, 256), jnp.int32), sds((rows, queries), jnp.int32))
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and calls[0].startswith("eva_paged_attention"), calls
+    import re
+
+    assert not re.search(r"= bf16\[1025,32,16,128\]\S* copy\(", text)
