@@ -334,30 +334,43 @@ def init_paged_kv_cache(cfg: TransformerConfig, n_pages: int, page_len: int,
                         dtype: Any = None,
                         quantized: Optional[bool] = None) -> Dict[str, Any]:
     """Paged decode cache: ONE pool of fixed-size KV pages shared by every
-    concurrent request — ``[num_layers, n_pages, page_len, heads,
-    head_dim]`` per projection. Which pages hold which request's timeline
-    is the engine's page tables (``serve/pages.py``); the arrays here are
-    donated through the two compiled serving programs and rewritten in
+    concurrent request — per projection a leaf a layer, ``[n_pages,
+    page_len, heads * head_dim]``. Which pages hold which request's
+    timeline is the engine's page tables (``serve/pages.py``); the leaves
+    are donated through the compiled serving programs and rewritten in
     place, so steady-state serving allocates nothing and slot utilization
     no longer depends on guessing a length distribution (the vLLM
     rendering of GSPMD's static-annotation premise, docs/serving.md).
 
+    Heads x head_dim lie together on the last axis because that is the
+    form the TPU compiler keeps row-major by itself: a leaf whose last dim
+    is a head_dim of 64 it holds with the page dim minor, and re-lays the
+    whole pool out around every write and every kernel call (PERF.md § 6,
+    PR 31). A layer's write touches that layer's leaf alone, and the paged
+    kernel reads ``(1, page_len, heads * head_dim)`` blocks of it as it
+    lies (``ops/paged_attention.py``).
+
     With ``cfg.kv_quant`` (or ``quantized=True``) the pages hold int8 with
-    f32 per-(page, position, head) scale planes alongside — same leading
-    dims, so the engine's dim1-keyed sharding, COW page copy, and byte
-    pricing all pick the scales up without special cases.
+    f32 per-(page, position, head) scale leaves ``[n_pages, page_len,
+    heads]`` alongside — the page dim leads there too, so the engine's
+    sharding, COW page copy, and byte pricing pick the scales up without
+    special cases.
     """
     if quantized is None:
         quantized = bool(getattr(cfg, "kv_quant", False))
-    shape = (cfg.num_layers, n_pages, page_len, cfg.num_heads, cfg.head_dim)
+    shape = (n_pages, page_len, cfg.num_heads * cfg.head_dim)
+
+    def leaves(shape, dtype):
+        return [jnp.zeros(shape, dtype) for _ in range(cfg.num_layers)]
+
     if quantized:
-        sshape = shape[:-1]
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(sshape, jnp.float32),
-                "v_scale": jnp.zeros(sshape, jnp.float32)}
+        sshape = (n_pages, page_len, cfg.num_heads)
+        return {"k": leaves(shape, jnp.int8),
+                "v": leaves(shape, jnp.int8),
+                "k_scale": leaves(sshape, jnp.float32),
+                "v_scale": leaves(sshape, jnp.float32)}
     dtype = dtype or cfg.dtype
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return {"k": leaves(shape, dtype), "v": leaves(shape, dtype)}
 
 
 def _resolve_paged_impl(cfg: TransformerConfig, batch: int,
@@ -371,23 +384,35 @@ def _resolve_paged_impl(cfg: TransformerConfig, batch: int,
                               page_len, cfg.num_heads)
 
 
+def _own_leaves(cache):
+    """The cache with leaf lists of its own: the programs below replace a
+    layer's leaf as they go and must not write into the caller's lists."""
+    return {name: list(leaves) for name, leaves in cache.items()}
+
+
 def _paged_scatter(cache, layer, page_of, off, k, v):
-    """Write one program's k/v rows through the page table indices —
-    quantize-on-scatter when the cache carries int8 pages (scales land in
-    the matching ``*_scale`` planes), plain dtype cast otherwise."""
+    """Write one program's k/v rows ``[..., H, D]`` into layer ``layer``'s
+    leaves through the page table indices (``page_of`` and ``off`` shaped
+    like the rows' leading dims) — quantize-on-scatter when the cache
+    carries int8 pages (scales land in the matching ``*_scale`` leaves),
+    plain dtype cast otherwise."""
+    def write(name, rows):
+        leaf = cache[name][layer]
+        cache[name][layer] = leaf.at[page_of, off].set(rows.astype(leaf.dtype))
+
+    def lanes(rows):                       # [..., H, D] -> [..., H * D]
+        return rows.reshape(rows.shape[:-2] + (-1,))
+
     if "k_scale" in cache:
         kq, ks = pa_ops.quantize_kv(k)
         vq, vs = pa_ops.quantize_kv(v)
-        cache["k"] = cache["k"].at[layer, page_of, off].set(kq)
-        cache["v"] = cache["v"].at[layer, page_of, off].set(vq)
-        cache["k_scale"] = cache["k_scale"].at[layer, page_of, off].set(ks)
-        cache["v_scale"] = cache["v_scale"].at[layer, page_of, off].set(vs)
+        write("k", lanes(kq))
+        write("v", lanes(vq))
+        write("k_scale", ks)
+        write("v_scale", vs)
     else:
-        cache_dtype = cache["k"].dtype
-        cache["k"] = cache["k"].at[layer, page_of, off].set(
-            k.astype(cache_dtype))
-        cache["v"] = cache["v"].at[layer, page_of, off].set(
-            v.astype(cache_dtype))
+        write("k", lanes(k))
+        write("v", lanes(v))
     return cache
 
 
@@ -426,7 +451,8 @@ def forward_paged_prefill_chunk(params, tokens, start, length, cache,
     unchanged; ``temperature<=0`` rows still return the argmax bit-exact.
     """
     b, c = tokens.shape
-    page_len = cache["k"].shape[2]
+    cache = _own_leaves(cache)
+    page_len = cache["k"][0].shape[1]
     pos = start + jnp.arange(c)                                   # [C] absolute
     page_of = page_table[pos // page_len]                         # [C]
     off = pos % page_len
@@ -491,7 +517,8 @@ def forward_paged_decode_step(params, tokens, positions, cache, page_tables,
     Returns ``(next_token [B] int32, cache)``.
     """
     b = tokens.shape[0]
-    page_len = cache["k"].shape[2]
+    cache = _own_leaves(cache)
+    page_len = cache["k"][0].shape[1]
     rows = jnp.arange(b)
     page_of = page_tables[rows, positions // page_len]            # [B]
     off = positions % page_len
@@ -577,7 +604,8 @@ def forward_paged_verify(params, tokens, positions, cache, page_tables,
     plain greedy decode would have produced.
     """
     b, k1 = tokens.shape
-    page_len = cache["k"].shape[2]
+    cache = _own_leaves(cache)
+    page_len = cache["k"][0].shape[1]
     n_tables = page_tables.shape[1]
     impl = _resolve_paged_impl(cfg, b, n_tables, page_len)
     rows_pos = positions[:, None] + jnp.arange(k1)[None, :]       # [B, K1]

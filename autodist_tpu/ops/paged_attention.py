@@ -109,7 +109,7 @@ def dequantize_kv(q, scale, dtype: Any = jnp.float32):
 def _paged_gather(cache_layer, page_tables):
     """Gather one layer's KV timeline(s) by page index.
 
-    ``cache_layer [n_pages, page_len, H, D]`` (or ``[n_pages, page_len, H]``
+    ``cache_layer [n_pages, page_len, H * D]`` (or ``[n_pages, page_len, H]``
     for a scale plane); ``page_tables`` is ``[P]`` (one request) or ``[B, P]``
     (the decode batch). Returns the gathered timeline
     ``[..., P * page_len, ...]``. Pad entries point at the scratch page —
@@ -122,12 +122,14 @@ def _paged_gather(cache_layer, page_tables):
         page_tables.shape[:-1] + (page_tables.shape[-1] * page_len,) + tail)
 
 
-def _gather_timeline(pages, scale, page_tables, compute_dtype):
-    """Materialize the timeline in ``compute_dtype``, dequantizing if
-    ``scale`` is present. The fp branch is the verbatim pre-kernel gather."""
-    if scale is None:
-        return _paged_gather(pages, page_tables).astype(compute_dtype)
+def _gather_timeline(pages, scale, page_tables, compute_dtype, heads: int):
+    """Materialize the timeline ``[..., T, H, D]`` in ``compute_dtype``,
+    dequantizing if ``scale`` is present. Pages lie with heads x head_dim
+    on one axis; only the gathered timeline (small) is split into heads."""
     g = _paged_gather(pages, page_tables)
+    g = g.reshape(g.shape[:-1] + (heads, g.shape[-1] // heads))
+    if scale is None:
+        return g.astype(compute_dtype)
     s = _paged_gather(scale, page_tables)
     return dequantize_kv(g, s, compute_dtype)
 
@@ -148,6 +150,11 @@ def _paged_kernel(tables_ref, qpos_ref, q_ref, *rest, page_len: int,
     DMAs exactly one page out of HBM: traffic scales with the live table,
     never with a materialized ``[B, P * page_len, H, D]`` timeline.
 
+    A page block is ``[page_len, H * D]``, as the pool holds it (heads x
+    head_dim on the lanes); the heads are taken as static lane slices and
+    stacked heads-major, so the products below are per head as they were
+    over a ``[page_len, H, D]`` block.
+
     The position mask is folded into the block loop via the absolute slot
     index ``t = p * page_len + offset``; fully-masked pages contribute
     exp(NEG_INF - m) == 0 because slot 0 (always admitted: positions >= 0)
@@ -166,18 +173,19 @@ def _paged_kernel(tables_ref, qpos_ref, q_ref, *rest, page_len: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0]                                   # [Q, H, D]
-    n_q = q.shape[0]
+    n_q, n_h, d = q.shape
     qh = jnp.transpose(q, (1, 0, 2)).astype(jnp.float32)   # [H, Q, D]
-    kblk = k_ref[0]                                # [page_len, H, D]
-    vblk = v_ref[0]
-    if quantized:
-        kf = kblk.astype(jnp.float32) * ks_ref[0][..., None]
-        vf = vblk.astype(jnp.float32) * vs_ref[0][..., None]
-    else:
-        kf = kblk.astype(jnp.float32)
-        vf = vblk.astype(jnp.float32)
-    kh = jnp.transpose(kf, (1, 0, 2))              # [H, T, D]
-    vh = jnp.transpose(vf, (1, 0, 2))
+
+    def heads_major(page_ref, scale_ref):
+        blk = page_ref[0].astype(jnp.float32)      # [page_len, H * D]
+        per_head = [blk[:, h * d:(h + 1) * d] for h in range(n_h)]
+        if quantized:
+            sc = scale_ref[0]                      # [page_len, H]
+            per_head = [x * sc[:, h:h + 1] for h, x in enumerate(per_head)]
+        return jnp.stack(per_head, axis=0)         # [H, T, D]
+
+    kh = heads_major(k_ref, ks_ref if quantized else None)
+    vh = heads_major(v_ref, vs_ref if quantized else None)
     s = jax.lax.dot_general(
         qh, kh, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
@@ -208,9 +216,10 @@ def _paged_kernel(tables_ref, qpos_ref, q_ref, *rest, page_len: int,
 
 def _kernel_attention(q4, k_pages, v_pages, page_tables, q_positions,
                       k_scale, v_scale, interpret: Optional[bool]):
-    """Dispatch the unified kernel: ``q4 [B, Q, H, D]``, ``page_tables
-    [B, P]``, ``q_positions [B, Q]`` absolute positions per query. Returns
-    ``[B, Q, H, D]`` in the query dtype."""
+    """Dispatch the unified kernel: ``q4 [B, Q, H, D]``, pages ``[n_pages,
+    page_len, H * D]``, ``page_tables [B, P]``, ``q_positions [B, Q]``
+    absolute positions per query. Returns ``[B, Q, H, D]`` in the query
+    dtype."""
     if interpret is None:
         interpret = _should_interpret()
     b, n_q, h, d = q4.shape
@@ -225,7 +234,7 @@ def _kernel_attention(q4, k_pages, v_pages, page_tables, q_positions,
     qpos = q_positions.astype(jnp.int32)[..., None]
 
     page_spec = pl.BlockSpec(
-        (1, page_len, h, d), lambda bi, pi, t: (t[bi, pi], 0, 0, 0))
+        (1, page_len, h * d), lambda bi, pi, t: (t[bi, pi], 0, 0))
     in_specs = [
         pl.BlockSpec((1, n_q, 1), lambda bi, pi, t: (bi, 0, 0)),    # qpos
         pl.BlockSpec((1, n_q, h, d), lambda bi, pi, t: (bi, 0, 0, 0)),
@@ -276,6 +285,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, positions, *,
                            compute_dtype: Any = None,
                            interpret: Optional[bool] = None):
     """Decode-step attention: ``q [B, H, D]`` (one query per row),
+    ``k_pages, v_pages [n_pages, page_len, H * D]`` one layer's pool (int8
+    pages: ``k_scale, v_scale [n_pages, page_len, H]`` beside them),
     ``page_tables [B, P]``, ``positions [B]``. Returns ``[B, H, D]``.
 
     ``impl='gather'`` is the verbatim pre-kernel program (einsum spellings
@@ -291,8 +302,10 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, positions, *,
         return out[:, 0]
     head_dim = q.shape[-1]
     timeline = page_tables.shape[1] * k_pages.shape[1]
-    ck = _gather_timeline(k_pages, k_scale, page_tables, compute_dtype)
-    cv = _gather_timeline(v_pages, v_scale, page_tables, compute_dtype)
+    ck = _gather_timeline(k_pages, k_scale, page_tables, compute_dtype,
+                          q.shape[-2])
+    cv = _gather_timeline(v_pages, v_scale, page_tables, compute_dtype,
+                          q.shape[-2])
     mask = position_mask(timeline, positions)                     # [B, T]
     logits = jnp.einsum("bhd,bthd->bht", q, ck).astype(jnp.float32)
     logits = logits / jnp.sqrt(head_dim).astype(jnp.float32)
@@ -315,8 +328,10 @@ def paged_prefill_attention(q, k_pages, v_pages, page_table, positions, *,
         return out[0]
     head_dim = q.shape[-1]
     timeline = page_table.shape[0] * k_pages.shape[1]
-    ck = _gather_timeline(k_pages, k_scale, page_table, compute_dtype)
-    cv = _gather_timeline(v_pages, v_scale, page_table, compute_dtype)
+    ck = _gather_timeline(k_pages, k_scale, page_table, compute_dtype,
+                          q.shape[-2])
+    cv = _gather_timeline(v_pages, v_scale, page_table, compute_dtype,
+                          q.shape[-2])
     mask = position_mask(timeline, positions)                     # [C, T]
     logits = jnp.einsum("chd,thd->hct", q, ck).astype(jnp.float32)
     logits = logits / jnp.sqrt(head_dim).astype(jnp.float32)
@@ -339,8 +354,10 @@ def paged_verify_attention(q, k_pages, v_pages, page_tables, rows_pos, *,
                                  k_scale, v_scale, interpret)
     head_dim = q.shape[-1]
     timeline = page_tables.shape[1] * k_pages.shape[1]
-    ck = _gather_timeline(k_pages, k_scale, page_tables, compute_dtype)
-    cv = _gather_timeline(v_pages, v_scale, page_tables, compute_dtype)
+    ck = _gather_timeline(k_pages, k_scale, page_tables, compute_dtype,
+                          q.shape[-2])
+    cv = _gather_timeline(v_pages, v_scale, page_tables, compute_dtype,
+                          q.shape[-2])
     mask = position_mask(timeline, rows_pos)                      # [B, K1, T]
     logits = jnp.einsum("bqhd,bthd->bhqt", q, ck).astype(jnp.float32)
     logits = logits / jnp.sqrt(head_dim).astype(jnp.float32)

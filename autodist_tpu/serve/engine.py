@@ -13,7 +13,7 @@ collectives for model-sharded parameters exactly as in training.
 
 Decode state is a **paged KV-cache** (the vLLM rendering of GSPMD-style
 static annotations, arXiv 2105.04663): ONE fixed pool of device pages
-(the zoo transformer's ``[layers, n_pages, page_len, heads, head_dim]``, or
+(the zoo transformer's ``[n_pages, page_len, heads * head_dim]`` a layer, or
 whatever leaves the model's ``init_paged_cache`` gives, the page dim where
 its :class:`~autodist_tpu.serve.pages.CacheLayout` says) sized from
 ``ResourceSpec`` HBM headroom and donated through the compiled steps, with
@@ -74,8 +74,8 @@ class DecodeModel:
     Paged surface (the production engine; all three required):
 
     - ``init_paged_cache(n_pages, page_len) -> cache`` pytree whose
-      leaves carry the page dim at ``cache_layout.page_axis`` (dim 1 of
-      rank>=2 leaves where the model states no layout; the engine shards
+      leaves carry the page dim at ``cache_layout.page_axis`` (dim 0, a
+      leaf a layer, where the model states no layout; the engine shards
       it over the mesh data axis);
     - ``prefill_chunk(params, tokens [1,C], start, length, cache,
       page_table [P]) -> (next_token [1], cache)`` — writes prompt
@@ -332,10 +332,10 @@ class InferenceEngine(_EngineBase):
         self.page_bytes = page_bytes
         # Quantized pool mode (int8 pages + f32 scale planes, PR 20):
         # detected from the model's own cache pytree, so the engine needs
-        # no config plumbing — the scale planes share the page dim and ride
-        # the dim1-keyed sharding/COW/pricing below unchanged. fp-equiv
-        # bytes reprice the int8 value planes at the model's fp cache dtype
-        # (from the stacked cache's leaf dtype) and drop the scale planes
+        # no config plumbing — the scale leaves share the page dim and ride
+        # the page-axis-keyed sharding/COW/pricing below unchanged. fp-equiv
+        # bytes reprice the int8 value leaves at the model's fp cache dtype
+        # (from the stacked cache's leaf dtype) and drop the scale leaves
         # (which would not exist in fp mode): the "what would these pages
         # cost unquantized" figure the capacity-x metrics divide by.
         self.kv_quant = isinstance(page_shaped, dict) and \
@@ -352,8 +352,9 @@ class InferenceEngine(_EngineBase):
             ))[0].dtype).itemsize
             self.page_fp_equiv_bytes = sum(
                 int(np.prod(leaf.shape)) * fp_itemsize
-                for name, leaf in page_shaped.items()
-                if not name.endswith("_scale"))
+                for name, leaves in page_shaped.items()
+                if not name.endswith("_scale")
+                for leaf in jax.tree_util.tree_leaves(leaves))
         else:
             self.page_fp_equiv_bytes = page_bytes
         max_useful = self.n_slots * self.max_pages
@@ -510,9 +511,9 @@ class InferenceEngine(_EngineBase):
 
     # ------------------------------------------------------------ decode pool
     def _cache_shardings(self, init_cache, n_pages: int):
-        """Page dim (where the model's layout says: dim 1 of rank>=2
-        leaves on the stacked pool, dim 0 of a leaf a layer) over the data
-        axis; scalars and vectors replicate. Evaluated on abstract shapes
+        """Page dim (where the model's layout says: dim 0 of a leaf a
+        layer, dim 1 of a pool stacked over layers) over the data axis;
+        scalars and vectors replicate. Evaluated on abstract shapes
         — no device cache is built to derive its own sharding."""
         from autodist_tpu.kernel.mesh import data_sharding
 
@@ -856,21 +857,25 @@ class InferenceEngine(_EngineBase):
             table = self.pool.alloc(n_tokens)
         return table
 
-    @staticmethod
-    def _make_page_copy_fn(n_pages: int, cache_sh):
+    def _make_page_copy_fn(self, n_pages: int, cache_sh):
         """Compile the COW page copy for one pool: every cache leaf's
-        ``src`` page row duplicated into ``dst``, donated in place with
-        the pool's canonical sharding. Page ids are traced scalars, so
-        ONE program serves every copy — a data-movement program over the
-        pool, not a serving program (the exactly-2/exactly-5 pins count
-        the per-token decode/prefill/verify programs)."""
+        ``src`` page duplicated into ``dst`` along the layout's page axis,
+        donated in place with the pool's canonical sharding. Page ids are
+        traced scalars, so ONE program serves every copy — a data-movement
+        program over the pool, not a serving program (the exactly-2 /
+        exactly-5 pins count the per-token decode/prefill/verify
+        programs)."""
+        axis = self.layout.page_axis
+
+        def copy_page(leaf, src, dst):
+            if leaf.ndim < 2 or leaf.shape[axis] != n_pages:
+                return leaf
+            page = jax.lax.dynamic_index_in_dim(leaf, src, axis)
+            return jax.lax.dynamic_update_index_in_dim(leaf, page, dst, axis)
 
         def serve_cow_copy(cache, src, dst):
             return jax.tree_util.tree_map(
-                lambda leaf: (leaf.at[:, dst].set(leaf[:, src])
-                              if leaf.ndim >= 2
-                              and leaf.shape[1] == n_pages else leaf),
-                cache)
+                lambda leaf: copy_page(leaf, src, dst), cache)
 
         return jax.jit(serve_cow_copy, donate_argnums=(0,),
                        out_shardings=cache_sh)

@@ -1,7 +1,7 @@
 """Paged KV-cache bookkeeping: the ONE page-table/pool allocator home.
 
 The serving engine's decode state is a single fixed-size pool of KV pages
-(device arrays ``[layers, n_pages, page_len, heads, head_dim]``, owned by
+(device arrays ``[n_pages, page_len, heads * head_dim]`` a layer, owned by
 :class:`~autodist_tpu.serve.engine.InferenceEngine`); WHICH pages belong
 to WHICH request is pure host arithmetic, and it all lives here — the
 same single-home pattern as ``kernel/bucketing.py`` (gradient collectives)
@@ -80,13 +80,14 @@ class CacheLayout:
     ``prefill_chunk`` is the chunk the model asks for; any chunk has to be
     a multiple of ``page_len`` and, with a window, divide it (no chunk
     straddles a window). ``page_axis`` is where the cache's leaves carry
-    the page dim: 1 for a pool stacked over layers, 0 for a leaf a layer.
+    the page dim: 0 for a leaf a layer (both model families), 1 for a pool
+    stacked over layers.
     """
 
     page_len: int = DEFAULT_PAGE_LEN
     window: Optional[int] = None
     prefill_chunk: Optional[int] = None
-    page_axis: int = 1
+    page_axis: int = 0
 
     def __post_init__(self):
         if self.window is not None and self.window % self.page_len:

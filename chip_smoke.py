@@ -353,6 +353,9 @@ def _paged_leg(meter, cfg, batch: int, table_pages, on_tpu: bool) -> dict:
                 kp, vp, ks, vs = (kf.astype(jnp.bfloat16),
                                   vf.astype(jnp.bfloat16), None, None)
                 kr, vr = kp.astype(jnp.float32), vp.astype(jnp.float32)
+            # Pages as the pool holds them: heads x head_dim on one axis.
+            kp, vp, kr, vr = (x.reshape(n_pages, PAGE_LEN, heads * dim)
+                              for x in (kp, vp, kr, vr))
             for name, fn, q, tab, qpos in (
                     ("decode", pa.paged_decode_attention, qd, tables, pos),
                     ("verify", pa.paged_verify_attention, qv, tables, rows_pos),

@@ -56,9 +56,9 @@ def measure_point(batch: int, table_pages: int, impl: str) -> dict:
     rng = np.random.default_rng(0)
     n_pages = batch * table_pages + 1
     kp = jnp.asarray(rng.standard_normal(
-        (n_pages, PAGE_LEN, HEADS, HEAD_DIM)), jnp.float32)
+        (n_pages, PAGE_LEN, HEADS * HEAD_DIM)), jnp.float32)
     vp = jnp.asarray(rng.standard_normal(
-        (n_pages, PAGE_LEN, HEADS, HEAD_DIM)), jnp.float32)
+        (n_pages, PAGE_LEN, HEADS * HEAD_DIM)), jnp.float32)
     tables = jnp.asarray(
         1 + rng.permutation(batch * table_pages).reshape(batch, table_pages),
         jnp.int32)

@@ -4,7 +4,8 @@
   tier-1 correctness vehicle) matches the verbatim gather reference for
   all three entry points — decode step, spec verify (including draft
   windows whose positions clamp to the scratch page), prefill chunk —
-  quantized and fp;
+  quantized and fp, on lane-dense pages ``[n_pages, page_len, H * D]`` at
+  toy widths, at 25 heads of 64 (GPT-2 XL) and at 4 heads of 128;
 - **engine-level bit-identity with quant OFF**: kernel-vs-gather token
   STREAMS are bit-equal, greedy and sampled, so flipping the impl can
   never fork a delivered stream (the PR-13/15/17 contracts ride on this);
@@ -36,14 +37,19 @@ B, P, PAGE_LEN, H, D = 3, 4, 8, 2, 16
 N_PAGES = 12
 
 
-def _pages(rng, quantized=False):
-    k = rng.standard_normal((N_PAGES, PAGE_LEN, H, D)).astype(np.float32)
-    v = rng.standard_normal((N_PAGES, PAGE_LEN, H, D)).astype(np.float32)
+def _lanes(x):
+    """``[..., H, D]`` -> ``[..., H * D]``: a page as the pool holds it."""
+    return x.reshape(x.shape[:-2] + (-1,))
+
+
+def _pages(rng, quantized=False, h=H, d=D):
+    k = rng.standard_normal((N_PAGES, PAGE_LEN, h, d)).astype(np.float32)
+    v = rng.standard_normal((N_PAGES, PAGE_LEN, h, d)).astype(np.float32)
     if not quantized:
-        return jnp.asarray(k), jnp.asarray(v), None, None
+        return _lanes(jnp.asarray(k)), _lanes(jnp.asarray(v)), None, None
     kq, ks = pa.quantize_kv(jnp.asarray(k))
     vq, vs = pa.quantize_kv(jnp.asarray(v))
-    return kq, vq, ks, vs
+    return _lanes(kq), _lanes(vq), ks, vs
 
 
 def _tables(rng):
@@ -112,6 +118,61 @@ class TestOpsParity:
             fn(q, kp, vp, tables, positions),
             pa.paged_decode_attention(q, kp, vp, tables, positions),
             atol=1e-5, rtol=1e-5)
+
+
+WIDTHS = pytest.mark.parametrize(
+    "h, d", [(25, 64), (4, 128)], ids=["25x64", "4x128"])
+
+
+class TestLaneDenseWidths:
+    """Kernel against gather on lane-dense pages at the widths the chip
+    serves: the heads are static lane slices at multiples of ``head_dim``,
+    so 25 heads of 64 (1,600 lanes, not a multiple of 128) and 4 heads of
+    128 take the same code."""
+
+    @WIDTHS
+    @pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+    def test_decode(self, h, d, quantized):
+        rng = np.random.default_rng(20)
+        kp, vp, ks, vs = _pages(rng, quantized, h, d)
+        tables = _tables(rng)
+        q = jnp.asarray(rng.standard_normal((B, h, d)), jnp.float32)
+        positions = jnp.asarray([0, 7, P * PAGE_LEN - 1], jnp.int32)
+        outs = [pa.paged_decode_attention(
+            q, kp, vp, tables, positions, k_scale=ks, v_scale=vs,
+            impl=impl) for impl in ("gather", "kernel")]
+        assert outs[1].shape == (B, h, d)
+        np.testing.assert_allclose(outs[0], outs[1], atol=2e-5, rtol=2e-5)
+
+    @WIDTHS
+    @pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+    def test_prefill_chunk(self, h, d, quantized):
+        rng = np.random.default_rng(21)
+        kp, vp, ks, vs = _pages(rng, quantized, h, d)
+        table = _tables(rng)[1]
+        q = jnp.asarray(rng.standard_normal((PAGE_LEN, h, d)), jnp.float32)
+        positions = jnp.arange(2 * PAGE_LEN, 3 * PAGE_LEN, dtype=jnp.int32)
+        outs = [pa.paged_prefill_attention(
+            q, kp, vp, table, positions, k_scale=ks, v_scale=vs,
+            impl=impl) for impl in ("gather", "kernel")]
+        np.testing.assert_allclose(outs[0], outs[1], atol=2e-5, rtol=2e-5)
+
+    @WIDTHS
+    def test_gather_reads_heads_where_the_write_put_them(self, h, d):
+        # One key per head set apart: the query of head j must find it in
+        # lanes [j * d, (j + 1) * d) of the page row, on both paths.
+        k = np.zeros((N_PAGES, PAGE_LEN, h, d), np.float32)
+        v = np.zeros((N_PAGES, PAGE_LEN, h, d), np.float32)
+        for j in range(h):
+            v[1, 0, j, :] = j + 1.0
+        tables = jnp.asarray([[1, 0, 0, 0]], jnp.int32)
+        q = jnp.ones((1, h, d), jnp.float32)
+        for impl in ("gather", "kernel"):
+            out = pa.paged_decode_attention(
+                q, _lanes(jnp.asarray(k)), _lanes(jnp.asarray(v)), tables,
+                jnp.asarray([0], jnp.int32), impl=impl)
+            np.testing.assert_allclose(
+                out[0, :, 0], np.arange(1, h + 1, dtype=np.float32))
 
 
 class TestQuantization:
@@ -281,8 +342,8 @@ class TestCrossover:
         assert resolve_paged_impl("kernel", 4, 4, 8, 2) == "kernel"
         with pytest.raises(ValueError):
             pa.paged_decode_attention(
-                jnp.zeros((1, H, D)), jnp.zeros((2, PAGE_LEN, H, D)),
-                jnp.zeros((2, PAGE_LEN, H, D)), jnp.zeros((1, 1), jnp.int32),
+                jnp.zeros((1, H, D)), jnp.zeros((2, PAGE_LEN, H * D)),
+                jnp.zeros((2, PAGE_LEN, H * D)), jnp.zeros((1, 1), jnp.int32),
                 jnp.zeros((1,), jnp.int32), impl="auto")
 
     def test_auto_is_gather_off_tpu(self):

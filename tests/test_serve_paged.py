@@ -81,6 +81,28 @@ def test_paged_matches_bucketed_greedy_streams(paged, bucketed):
         assert paged.generate(p, 10) == bucketed.generate(p, 10), p
 
 
+@pytest.mark.parametrize("impl", ["gather", "kernel"])
+def test_paged_stream_equals_the_unpaged_oracle(params, paged, bucketed, impl):
+    """The pool as a leaf a layer, ``[n_pages, page_len, heads * head_dim]``,
+    on both attention paths (the kernel in interpret mode here): the greedy
+    stream equals the unpaged oracle's (``forward_prefill`` /
+    ``forward_decode_step`` over one stacked timeline a slot)."""
+    import dataclasses
+
+    from autodist_tpu.serve.engine import InferenceEngine
+
+    cfg = dataclasses.replace(CFG, paged_attention_impl=impl)
+    engine = InferenceEngine(params, paged.plan, decode_model=decode_model(cfg),
+                             n_slots=4, page_len=8, n_pages=17, prefill_chunk=8)
+    leaves = jax.tree_util.tree_leaves(engine._cache)
+    assert len(leaves) == 2 * CFG.num_layers
+    assert {leaf.shape for leaf in leaves} == {(engine.pool.n_pages, 8, CFG.d_model)}
+    rng = np.random.default_rng(31)
+    for n in (3, 8, 13, 21):         # inside a page, a whole page, across pages
+        p = rng.integers(1, 96, size=n).astype(np.int32)
+        assert engine.generate(p, 9) == bucketed.generate(p, 9), (impl, n)
+
+
 def test_mid_batch_join_matches_bucketed(paged, bucketed):
     """A request joining mid-decode sees the same stream on both engines —
     batching (and paging) is scheduling, never semantics."""

@@ -256,6 +256,58 @@ class TestEngineSharing:
         # write.
         assert shared.prefix_stats()["cow_copies"] == cow_before + 1
 
+    @pytest.mark.parametrize("page_axis", [0, 1])
+    def test_cow_copy_touches_one_page_of_every_leaf(self, rig, page_axis):
+        """The copy follows the layout's page axis (0: a leaf a layer, as
+        both model families hold their pools; 1: a pool stacked over
+        layers): ``dst`` becomes ``src`` in every leaf with a page dim,
+        every other page and every other leaf stays bit-equal."""
+        import types
+
+        import jax
+        import jax.numpy as jnp
+
+        from autodist_tpu.serve import pages as serve_pages
+        from autodist_tpu.serve.engine import InferenceEngine
+
+        n_pages, rng = 7, np.random.default_rng(23)
+        shape = (n_pages, 8, 6) if page_axis == 0 else (3, n_pages, 8, 6)
+        cache = {"k": [rng.standard_normal(shape).astype(np.float32)
+                       for _ in range(2)],
+                 "k_scale": [rng.standard_normal(shape[:-1]).astype(np.float32)],
+                 "steps": np.arange(n_pages + 1, dtype=np.int32)}
+        engine = types.SimpleNamespace(
+            layout=serve_pages.CacheLayout(page_len=8, page_axis=page_axis))
+        copy = InferenceEngine._make_page_copy_fn(engine, n_pages, None)
+        out = copy(jax.tree_util.tree_map(jnp.asarray, cache),
+                   jnp.int32(5), jnp.int32(2))
+        before = jax.tree_util.tree_leaves(cache)
+        after = [np.asarray(x) for x in jax.tree_util.tree_leaves(out)]
+        assert len(before) == len(after) == 4
+        for was, now in zip(before, after):
+            want = was.copy()
+            if was.ndim >= 2:
+                index = (slice(None),) * page_axis
+                want[index + (2,)] = was[index + (5,)]
+            assert np.array_equal(now, want)
+        # and through an engine, on GPT-2's own pool
+        control, _shared, params, dm, _cfg = rig
+        shared = InferenceEngine(
+            params, control.plan, decode_model=dm, prefix_cache=True,
+            n_slots=8, page_len=8, n_pages=16, prefill_chunk=8, max_len=64)
+        leaves = jax.tree_util.tree_leaves(shared._cache)
+        filled = [rng.standard_normal(x.shape).astype(x.dtype) for x in leaves]
+        shared._cache = jax.tree_util.tree_unflatten(
+            jax.tree_util.tree_structure(shared._cache),
+            [jax.device_put(f, x.sharding) for f, x in zip(filled, leaves)])
+        copies = shared.prefix_stats()["cow_copies"]
+        shared._cow_page(9, 4)
+        assert shared.prefix_stats()["cow_copies"] == copies + 1
+        for was, now in zip(filled, jax.tree_util.tree_leaves(shared._cache)):
+            want = was.copy()
+            want[4] = was[9]
+            assert np.array_equal(np.asarray(now), want)
+
     def test_mid_batch_join_through_batcher(self, rig, shared_prompts):
         from autodist_tpu.serve.batcher import ContinuousBatcher, RequestState
 

@@ -411,7 +411,7 @@ def test_kernel_names_in_the_compiled_programs(one_chip):
         return PA.paged_decode_attention(q, kp, vp, tables, pos,
                                          impl="kernel", interpret=False)
 
-    pages = sds((65, 16, 25, 64))
+    pages = sds((65, 16, 25 * 64))
     calls = _custom_calls(analysis.compiled_text(
         jax.jit(serve_decode_step), sds((4, 25, 64)), pages, pages,
         sds((4, 16), jnp.int32), sds((4,), jnp.int32)))
@@ -477,3 +477,94 @@ def test_eva_kernel_compiles_for_the_chip_at_published_widths(one_chip, rows, qu
     import re
 
     assert not re.search(r"= bf16\[1025,32,16,128\]\S* copy\(", text)
+
+
+# ------------------------------------------- the page pool stays where it lies
+XL_PAGES, XL_PAGE_LEN, XL_HEADS, XL_HEAD_DIM, XL_TABLE = 257, 16, 25, 64, 64
+
+
+def _leaf_sized(text, leaf):
+    """``(opcode, the instruction's line)`` of every instruction of a
+    compiled program whose result is, or holds, an array of shape ``leaf``."""
+    import re
+
+    out = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(.*$", text, flags=re.M):
+        if leaf in m.group(1):
+            out.append((m.group(2), m.group(0)))
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode-step", "prefill-chunk", "verify"])
+def test_serving_programs_leave_the_page_pool_where_it_lies(one_chip, monkeypatch,
+                                                            program):
+    """The mechanism's witness (PR 31): GPT-2 XL's widths (25 heads of 64,
+    a few of its layers), 257 pages, the cache donated, the real Mosaic
+    call. A layer's leaf ``[n_pages, page_len, heads * head_dim]`` is a
+    parameter, is written by a scatter fusion in place and is read by the
+    call named ``paged_attention`` as it lies: the compiled program holds no
+    copy, slice, transpose or other fusion of a leaf's size (a pool whose
+    last dim is the head_dim of 64 cost four whole-pool copies and two
+    slices a layer; PERF.md section 6, PR 31). What the compiler's own
+    prefetch moves into fast memory ahead of an operation (asynchronous,
+    memory space ``S(1)``) is at most one layer's pair of leaves."""
+    from autodist_tpu.models import transformer as T
+    from autodist_tpu.ops import paged_attention as PA
+
+    monkeypatch.setattr(PA, "_should_interpret", lambda: False)
+    layers, rows, chunk, k1 = 4, 4, 16, 5
+    cfg = T.TransformerConfig(
+        vocab_size=50257, num_layers=layers, d_model=XL_HEADS * XL_HEAD_DIM,
+        num_heads=XL_HEADS, d_ff=6400, max_seq_len=1024, dtype=jnp.bfloat16,
+        paged_attention_impl="kernel")
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = described(jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = described(jax.eval_shape(
+        lambda: T.init_paged_kv_cache(cfg, XL_PAGES, XL_PAGE_LEN)))
+
+    def serve_decode_step(params, tokens, positions, cache, tables):
+        return T.forward_paged_decode_step(params, tokens, positions, cache, tables, cfg)
+
+    def serve_prefill_chunk(params, tokens, start, length, cache, table):
+        return T.forward_paged_prefill_chunk(params, tokens, start, length, cache,
+                                             table, cfg)
+
+    def serve_spec_verify(params, tokens, positions, cache, tables):
+        return T.forward_paged_verify(params, tokens, positions, cache, tables, cfg)
+
+    fn, args, donated = {
+        "decode-step": (serve_decode_step,
+                        (params, i32(rows), i32(rows), cache, i32(rows, XL_TABLE)), 3),
+        "prefill-chunk": (serve_prefill_chunk,
+                          (params, i32(1, chunk), i32(), i32(), cache, i32(XL_TABLE)), 4),
+        "verify": (serve_spec_verify,
+                   (params, i32(rows, k1), i32(rows), cache, i32(rows, XL_TABLE)), 3),
+    }[program]
+    text = analysis.compiled_text(jax.jit(fn, donate_argnums=(donated,)), *args)
+
+    calls = _custom_calls(text)
+    assert len(calls) == layers and all(c.startswith("paged_attention") for c in calls), calls
+    leaf = f"bf16[{XL_PAGES},{XL_PAGE_LEN},{XL_HEADS * XL_HEAD_DIM}]"
+    found = _leaf_sized(text, leaf)
+    assert sum(op == "scatter" for op, _ in found) == 2 * layers
+    in_place = {"parameter", "scatter", "tuple", "get-tuple-element", "bitcast",
+                "copy-done", "slice-done"}      # the last two end a checked start
+    arrivals = 0            # leaves the prefetch brings into fast memory
+    for op, line in found:
+        result = line.split(" = ", 1)[1]
+        if op == "fusion":
+            assert "/scatter\"" in line, f"a fusion of a leaf's size, no write: {line[:300]}"
+        elif op == "copy-start" or (op == "custom-call" and "ConcatBitcast" in line):
+            assert "S(1)" in result, f"a leaf moved, and not by the prefetch: {line[:300]}"
+            arrivals += "S(1)" in result.split("}", 1)[0]
+        else:
+            assert op in in_place, f"{op} of a leaf's size: {line[:300]}"
+    assert arrivals <= 2, f"{arrivals} leaves ride the prefetch"
