@@ -7,8 +7,9 @@ thread: one process, no client threads. The window opens and closes at a
 tick's end, so tokens are counted exactly between two ticks, at emission,
 whether or not their request finished.
 
-The traffic file gives ``clients``, the lengths (see ``harness/traffic.py``)
-and ``check_requests``, how many finished requests the reference follows.
+The traffic file gives ``clients``, the lengths (see ``harness/traffic.py``),
+``check_requests``, how many finished requests the reference follows, and,
+where the cell reports the gap's tail, ``tail_percentile``.
 The configuration file's ``serving`` group gives what a deployment states
 (``n_slots``, ``max_len``); page size, pool size, prefill chunk and the
 attention paths are the program's own choice. Everything that is the
@@ -28,6 +29,15 @@ from perfbench.harness import device, runtime, traffic
 
 TOKENS_COUNTER = "serve_tokens_generated_total"
 POOL_GAUGE = "serve_page_pool_utilization"
+# ``serve_tpot_tail_s`` is the gap at the percentile the traffic file states
+# (``tail_percentile``). A window's gaps fall into a few classes of tick (one
+# that only decodes; one whose chunk rode behind the decode step; ones whose
+# chunk could not, or that carry two), and a percentile that lies where a
+# class ends jumps by the step between two of them from run to run: each
+# cell states one that lies well inside a class (PERF.md section 6, PR 33).
+# The ladder and the mean of the band from p90 to p99 are said in every run,
+# so that whoever moves the classes' shares sees where their edges lie.
+LADDER = (50, 90, 95, 98, 99, 99.5)
 
 
 class Observer:
@@ -286,10 +296,15 @@ def run(ctx):
                 numbers["control." + prec + ".logit_gap"] = value
 
     gaps, first = obs.gaps, obs.first
+    if gaps:
+        say(f"gaps {len(gaps)}: " + " ".join(
+            f"p{q:g} {runtime.percentile(gaps, q):.6f}" for q in LADDER)
+            + f"; mean of p90-p99 {runtime.band_mean(gaps):.6f} s")
     end_to_end = {
         "setup_s": setup_s,
         "serve_tok_s": tokens / window,
-        "serve_tpot_p95_s": runtime.percentile(gaps, 95) if gaps else None,
+        "serve_tpot_tail_s": runtime.percentile(gaps, mix["tail_percentile"])
+        if gaps and "tail_percentile" in mix else None,
         "serve_ttft_per_ktok_s": (1000.0 * sum(t for t, _ in first)
                                   / sum(n for _, n in first)) if first else None,
     }

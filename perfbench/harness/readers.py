@@ -105,11 +105,11 @@ def mfu_decode(run, ctx):
 
 
 def mfu_tpot(run, ctx):
-    p95, peak, c = run["end_to_end"].get("serve_tpot_p95_s"), peak_flops(ctx), mean_context(run)
-    if not p95 or peak is None or c is None:
+    tail, peak, c = run["end_to_end"].get("serve_tpot_tail_s"), peak_flops(ctx), mean_context(run)
+    if not tail or peak is None or c is None:
         return None
     rows = run["data"]["engine"]["n_slots"]
-    return 100.0 * rows * family(ctx).decode_flops(ctx["cell"].model, int(c)) / p95 / peak
+    return 100.0 * rows * family(ctx).decode_flops(ctx["cell"].model, int(c)) / tail / peak
 
 
 def mfu_ttft(run, ctx):
@@ -132,6 +132,11 @@ def prefill_chunk_p50_ms(run, ctx):
 def tick_p50_ms(run, ctx):
     ticks = run["data"]["ticks"]
     return 1e3 * runtime.median([t[0] for t in ticks]) if ticks else None
+
+
+def gap_band_mean_ms(run, ctx):
+    gaps = run["data"]["gaps"]
+    return 1e3 * runtime.band_mean(gaps) if gaps else None
 
 
 def slot_occupancy(run, ctx):

@@ -1,5 +1,5 @@
 """What every driver shares: the count of compilations, the profiler
-session, program spans cut to a window, and percentiles."""
+session, program spans cut to a window, percentiles and the tail's band."""
 from __future__ import annotations
 
 import os
@@ -77,11 +77,27 @@ class ProfilerSession:
             shutil.rmtree(self.dir, ignore_errors=True)
 
 
+def _rank(n: int, q: float) -> int:
+    """Index of the nearest-rank percentile ``q`` in a sorted list of ``n``."""
+    return max(0, min(n - 1, int(-(-q * n // 100)) - 1))
+
+
 def percentile(values, q: float) -> float:
     """Nearest-rank percentile (``q`` in 0..100) of a non-empty list."""
     xs = sorted(values)
-    k = max(0, min(len(xs) - 1, int(-(-q * len(xs) // 100)) - 1))
-    return xs[k]
+    return xs[_rank(len(xs), q)]
+
+
+def band_mean(values, lo: float = 90, hi: float = 99) -> float:
+    """Mean of the values from the one at percentile ``lo`` to the one at
+    percentile ``hi``, both by nearest rank and both in it, of a non-empty
+    list: the slow tenth with the slowest hundredth set aside. Where the
+    values fall into a few classes it moves with the classes' shares and
+    does not jump when a share crosses a percentile; a list too short to
+    tell the two ranks apart gives the one value at both."""
+    xs = sorted(values)
+    band = xs[_rank(len(xs), lo):_rank(len(xs), hi) + 1]
+    return sum(band) / len(band)
 
 
 def make_autodist(strategy_builder, chips: int):

@@ -1,4 +1,4 @@
-"""engine.decode_step_device_p50_ms.tpot: Median device run of the module jit_serve_decode_step: the part of the p95 gap that every tick has.
+"""engine.decode_step_device_p50_ms.tpot: Median device run of the module jit_serve_decode_step: the part of the gap's tail that every tick has.
 
 The `.decode` reader under the name of the one end-to-end metric that
 `evabyte-serve-decode-long` reports; it goes when that cell can report

@@ -1,4 +1,4 @@
-"""model.mfu.tpot: Required FLOPs of one decode step over all slots / serve_tpot_p95_s / peak."""
+"""model.mfu.tpot: Required FLOPs of one decode step over all slots / serve_tpot_tail_s / peak."""
 from perfbench.harness import readers, runtime  # noqa: F401
 
 

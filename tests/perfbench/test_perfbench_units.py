@@ -81,6 +81,53 @@ def test_per_layer_metric_cells_report_what_it_moves(metric):
         assert m["unit"] == "%"
 
 
+def _cells_of_every_manifest():
+    out = [pytest.param(MANIFEST, None, c, id=c) for c in CELLS]
+    for name in sorted(os.listdir(TINY_DIR)):
+        if name.startswith("BENCHMARK.") and name.endswith(".json"):
+            mf = manifest.load(os.path.join(TINY_DIR, name))
+            out += [pytest.param(mf, TINY_DIR, w["name"], id=name[10:-5] + "." + w["name"])
+                    for w in mf["workloads"]]
+    return out
+
+
+@pytest.mark.parametrize("mf,data_dir,cell", _cells_of_every_manifest())
+def test_no_rename_drops_a_reader_of_a_cell(mf, data_dir, cell):
+    """``Cell.per_layer`` leaves out an entry whose ``moves`` the cell does
+    not report. So every entry's ``moves`` names an end-to-end entry that
+    each of the entry's cells reports, in the benchmark's manifest and the
+    tests' own: an end-to-end metric renamed in one place and not the other
+    fails here, where it would only silence a reader in a run."""
+    e2e = {m["name"]: m for m in mf["end_to_end"]}
+    cells = [w["name"] for w in mf["workloads"]]
+    for m in mf["per_layer"]:
+        assert m["moves"] in e2e, (m["name"], m["moves"])
+        reporting = set(e2e[m["moves"]].get("workloads", cells))
+        assert set(m.get("workloads", reporting)) <= reporting, m["name"]
+        assert set(m.get("workloads", ())) <= set(cells), m["name"]
+    c = manifest.Cell(mf, cell, data_dir=data_dir)
+    listed = [m["name"] for m in mf["per_layer"] if cell in m.get(
+        "workloads", e2e[m["moves"]].get("workloads", cells))]
+    assert [m["name"] for m in c.per_layer] == listed
+
+
+@pytest.mark.parametrize("mf,data_dir,cell", _cells_of_every_manifest())
+def test_a_cell_that_reports_the_gaps_tail_states_its_percentile(mf, data_dir, cell):
+    """``serve_tpot_tail_s`` is the gap at the percentile the cell's traffic
+    file states, 90, 95 or 99; and the band's mean beside it reads whatever
+    gaps a run holds."""
+    c = manifest.Cell(mf, cell, data_dir=data_dir)
+    if "serve_tpot_tail_s" not in {m["name"] for m in c.end_to_end}:
+        return
+    assert c.traffic["driver"] == "serve-closed"
+    assert c.traffic["tail_percentile"] in (90, 95, 99)
+    assert "sched.gap_p90_p99_mean_ms.tpot" in {m["name"] for m in c.per_layer}
+    read = c.reader("sched.gap_p90_p99_mean_ms.tpot").read
+    assert read({"data": {"gaps": []}}, {}) is None
+    assert read({"data": {"gaps": _spikes(0.047)}}, {}) == pytest.approx(
+        1e3 * runtime.band_mean(_spikes(0.047)))
+
+
 def test_every_config_file_is_under_paths_and_used():
     used = {w["config"] for w in MANIFEST["workloads"]}
     for c in MANIFEST["configs"]:
@@ -289,10 +336,58 @@ def test_trace_reader_reads_a_recorded_profile(tmp_path):
     assert tr.top_ops() == [] and tr.idle_gaps() == []
 
 
-def test_percentile_and_median():
-    assert runtime.percentile(list(range(1, 101)), 95) == 95
-    assert runtime.percentile([3.0], 95) == 3.0
-    assert runtime.median([1, 3, 2, 10]) == 2.5
+def _spikes(top_share, n=7000):
+    """Gaps of three classes of tick, as the XL decode cell has them:
+    22.6 ms, 29.9 ms for 18% of gaps, 34.5 ms for ``top_share`` of them."""
+    top, mid = round(n * top_share), round(n * 0.18)
+    return [0.0345] * top + [0.0299] * mid + [0.0226] * (n - top - mid)
+
+
+@pytest.mark.parametrize("case", [
+    "percentile", "percentile_of_one", "median",
+    "band_of_a_hundred", "band_of_one", "band_shorter_than_it_needs",
+    "band_sets_the_slowest_hundredth_aside", "band_takes_any_order",
+    "band_is_continuous_where_p95_jumps", "p90_holds_where_p95_jumps"])
+def test_percentile_and_median(case):
+    if case == "percentile":
+        assert runtime.percentile(list(range(1, 101)), 95) == 95
+    elif case == "percentile_of_one":
+        assert runtime.percentile([3.0], 95) == 3.0
+    elif case == "median":
+        assert runtime.median([1, 3, 2, 10]) == 2.5
+    elif case == "band_of_a_hundred":
+        # the values at p90 ... p99, both in it: 90, 91, ..., 99
+        assert runtime.band_mean(list(range(1, 101))) == 94.5
+        assert runtime.band_mean(list(range(1, 101)), 50, 60) == 55.0
+    elif case == "band_of_one":
+        assert runtime.band_mean([3.0]) == 3.0
+    elif case == "band_shorter_than_it_needs":
+        # ten values: p90 is the ninth, p99 the tenth; five: both the fifth
+        assert runtime.band_mean([float(i) for i in range(1, 11)]) == 9.5
+        assert runtime.band_mean([1.0, 2.0, 3.0, 4.0, 5.0]) == 5.0
+    elif case == "band_sets_the_slowest_hundredth_aside":
+        calm = [0.02] * 1000
+        stalled = [0.02] * 990 + [2.5] * 10     # a machine standing still
+        assert runtime.band_mean(stalled) == runtime.band_mean(calm) == 0.02
+        assert runtime.band_mean([0.02] * 989 + [2.5] * 11) > 0.02
+    elif case == "band_takes_any_order":
+        xs = _spikes(0.05)
+        mixed = list(np.random.default_rng(0).permutation(xs))
+        assert runtime.band_mean(mixed) == pytest.approx(runtime.band_mean(sorted(xs)))
+    elif case == "p90_holds_where_p95_jumps":
+        # a percentile four points inside a class reads that class whichever
+        # side of p95 the top class's edge falls
+        assert [runtime.percentile(_spikes(top), 90)
+                for top in (0.044, 0.047, 0.053, 0.06)] == [0.0299] * 4
+    else:
+        # the top class at 4.7% and at 5.3% of 7,000 gaps lies to either
+        # side of the 95th percentile; the band's mean moves with its share
+        below, above = _spikes(0.047), _spikes(0.053)
+        p95 = [runtime.percentile(xs, 95) for xs in (below, above)]
+        assert p95 == [0.0299, 0.0345] and p95[1] / p95[0] > 1.15
+        tail = [runtime.band_mean(xs) for xs in (below, above)]
+        assert tail[0] < tail[1] < 1.01 * tail[0]
+        assert 0.0299 < tail[0] and tail[1] < 0.0345
 
 
 # ---------------------------------------------------------------- reference

@@ -1,14 +1,17 @@
 """Proof that the room for another model family is real: ``hfkeys``
 (``tests/perfbench/tiny/families/hfkeys.py``) shares no model key with
-GPT-2, holds bfloat16 leaves and a slice of its vocabulary, and no file of
-``perfbench/`` knows its name or its keys; yet a serving cell and a training
-cell of it run end to end through the benchmark's own drivers, on the CPU,
-with ``correct`` true, and the benchmark's own readers count its work."""
+GPT-2, holds bfloat16 leaves and a slice of its vocabulary, and no file that
+the families share under ``perfbench/`` knows its name or its keys; yet a
+serving cell and a training cell of it run end to end through the benchmark's
+own drivers, on the CPU, with ``correct`` true, and the benchmark's own
+readers count its work."""
 import contextlib
 import io
 import json
 import os
+import pathlib
 import re
+import shutil
 import sys
 import time
 
@@ -73,17 +76,51 @@ def test_the_second_family_shares_no_model_key_with_gpt2():
                          data_dir=TINY).model["param_dtype"] == "bfloat16"
 
 
-def test_no_file_of_perfbench_knows_the_second_family():
+# What every family and cell shares: the command, the builder's tools, the
+# harness, the drivers and the metric readers. ``configs/`` and
+# ``families/`` are the families' own, and a family whose published keys are
+# HF's (EvaByte's ``hidden_size``, ...) states them there letter for letter.
+SHARED = ("run.py", "control.py", "faults.py", "harness", "drivers", "metrics")
+
+
+def _knowing_the_second_family(bench_dir):
+    """``(file, word)`` for every shared file under ``bench_dir`` that holds
+    the toy family's name or one of its model keys."""
     own = json.load(open(os.path.join(TINY, "configs", "hfkeys-tiny.json")))
     words = ["hfkeys"] + [k for k in own if k not in (
         "family", "vocab_size", "param_dtype", "serving", "assumed")]
     assert "hidden_size" in words and "num_hidden_layers" in words
-    for folder, _, files in os.walk(manifest.BENCH_DIR):
-        for name in files:
-            if name.endswith((".py", ".json")):
-                text = open(os.path.join(folder, name), encoding="utf-8").read()
-                for word in words:
-                    assert not re.search(rf"\b{word}\b", text), (name, word)
+    found = []
+    for top in SHARED:
+        top = pathlib.Path(bench_dir, top)
+        assert top.exists(), top
+        for path in [top] if top.is_file() else sorted(top.rglob("*")):
+            if path.suffix in (".py", ".json"):
+                text = path.read_text(encoding="utf-8")
+                found += [(path.name, word) for word in words
+                          if re.search(rf"\b{word}\b", text)]
+    return found
+
+
+def test_no_file_of_perfbench_knows_the_second_family():
+    assert _knowing_the_second_family(manifest.BENCH_DIR) == []
+
+
+def test_a_shared_file_that_learns_a_familys_key_is_found(tmp_path):
+    """The walk above on a copy of the shared files with one key of the
+    second family written into ``harness/``: it must be found, there and in
+    no other file."""
+    for top in SHARED:
+        src = os.path.join(manifest.BENCH_DIR, top)
+        if os.path.isdir(src):
+            shutil.copytree(src, tmp_path / top,
+                            ignore=shutil.ignore_patterns("__pycache__"))
+        else:
+            shutil.copy(src, tmp_path / top)
+    assert _knowing_the_second_family(str(tmp_path)) == []
+    with open(tmp_path / "harness" / "counts.py", "a", encoding="utf-8") as f:
+        f.write('\nWIDTH = lambda model: model["hidden_size"]\n')
+    assert _knowing_the_second_family(str(tmp_path)) == [("counts.py", "hidden_size")]
 
 
 @pytest.mark.parametrize("cell,metric", [(SERVE, "serve_tok_s"), (TRAIN, "train_tok_s_chip")])
