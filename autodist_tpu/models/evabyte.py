@@ -350,8 +350,8 @@ def forward_paged_decode_step(params, tokens, positions, cache, page_tables,
 
 def decode_model(cfg: EvaByteConfig, eos_id: Optional[int] = None):
     """The serving adapter: the paged surface and the statement of the
-    cache. No bucketed surface and no ``verify_paged``: speculative
-    verification over a ring is not carried (``serve/spec.py`` refuses)."""
+    cache. No ``verify_paged``: speculative verification over a ring is
+    not carried (``serve/spec.py`` refuses)."""
     from autodist_tpu.serve.engine import DecodeModel
 
     return DecodeModel(

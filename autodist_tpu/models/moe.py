@@ -32,7 +32,8 @@ from autodist_tpu.models import layers as L
 from autodist_tpu.models.spec import ModelSpec, register_model
 from autodist_tpu.models.transformer import (
     TransformerConfig,
-    _attention,
+    _attn_part,
+    _train_attend,
 )
 
 
@@ -116,18 +117,11 @@ def moe_ffn(p, x, cfg: MoEConfig):
 
 
 def _block(bp, x, cfg: MoEConfig):
+    """The dense model's attention half (``transformer._attn_part``, with
+    training's ``attend``) and the Switch FFN in place of its MLP half."""
     b, s, d = x.shape
-    h = L.layernorm(bp["ln1"], x)
-    q = L.dense(bp["attn"]["wq"], h, compute_dtype=cfg.dtype).reshape(
-        b, s, cfg.num_heads, cfg.head_dim)
-    k = L.dense(bp["attn"]["wk"], h, compute_dtype=cfg.dtype).reshape(
-        b, s, cfg.num_heads, cfg.head_dim)
-    v = L.dense(bp["attn"]["wv"], h, compute_dtype=cfg.dtype).reshape(
-        b, s, cfg.num_heads, cfg.head_dim)
-    o = _attention(q, k, v, cfg).reshape(b, s, d)
-    x = x + L.dense(bp["attn"]["wo"], o, compute_dtype=cfg.dtype).astype(x.dtype)
-
-    h = L.layernorm(bp["ln2"], x)
+    x, _ = _attn_part(bp, x, _train_attend(cfg), cfg)
+    h = L.layernorm(bp["ln2"], x, cfg.layer_norm_eps)
     y, aux = moe_ffn(bp["moe"], h.reshape(b * s, d), cfg)
     return x + y.reshape(b, s, d).astype(x.dtype), aux
 
@@ -141,7 +135,7 @@ def forward(params, tokens, cfg: MoEConfig):
         block = jax.checkpoint(_block) if cfg.remat else _block
         x, aux = block(params[f"layers_{i}"], x, cfg)
         aux_total = aux_total + aux
-    x = L.layernorm(params["ln_f"], x)
+    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
     logits = jnp.einsum(
         "bsd,vd->bsv", x, params["embed"]["embedding"].astype(cfg.dtype)
     ).astype(jnp.float32)

@@ -158,19 +158,29 @@ def _attention(q, k, v, cfg: TransformerConfig):
     raise ValueError(f"unknown attention_impl {cfg.attention_impl!r}")
 
 
-def _block(block_params, x, cfg: TransformerConfig):
-    b, s, _ = x.shape
+def _attn_part(block_params, x, attend, cfg: TransformerConfig):
+    """The attention half of the one layer body, over ``x [..., D]``
+    whatever its leading dims: pre-norm, the three projections split into
+    heads ``[..., H, head_dim]``, ``o, carry = attend(q, k, v)``, the
+    output projection and the residual. ``attend`` is all that differs
+    between the programs (training: :func:`_attention`; a paged program:
+    the write through its page table and its paged read, the written cache
+    as ``carry``). Returns ``(x, carry)``."""
+    lead = x.shape[:-1]
     h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
     attn_p = block_params["attn"]
     q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
     k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
     v = L.dense(attn_p["wv"], h, compute_dtype=cfg.dtype)
-    q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
-    k = k.reshape(b, s, cfg.num_heads, cfg.head_dim)
-    v = v.reshape(b, s, cfg.num_heads, cfg.head_dim)
-    o = _attention(q, k, v, cfg).reshape(b, s, cfg.d_model)
-    x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
+    heads = lead + (cfg.num_heads, cfg.head_dim)
+    o, carry = attend(q.reshape(heads), k.reshape(heads), v.reshape(heads))
+    o = o.reshape(lead + (cfg.d_model,))
+    return x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype), carry
 
+
+def _mlp_part(block_params, x, cfg: TransformerConfig):
+    """The MLP half of the one layer body: pre-norm, ``fc1``, GELU,
+    ``fc2``, residual."""
     h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
     h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
     h = jax.nn.gelu(h)
@@ -178,21 +188,42 @@ def _block(block_params, x, cfg: TransformerConfig):
     return x + h
 
 
+def _train_attend(cfg: TransformerConfig):
+    """Training's ``attend``: attention over the sequence itself, nothing
+    carried."""
+    return lambda q, k, v: (_attention(q, k, v, cfg), None)
+
+
+def _block(block_params, x, cfg: TransformerConfig):
+    x, _ = _attn_part(block_params, x, _train_attend(cfg), cfg)
+    return _mlp_part(block_params, x, cfg)
+
+
+def _embed(params, tokens, cfg: TransformerConfig, positions=None):
+    """Token + learned position embedding in the compute dtype;
+    ``positions`` None is the sequence's own ``0..S-1``."""
+    x = L.embedding_lookup(params["embed"], tokens).astype(cfg.dtype)
+    if positions is None:
+        positions = jnp.arange(tokens.shape[-1])
+    return x + L.embedding_lookup(params["pos_embed"], positions).astype(cfg.dtype)
+
+
+def _head(params, x, cfg: TransformerConfig):
+    """Tied output embedding: one big ``[.., D] x [D, V]`` matmul on the
+    MXU, in the compute dtype."""
+    return x.astype(cfg.dtype) @ params["embed"]["embedding"].T.astype(cfg.dtype)
+
+
 def forward(params, tokens, cfg: TransformerConfig):
     """tokens [B, S] int32 -> logits [B, S, V] (fp32)."""
-    b, s = tokens.shape
-    x = L.embedding_lookup(params["embed"], tokens).astype(cfg.dtype)
-    pos = jnp.arange(s)
-    x = x + L.embedding_lookup(params["pos_embed"], pos).astype(cfg.dtype)
+    x = _embed(params, tokens, cfg)
     block = partial(_block, cfg=cfg)
     if cfg.remat:
         block = jax.checkpoint(block)
     for i in range(cfg.num_layers):
         x = block(params[f"layers_{i}"], x)
     x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
-    # Tied output embedding: one big [B*S, D] x [D, V] matmul on the MXU.
-    logits = x.astype(cfg.dtype) @ params["embed"]["embedding"].T.astype(cfg.dtype)
-    return logits.astype(jnp.float32)
+    return _head(params, x, cfg).astype(jnp.float32)
 
 
 def loss_fn(params, batch, cfg: TransformerConfig):
@@ -210,123 +241,6 @@ def loss_fn(params, batch, cfg: TransformerConfig):
     mask = mask.astype(jnp.float32)  # 1 where masked
     per_tok = L.per_token_xent(logits, batch["labels"]) * mask
     return per_tok.sum() / jnp.maximum(mask.sum(), 1.0)
-
-
-# ------------------------------------------------------------ KV-cache decode
-def init_kv_cache(cfg: TransformerConfig, n_slots: int, max_len: int,
-                  dtype: Any = None) -> Dict[str, Any]:
-    """Preallocated decode cache for ``n_slots`` concurrent sequences.
-
-    One stacked array per projection — ``[num_layers, slots, max_len, heads,
-    head_dim]`` — so a whole decode step updates the cache with two
-    ``scatter``s instead of ``2 * num_layers`` and the serving engine can
-    donate it through the jitted step (in-place on device). ``max_len`` is
-    the slot's total timeline (prompt + generated), chosen per length bucket
-    by the engine; dtype defaults to the model's compute dtype.
-    """
-    dtype = dtype or cfg.dtype
-    shape = (cfg.num_layers, n_slots, max_len, cfg.num_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-
-def forward_prefill(params, tokens, length, cache, slot, cfg: TransformerConfig):
-    """Prompt pass: run the normal causal forward on ``tokens`` ``[1, S]``
-    (padded to the bucket), write each layer's k/v into cache row ``slot``
-    at positions ``[0, S)``, and return the greedy next token.
-
-    The attention itself is the UNCACHED forward (queries at position i
-    attend keys 0..i), so prefill logits match :func:`forward` exactly;
-    the cache is populated as a side product. Positions ``>= length`` hold
-    pad garbage, but the decode step's mask only admits positions
-    ``<= current`` and decode overwrites position ``length`` before first
-    attending it, so the garbage is never read.
-
-    Returns ``(next_token [1] int32, cache)`` where the token is the argmax
-    of the logits at position ``length - 1`` — the first generated token.
-    """
-    b, s = tokens.shape
-    x = L.embedding_lookup(params["embed"], tokens).astype(cfg.dtype)
-    pos = jnp.arange(s)
-    x = x + L.embedding_lookup(params["pos_embed"], pos).astype(cfg.dtype)
-    for i in range(cfg.num_layers):
-        block_params = params[f"layers_{i}"]
-        h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
-        attn_p = block_params["attn"]
-        q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
-        k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
-        v = L.dense(attn_p["wv"], h, compute_dtype=cfg.dtype)
-        q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(b, s, cfg.num_heads, cfg.head_dim)
-        v = v.reshape(b, s, cfg.num_heads, cfg.head_dim)
-        cache_dtype = cache["k"].dtype
-        cache["k"] = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache_dtype)[None],
-            (i, slot, 0, 0, 0))
-        cache["v"] = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache_dtype)[None],
-            (i, slot, 0, 0, 0))
-        o = _dot_attention(q, k, v, causal=True).reshape(b, s, cfg.d_model)
-        x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
-        h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
-        h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
-        h = jax.nn.gelu(h)
-        h = L.dense(block_params["mlp"]["fc2"], h, compute_dtype=cfg.dtype)
-        x = x + h
-    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
-    last = x[jnp.arange(b), length - 1]                      # [B, D]
-    logits = (last.astype(cfg.dtype)
-              @ params["embed"]["embedding"].T.astype(cfg.dtype))
-    return jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32), cache
-
-
-def forward_decode_step(params, tokens, positions, cache, cfg: TransformerConfig):
-    """One incremental decode step over every cache slot.
-
-    ``tokens [B] int32`` is each slot's current token (B == slot count),
-    ``positions [B]`` its absolute timeline index. Each layer writes the
-    token's k/v into ``cache[:, b, positions[b]]`` and attends over the
-    cache with the mask ``j <= positions[b]`` — the incremental equivalent
-    of the causal forward's row ``positions[b]``. Inactive slots compute
-    garbage under the same mask (cheap; the engine ignores their outputs).
-
-    Returns ``(next_token [B] int32, cache)``.
-    """
-    b = tokens.shape[0]
-    max_len = cache["k"].shape[2]
-    rows = jnp.arange(b)
-    x = L.embedding_lookup(params["embed"], tokens).astype(cfg.dtype)
-    x = x + L.embedding_lookup(params["pos_embed"], positions).astype(cfg.dtype)
-    mask = pa_ops.position_mask(max_len, positions)              # [B, L]
-    for i in range(cfg.num_layers):
-        block_params = params[f"layers_{i}"]
-        h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
-        attn_p = block_params["attn"]
-        q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
-        k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
-        v = L.dense(attn_p["wv"], h, compute_dtype=cfg.dtype)
-        q = q.reshape(b, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(b, cfg.num_heads, cfg.head_dim)
-        v = v.reshape(b, cfg.num_heads, cfg.head_dim)
-        cache_dtype = cache["k"].dtype
-        cache["k"] = cache["k"].at[i, rows, positions].set(k.astype(cache_dtype))
-        cache["v"] = cache["v"].at[i, rows, positions].set(v.astype(cache_dtype))
-        ck = cache["k"][i].astype(cfg.dtype)                 # [B, L, H, D]
-        cv = cache["v"][i].astype(cfg.dtype)
-        logits = jnp.einsum("bhd,blhd->bhl", q, ck).astype(jnp.float32)
-        logits = logits / jnp.sqrt(cfg.head_dim).astype(jnp.float32)
-        logits = pa_ops.apply_mask(logits, mask[:, None, :])
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        o = jnp.einsum("bhl,blhd->bhd", probs, cv).reshape(b, cfg.d_model)
-        x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
-        h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
-        h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
-        h = jax.nn.gelu(h)
-        h = L.dense(block_params["mlp"]["fc2"], h, compute_dtype=cfg.dtype)
-        x = x + h
-    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
-    logits = (x.astype(cfg.dtype)
-              @ params["embed"]["embedding"].T.astype(cfg.dtype))
-    return jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32), cache
 
 
 # --------------------------------------------------------- paged KV decode
@@ -373,17 +287,6 @@ def init_paged_kv_cache(cfg: TransformerConfig, n_pages: int, page_len: int,
     return {"k": leaves(shape, dtype), "v": leaves(shape, dtype)}
 
 
-def _resolve_paged_impl(cfg: TransformerConfig, batch: int,
-                        table_pages: int, page_len: int) -> str:
-    """Trace-time kernel-vs-gather choice for one paged program — static,
-    so the engine's compiled-program pins (2 serve + 1 verify) never fork
-    on it. The math itself lives in ops/paged_attention.py only."""
-    from autodist_tpu.ops.crossover import resolve_paged_impl
-
-    return resolve_paged_impl(cfg.paged_attention_impl, batch, table_pages,
-                              page_len, cfg.num_heads)
-
-
 def _own_leaves(cache):
     """The cache with leaf lists of its own: the programs below replace a
     layer's leaf as they go and must not write into the caller's lists."""
@@ -416,10 +319,55 @@ def _paged_scatter(cache, layer, page_of, off, k, v):
     return cache
 
 
-def _layer_scales(cache, layer):
-    if "k_scale" in cache:
-        return cache["k_scale"][layer], cache["v_scale"][layer]
-    return None, None
+def _paged_layers(params, x, cache, page_of, off, attention, tables,
+                  positions, cfg: TransformerConfig):
+    """The layer stack and the final norm of a paged program over ``x [B,
+    ..., D]``. Each layer is the one body (:func:`_attn_part`,
+    :func:`_mlp_part`) around an ``attend`` that scatters the rows' k/v
+    through ``(page_of, off)`` and reads with ``attention``, the one of
+    ``ops/paged_attention.py``'s three entry points the program calls
+    with its ``tables`` and ``positions``. The kernel-vs-gather choice is
+    made once here at trace time — static, so the engine's compiled-program
+    pins (two serving programs, three with speculative verification) never
+    fork on it; the math itself lives in ops/paged_attention.py only.
+    Returns ``(x, cache)``: the cache is threaded through ``attend`` as a
+    value, never written behind the body's back."""
+    from autodist_tpu.ops.crossover import resolve_paged_impl
+
+    cache = _own_leaves(cache)
+    impl = resolve_paged_impl(
+        cfg.paged_attention_impl, x.shape[0], tables.shape[-1],
+        cache["k"][0].shape[1], cfg.num_heads)
+    for i in range(cfg.num_layers):
+        def attend(q, k, v, i=i, cache=cache):
+            # The rows take the leading dims of their table indices: the
+            # chunk program's lead with a batch dim of 1 its indices lack.
+            q, k, v = (t.reshape(page_of.shape + t.shape[-2:])
+                       for t in (q, k, v))
+            cache = _paged_scatter(cache, i, page_of, off, k, v)
+            ks, vs = ((cache["k_scale"][i], cache["v_scale"][i])
+                      if "k_scale" in cache else (None, None))
+            return attention(
+                q, cache["k"][i], cache["v"][i], tables, positions,
+                k_scale=ks, v_scale=vs, impl=impl,
+                compute_dtype=cfg.dtype), cache
+
+        block_params = params[f"layers_{i}"]
+        x, cache = _attn_part(block_params, x, attend, cfg)
+        x = _mlp_part(block_params, x, cfg)
+    return L.layernorm(params["ln_f"], x, cfg.layer_norm_eps), cache
+
+
+def _pick(logits, counters, samp):
+    """The token a paged program ends with: the greedy argmax, or with
+    ``samp`` (the per-slot sampling arrays, serve/sampling.py) the
+    counter-keyed sample, ``counters`` being the emitted tokens' absolute
+    positions; ``temperature<=0`` rows still return the argmax bit-exact."""
+    if samp is None:
+        return jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+    from autodist_tpu.serve.sampling import sample_tokens
+
+    return sample_tokens(logits, counters, samp)
 
 
 def forward_paged_prefill_chunk(params, tokens, start, length, cache,
@@ -444,60 +392,29 @@ def forward_paged_prefill_chunk(params, tokens, start, length, cache,
     Returns ``(next_token [1], cache)``; the token is the argmax at
     position ``length - 1``, meaningful only on the chunk containing it
     (the host uses the final chunk's value — prefill emits the first
-    generated token, exactly like the unpaged prefill). With ``samp``
+    generated token). With ``samp``
     (the per-slot sampling arrays, serve/sampling.py) the token is the
     counter-keyed sample at absolute position ``length`` instead —
     identical on every chunk, so the host's final-chunk read is
     unchanged; ``temperature<=0`` rows still return the argmax bit-exact.
     """
     b, c = tokens.shape
-    cache = _own_leaves(cache)
     page_len = cache["k"][0].shape[1]
     pos = start + jnp.arange(c)                                   # [C] absolute
     page_of = page_table[pos // page_len]                         # [C]
     off = pos % page_len
-    impl = _resolve_paged_impl(cfg, 1, page_table.shape[0], page_len)
     # Clamp the positional-embedding lookup only: pad positions may sit past
     # the table (their k/v land in scratch) but must still embed in-range.
-    emb_pos = jnp.minimum(pos, cfg.max_seq_len - 1)
-    x = L.embedding_lookup(params["embed"], tokens).astype(cfg.dtype)
-    x = x + L.embedding_lookup(params["pos_embed"], emb_pos).astype(cfg.dtype)
-    for i in range(cfg.num_layers):
-        block_params = params[f"layers_{i}"]
-        h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
-        attn_p = block_params["attn"]
-        q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
-        k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
-        v = L.dense(attn_p["wv"], h, compute_dtype=cfg.dtype)
-        q = q.reshape(c, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(c, cfg.num_heads, cfg.head_dim)
-        v = v.reshape(c, cfg.num_heads, cfg.head_dim)
-        cache = _paged_scatter(cache, i, page_of, off, k, v)
-        ks, vs = _layer_scales(cache, i)
-        o = pa_ops.paged_prefill_attention(
-            q, cache["k"][i], cache["v"][i], page_table, pos,
-            k_scale=ks, v_scale=vs, impl=impl,
-            compute_dtype=cfg.dtype).reshape(b, c, cfg.d_model)
-        x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
-        h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
-        h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
-        h = jax.nn.gelu(h)
-        h = L.dense(block_params["mlp"]["fc2"], h, compute_dtype=cfg.dtype)
-        x = x + h
-    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
+    x = _embed(params, tokens, cfg, jnp.minimum(pos, cfg.max_seq_len - 1))
+    x, cache = _paged_layers(params, x, cache, page_of, off,
+                             pa_ops.paged_prefill_attention, page_table, pos,
+                             cfg)
     frontier = jnp.clip(length - 1 - start, 0, c - 1)
-    last = x[jnp.arange(b), frontier]                             # [1, D]
-    logits = (last.astype(cfg.dtype)
-              @ params["embed"]["embedding"].T.astype(cfg.dtype))
-    if samp is None:
-        return (jnp.argmax(logits.astype(jnp.float32), axis=-1)
-                .astype(jnp.int32), cache)
-    from autodist_tpu.serve.sampling import sample_tokens
-
+    logits = _head(params, x[jnp.arange(b), frontier], cfg)       # [1, V]
     # The emitted token's absolute position is `length` (prompt occupies
     # 0..length-1) — the same counter on every chunk of this prompt.
     counters = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
-    return sample_tokens(logits, counters, samp), cache
+    return _pick(logits, counters, samp), cache
 
 
 def forward_paged_decode_step(params, tokens, positions, cache, page_tables,
@@ -506,66 +423,35 @@ def forward_paged_decode_step(params, tokens, positions, cache, page_tables,
     """One incremental decode step over every decode row: the SINGLE
     compiled decode program for all active requests.
 
-    ``tokens [B]`` / ``positions [B]`` as in :func:`forward_decode_step`;
-    ``page_tables [B, P]`` maps each row's timeline onto pool pages (idle
-    rows carry all-scratch tables and compute finite garbage the engine
-    ignores). Each layer scatters the token's k/v through the row's table
-    and attends over the gathered timeline under ``j <= positions[b]`` —
-    the paged rendering of the stacked-cache step, so one program serves
-    any mix of request lengths.
+    ``tokens [B] int32`` is each row's current token (B == slot count),
+    ``positions [B]`` its absolute timeline index; ``page_tables [B, P]``
+    maps each row's timeline onto pool pages (idle rows carry all-scratch
+    tables and compute finite garbage the engine ignores). Each layer
+    scatters the token's k/v through the row's table and attends over the
+    gathered timeline under ``j <= positions[b]`` — the incremental
+    equivalent of the causal forward's row ``positions[b]``, so one
+    program serves any mix of request lengths.
 
     Returns ``(next_token [B] int32, cache)``.
     """
-    b = tokens.shape[0]
-    cache = _own_leaves(cache)
     page_len = cache["k"][0].shape[1]
-    rows = jnp.arange(b)
+    rows = jnp.arange(tokens.shape[0])
     page_of = page_tables[rows, positions // page_len]            # [B]
     off = positions % page_len
-    impl = _resolve_paged_impl(cfg, b, page_tables.shape[1], page_len)
-    emb_pos = jnp.minimum(positions, cfg.max_seq_len - 1)
-    x = L.embedding_lookup(params["embed"], tokens).astype(cfg.dtype)
-    x = x + L.embedding_lookup(params["pos_embed"], emb_pos).astype(cfg.dtype)
-    for i in range(cfg.num_layers):
-        block_params = params[f"layers_{i}"]
-        h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
-        attn_p = block_params["attn"]
-        q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
-        k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
-        v = L.dense(attn_p["wv"], h, compute_dtype=cfg.dtype)
-        q = q.reshape(b, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(b, cfg.num_heads, cfg.head_dim)
-        v = v.reshape(b, cfg.num_heads, cfg.head_dim)
-        cache = _paged_scatter(cache, i, page_of, off, k, v)
-        ks, vs = _layer_scales(cache, i)
-        o = pa_ops.paged_decode_attention(
-            q, cache["k"][i], cache["v"][i], page_tables, positions,
-            k_scale=ks, v_scale=vs, impl=impl,
-            compute_dtype=cfg.dtype).reshape(b, cfg.d_model)
-        x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
-        h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
-        h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
-        h = jax.nn.gelu(h)
-        h = L.dense(block_params["mlp"]["fc2"], h, compute_dtype=cfg.dtype)
-        x = x + h
-    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
-    logits = (x.astype(cfg.dtype)
-              @ params["embed"]["embedding"].T.astype(cfg.dtype))
+    x = _embed(params, tokens, cfg,
+               jnp.minimum(positions, cfg.max_seq_len - 1))
+    x, cache = _paged_layers(params, x, cache, page_of, off,
+                             pa_ops.paged_decode_attention, page_tables,
+                             positions, cfg)
+    logits = _head(params, x, cfg)
     if return_logits:
         # Drift-probe path (tests / selftest only — never compiled by the
         # engine, so the program pins don't see it): expose the fp32
         # logits next to the token for quant-vs-fp oracle comparison.
-        return (jnp.argmax(logits.astype(jnp.float32), axis=-1)
-                .astype(jnp.int32), logits.astype(jnp.float32), cache)
-    if samp is None:
-        return (jnp.argmax(logits.astype(jnp.float32), axis=-1)
-                .astype(jnp.int32), cache)
-    from autodist_tpu.serve.sampling import sample_tokens
-
+        return (_pick(logits, None, None), logits.astype(jnp.float32), cache)
     # The incoming token sits at `positions`; the emitted token's
     # absolute position — the draw counter — is `positions + 1`.
-    return sample_tokens(logits, positions.astype(jnp.int32) + 1,
-                         samp), cache
+    return _pick(logits, positions.astype(jnp.int32) + 1, samp), cache
 
 
 def forward_paged_verify(params, tokens, positions, cache, page_tables,
@@ -603,11 +489,9 @@ def forward_paged_verify(params, tokens, positions, cache, page_tables,
     target's own bonus/correction token — which is bit-identical to what
     plain greedy decode would have produced.
     """
-    b, k1 = tokens.shape
-    cache = _own_leaves(cache)
+    k1 = tokens.shape[1]
     page_len = cache["k"][0].shape[1]
     n_tables = page_tables.shape[1]
-    impl = _resolve_paged_impl(cfg, b, n_tables, page_len)
     rows_pos = positions[:, None] + jnp.arange(k1)[None, :]       # [B, K1]
     pidx = rows_pos // page_len
     # Past the static table width -> the reserved scratch page (0): the
@@ -626,46 +510,16 @@ def forward_paged_verify(params, tokens, positions, cache, page_tables,
     # Acceptance below compares the RAW proposals, so a clamped
     # out-of-vocab id can never falsely match the target's argmax.
     emb_ids = jnp.clip(tokens, 0, cfg.vocab_size - 1)
-    x = L.embedding_lookup(params["embed"], emb_ids).astype(cfg.dtype)
-    x = x + L.embedding_lookup(params["pos_embed"], emb_pos).astype(cfg.dtype)
-    for i in range(cfg.num_layers):
-        block_params = params[f"layers_{i}"]
-        h = L.layernorm(block_params["ln1"], x, cfg.layer_norm_eps)
-        attn_p = block_params["attn"]
-        q = L.dense(attn_p["wq"], h, compute_dtype=cfg.dtype)
-        k = L.dense(attn_p["wk"], h, compute_dtype=cfg.dtype)
-        v = L.dense(attn_p["wv"], h, compute_dtype=cfg.dtype)
-        q = q.reshape(b, k1, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(b, k1, cfg.num_heads, cfg.head_dim)
-        v = v.reshape(b, k1, cfg.num_heads, cfg.head_dim)
-        cache = _paged_scatter(cache, i, page_of, off, k, v)
-        ks, vs = _layer_scales(cache, i)
-        o = pa_ops.paged_verify_attention(
-            q, cache["k"][i], cache["v"][i], page_tables, rows_pos,
-            k_scale=ks, v_scale=vs, impl=impl,
-            compute_dtype=cfg.dtype).reshape(b, k1, cfg.d_model)
-        x = x + L.dense(attn_p["wo"], o, compute_dtype=cfg.dtype)
-        h = L.layernorm(block_params["ln2"], x, cfg.layer_norm_eps)
-        h = L.dense(block_params["mlp"]["fc1"], h, compute_dtype=cfg.dtype)
-        h = jax.nn.gelu(h)
-        h = L.dense(block_params["mlp"]["fc2"], h, compute_dtype=cfg.dtype)
-        x = x + h
-    x = L.layernorm(params["ln_f"], x, cfg.layer_norm_eps)
-    logits = (x.astype(cfg.dtype)
-              @ params["embed"]["embedding"].T.astype(cfg.dtype))
-    if samp is None:
-        out = jnp.argmax(logits.astype(jnp.float32),
-                         axis=-1).astype(jnp.int32)
-    else:
-        from autodist_tpu.serve.sampling import sample_tokens
-
-        # out[b, j] is the token emitted after the prefix through
-        # tokens[b, j] — absolute position rows_pos + 1, the same
-        # counter plain decode uses for that position, so the coupled
-        # sample here IS the plain stochastic stream's token and the
-        # accept count below stays lossless for any draft
-        # (serve/sampling.py § coupling).
-        out = sample_tokens(logits, rows_pos.astype(jnp.int32) + 1, samp)
+    x = _embed(params, emb_ids, cfg, emb_pos)
+    x, cache = _paged_layers(params, x, cache, page_of, off,
+                             pa_ops.paged_verify_attention, page_tables,
+                             rows_pos, cfg)
+    # out[b, j] is the token emitted after the prefix through
+    # tokens[b, j] — absolute position rows_pos + 1, the same counter
+    # plain decode uses for that position, so a coupled sample here IS
+    # the plain stochastic stream's token and the accept count below
+    # stays lossless for any draft (serve/sampling.py § coupling).
+    out = _pick(_head(params, x, cfg), rows_pos.astype(jnp.int32) + 1, samp)
     # Accept/reject on device: count the leading proposals that match
     # the target's own (argmax or coupled-sample) token per position.
     match = (tokens[:, 1:] == out[:, :-1]).astype(jnp.int32)      # [B, K]
@@ -674,21 +528,15 @@ def forward_paged_verify(params, tokens, positions, cache, page_tables,
 
 
 def decode_model(cfg: TransformerConfig, eos_id: Optional[int] = None):
-    """The transformer's serving adapter — the pure cache functions bound to
-    one config, in the shape :class:`autodist_tpu.serve.InferenceEngine`
-    consumes (see serve/engine.py DecodeModel). Carries BOTH cache
-    renderings: the paged functions the production engine compiles, and
-    the stacked bucketed ones the legacy baseline/oracle engine keeps."""
+    """The transformer's serving adapter — the pure paged-cache functions
+    bound to one config, in the shape
+    :class:`autodist_tpu.serve.InferenceEngine` consumes (see
+    serve/engine.py DecodeModel)."""
     from autodist_tpu.serve.engine import DecodeModel
 
     return DecodeModel(
-        init_cache=lambda n_slots, max_len: init_kv_cache(cfg, n_slots, max_len),
-        prefill=lambda params, tokens, length, cache, slot: forward_prefill(
-            params, tokens, length, cache, slot, cfg),
-        decode_step=lambda params, tokens, positions, cache: forward_decode_step(
-            params, tokens, positions, cache, cfg),
-        init_paged_cache=lambda n_pages, page_len: init_paged_kv_cache(
-            cfg, n_pages, page_len),
+        init_paged_cache=lambda n_pages, page_len, quantized=None:
+            init_paged_kv_cache(cfg, n_pages, page_len, quantized=quantized),
         prefill_chunk=lambda params, tokens, start, length, cache, table,
             samp=None: forward_paged_prefill_chunk(
                 params, tokens, start, length, cache, table, cfg, samp=samp),

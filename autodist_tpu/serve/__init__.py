@@ -16,17 +16,16 @@ Layers:
   restored from a checkpoint into plan shardings, a jitted one-shot apply,
   and a paged KV-cache decode loop — exactly TWO compiled serving programs
   (one decode over all slot rows + one fixed-size prefill chunk) for any
-  request-length mix. :class:`BucketedInferenceEngine` keeps the previous
-  length-bucketed design as the selftest's equal-HBM baseline.
+  request-length mix.
 - :mod:`autodist_tpu.serve.batcher` — :class:`ContinuousBatcher`: bounded
   admission queue with backpressure, page-availability admission (typed
   :class:`~autodist_tpu.serve.engine.AdmissionDenied` — retryable pool
   pressure vs never-placeable rejection), chunked prefill interleaved with
   decode, per-request deadlines, page recycling on retirement.
 - :mod:`autodist_tpu.serve.server` — asyncio HTTP front end and the
-  ``python -m autodist_tpu.serve --selftest`` CPU-sim proof (>=2x
-  concurrency vs the bucketed baseline at equal KV HBM, zero drops,
-  bit-identical greedy streams, exactly 2 compiled programs).
+  ``python -m autodist_tpu.serve --selftest`` CPU-sim proof (greedy
+  streams bit-identical to the uncached forward's, zero drops, exactly
+  2 compiled programs).
 
 - :mod:`autodist_tpu.serve.replica` / :mod:`autodist_tpu.serve.router` —
   the multi-replica control plane: N supervised replicas exporting typed
@@ -96,7 +95,6 @@ from autodist_tpu.serve.batcher import (
 )
 from autodist_tpu.serve.engine import (
     AdmissionDenied,
-    BucketedInferenceEngine,
     DecodeModel,
     EngineDeadError,
     InferenceEngine,
@@ -117,7 +115,6 @@ from autodist_tpu.serve.spec import SpecDecodeEngine
 __all__ = [
     "AdmissionDenied",
     "Backpressure",
-    "BucketedInferenceEngine",
     "ContinuousBatcher",
     "DecodeModel",
     "EngineDeadError",

@@ -3,9 +3,9 @@
 Six modes:
 
 - ``--selftest``: the zero-hardware single-engine proof (tiny CPU
-  transformer; >=2x concurrency vs the bucketed baseline at equal KV HBM,
-  bit-identical greedy streams, >=64 concurrent mock requests with zero
-  drops, exactly 2 compiled serving programs). Run with
+  transformer; greedy streams bit-identical to the uncached forward's,
+  >=64 concurrent mock requests with zero drops, exactly 2 compiled
+  serving programs). Run with
   ``JAX_PLATFORMS=cpu``; exits nonzero on any violated bar.
 - ``--selftest-router``: the multi-replica control-plane proof
   (docs/serving.md § router): 3 in-process replicas behind the router,

@@ -27,18 +27,13 @@ the pages the whole ``prompt + max_new`` timeline needs under the model's
 layout (one per ``page_len`` positions; or, over a window ring, the ring's
 pages and one summary page per ``page_len**2`` positions); retirement
 recycles them in the same tick.
-
-:class:`BucketedInferenceEngine` keeps the previous length-bucketed stacked
-slot pools as the comparison baseline the serve selftest measures the paged
-design against (>=2x concurrency at equal KV HBM, bit-identical greedy
-streams) — production traffic uses the paged engine.
 """
 from __future__ import annotations
 
 import math
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -53,8 +48,6 @@ from autodist_tpu.obs import spans as obs_spans
 from autodist_tpu.serve import pages as serve_pages
 from autodist_tpu.serve import prefix as serve_prefix
 from autodist_tpu.serve import sampling as serve_sampling
-
-DEFAULT_BUCKET_LENS = (32, 64, 128, 256, 512, 1024)
 
 #: Slot phases (host bookkeeping; single scheduler-thread writer).
 _FREE, _PREFILL, _DECODE = 0, 1, 2
@@ -71,12 +64,15 @@ class EngineDeadError(RuntimeError):
 class DecodeModel:
     """Model adapter for autoregressive decode — pure functions, one config.
 
-    Paged surface (the production engine; all three required):
+    The paged surface (all three required):
 
     - ``init_paged_cache(n_pages, page_len) -> cache`` pytree whose
       leaves carry the page dim at ``cache_layout.page_axis`` (dim 0, a
       leaf a layer, where the model states no layout; the engine shards
-      it over the mesh data axis);
+      it over the mesh data axis). A model whose cache holds int8 pages
+      (``*_scale`` leaves beside them) also takes ``quantized=False`` and
+      gives the cache it would hold unquantised: what the engine prices
+      the capacity win against;
     - ``prefill_chunk(params, tokens [1,C], start, length, cache,
       page_table [P]) -> (next_token [1], cache)`` — writes prompt
       positions ``[start, start+C)`` through the page table; the returned
@@ -84,10 +80,6 @@ class DecodeModel:
     - ``decode_paged(params, tokens [B], positions [B], cache,
       page_tables [B,P]) -> (next_token [B], cache)`` with
       ``B == n_slots``.
-
-    Bucketed surface (:class:`BucketedInferenceEngine`, the selftest's
-    equal-HBM baseline and the oracle's cached side): ``init_cache``,
-    ``prefill``, ``decode_step`` — see that class.
 
     ``eos_id``: generation stops when emitted (None = length-only);
     ``max_len``: the model's positional ceiling.
@@ -105,12 +97,9 @@ class DecodeModel:
     way.
     """
 
-    init_cache: Optional[Callable[[int, int], Any]] = None
-    prefill: Optional[Callable[..., Tuple[Any, Any]]] = None
-    decode_step: Optional[Callable[..., Tuple[Any, Any]]] = None
-    init_paged_cache: Optional[Callable[[int, int], Any]] = None
-    prefill_chunk: Optional[Callable[..., Tuple[Any, Any]]] = None
-    decode_paged: Optional[Callable[..., Tuple[Any, Any]]] = None
+    init_paged_cache: Callable[..., Any]
+    prefill_chunk: Callable[..., Tuple[Any, Any]]
+    decode_paged: Callable[..., Tuple[Any, Any]]
     # Speculative-decode verification surface (serve/spec.py): one batched
     # multi-position forward ``(params, tokens [B, K+1], positions [B],
     # cache, page_tables [B, P]) -> (accept [B], out_tokens [B, K+1],
@@ -143,85 +132,7 @@ class AdmissionDenied:
     retryable: bool
 
 
-class _EngineBase:
-    """Shared params-in-plan-shardings setup + one-shot inference."""
-
-    def __init__(self, params: Any, plan: ShardingPlan,
-                 apply_fn: Optional[Callable] = None):
-        self.plan = plan
-        self.mesh = plan.mesh
-        self._data_axis = data_axis(self.mesh)
-        self._data_degree = dict(
-            zip(self.mesh.axis_names, self.mesh.devices.shape))[self._data_axis]
-        # Storage view + plan shardings: the same parameter contract the
-        # train step uses (pad-and-mask plans store padded; the wrapped fns
-        # below unpad under the trace). device_view: serving ignores
-        # host-offload markers — params stay HBM-resident (offload is a
-        # training-memory bargain inference has no reason to pay per step).
-        self.params = jax.device_put(
-            plan.pad_params(params),
-            plan.params_shardings(
-                jax.eval_shape(lambda: plan.pad_params(params)),
-                device_view=True),
-        )
-        self._apply_fn = apply_fn
-        self._apply_jit = (
-            jax.jit(lambda p, b: apply_fn(plan.unpad_params(p), b))
-            if apply_fn is not None else None
-        )
-
-    @staticmethod
-    def restore_params(checkpoint: str, params_template: Any,
-                       plan: ShardingPlan) -> Any:
-        """Checkpoint → params in plan shardings (partial, parallel read).
-
-        ``checkpoint`` is a checkpoint dir (``.../ckpt-N``) or a Saver
-        directory (the newest ``ckpt-*`` inside is taken). The template
-        supplies the pytree structure + logical shapes; a training
-        checkpoint's extra entries (optimizer slots, step) are ignored —
-        saving ``state.params`` or the whole logical state both serve.
-        """
-        import os
-
-        from autodist_tpu.checkpoint.saver import Saver
-
-        if os.path.exists(os.path.join(checkpoint, "metadata.json")):
-            saver, path = Saver(os.path.dirname(checkpoint)), checkpoint
-        else:
-            saver = Saver(checkpoint)
-            path = saver.latest_checkpoint()
-            if path is None:
-                raise FileNotFoundError(f"no ckpt-* under {checkpoint!r}")
-        shaped = jax.eval_shape(lambda: params_template)
-        # Serving keeps params HBM-resident regardless of training-time
-        # offload markers (device_view): offload trades HBM for per-step
-        # streaming, a training-memory bargain inference has no reason to pay.
-        shardings = plan.params_shardings(shaped, device_view=True)
-        # A checkpoint written from a full train state (step.save) prefixes
-        # every parameter with "params/"; restore just that subtree so the
-        # optimizer/step entries are never read.
-        from autodist_tpu.model_item import _path_to_name
-
-        leaves, _ = jax.tree_util.tree_flatten_with_path(shaped)
-        probe = _path_to_name(leaves[0][0]) if leaves else ""
-        entries = Saver.read_metadata(path)["entries"]
-        if probe and probe not in entries and f"params/{probe}" in entries:
-            return saver.restore_subtree(path, "params", shaped, shardings)
-        return saver.restore(path, target=shaped, shardings=shardings)
-
-    # --------------------------------------------------------------- one-shot
-    def infer(self, batch: Any) -> Any:
-        """One-shot forward (classification, scoring): batch shards over the
-        data axis, output stays a device pytree."""
-        if self._apply_jit is None:
-            raise ValueError("engine built without apply_fn; one-shot "
-                             "inference unavailable")
-        batch = jax.device_put(
-            batch, self.plan.batch_shardings(batch, strict=False))
-        return self._apply_jit(self.params, batch)
-
-
-class InferenceEngine(_EngineBase):
+class InferenceEngine:
     """Serve a (possibly sharded) model: ``infer`` for one-shot batches,
     ``admit``/``prefill_step``/``step``/``release`` for paged
     continuous-batching decode.
@@ -274,16 +185,30 @@ class InferenceEngine(_EngineBase):
             raise ValueError(
                 "InferenceEngine needs apply_fn (one-shot), decode_model "
                 "(autoregressive), or both")
-        super().__init__(params, plan, apply_fn=apply_fn)
+        self.plan = plan
+        self.mesh = plan.mesh
+        self._data_axis = data_axis(self.mesh)
+        self._data_degree = dict(
+            zip(self.mesh.axis_names, self.mesh.devices.shape))[self._data_axis]
+        # Storage view + plan shardings: the same parameter contract the
+        # train step uses (pad-and-mask plans store padded; the wrapped fns
+        # below unpad under the trace). device_view: serving ignores
+        # host-offload markers — params stay HBM-resident (offload is a
+        # training-memory bargain inference has no reason to pay per step).
+        self.params = jax.device_put(
+            plan.pad_params(params),
+            plan.params_shardings(
+                jax.eval_shape(lambda: plan.pad_params(params)),
+                device_view=True),
+        )
+        self._apply_fn = apply_fn
+        self._apply_jit = (
+            jax.jit(lambda p, b: apply_fn(plan.unpad_params(p), b))
+            if apply_fn is not None else None
+        )
         self.decode_model = decode_model
         if decode_model is None:
             return
-        for fn in ("init_paged_cache", "prefill_chunk", "decode_paged"):
-            if getattr(decode_model, fn) is None:
-                raise ValueError(
-                    f"decode_model lacks the paged surface ({fn}); the "
-                    f"paged engine needs init_paged_cache + prefill_chunk "
-                    f"+ decode_paged (see DecodeModel)")
         # Decode rows shard over the data axis via the batch dim of the
         # per-step tensors; keep the row count divisible so gathers stay
         # even (round up rather than reject).
@@ -335,7 +260,7 @@ class InferenceEngine(_EngineBase):
         # no config plumbing — the scale leaves share the page dim and ride
         # the page-axis-keyed sharding/COW/pricing below unchanged. fp-equiv
         # bytes reprice the int8 value leaves at the model's fp cache dtype
-        # (from the stacked cache's leaf dtype) and drop the scale leaves
+        # (its own unquantised cache's leaf dtype) and drop the scale leaves
         # (which would not exist in fp mode): the "what would these pages
         # cost unquantized" figure the capacity-x metrics divide by.
         self.kv_quant = isinstance(page_shaped, dict) and \
@@ -348,8 +273,8 @@ class InferenceEngine(_EngineBase):
                 "keys (ROADMAP.md Queue 2)")
         if self.kv_quant:
             fp_itemsize = np.dtype(jax.tree_util.tree_leaves(jax.eval_shape(
-                lambda: decode_model.init_cache(1, self.page_len)
-            ))[0].dtype).itemsize
+                lambda: decode_model.init_paged_cache(
+                    1, self.page_len, quantized=False)))[0].dtype).itemsize
             self.page_fp_equiv_bytes = sum(
                 int(np.prod(leaf.shape)) * fp_itemsize
                 for name, leaves in page_shaped.items()
@@ -508,6 +433,56 @@ class InferenceEngine(_EngineBase):
             params = cls.restore_params(checkpoint, params, plan)
         return cls(params, plan, apply_fn=apply_fn, decode_model=decode_model,
                    resource_spec=resource_spec, **engine_kwargs)
+
+    @staticmethod
+    def restore_params(checkpoint: str, params_template: Any,
+                       plan: ShardingPlan) -> Any:
+        """Checkpoint → params in plan shardings (partial, parallel read).
+
+        ``checkpoint`` is a checkpoint dir (``.../ckpt-N``) or a Saver
+        directory (the newest ``ckpt-*`` inside is taken). The template
+        supplies the pytree structure + logical shapes; a training
+        checkpoint's extra entries (optimizer slots, step) are ignored —
+        saving ``state.params`` or the whole logical state both serve.
+        """
+        import os
+
+        from autodist_tpu.checkpoint.saver import Saver
+
+        if os.path.exists(os.path.join(checkpoint, "metadata.json")):
+            saver, path = Saver(os.path.dirname(checkpoint)), checkpoint
+        else:
+            saver = Saver(checkpoint)
+            path = saver.latest_checkpoint()
+            if path is None:
+                raise FileNotFoundError(f"no ckpt-* under {checkpoint!r}")
+        shaped = jax.eval_shape(lambda: params_template)
+        # Serving keeps params HBM-resident regardless of training-time
+        # offload markers (device_view): offload trades HBM for per-step
+        # streaming, a training-memory bargain inference has no reason to pay.
+        shardings = plan.params_shardings(shaped, device_view=True)
+        # A checkpoint written from a full train state (step.save) prefixes
+        # every parameter with "params/"; restore just that subtree so the
+        # optimizer/step entries are never read.
+        from autodist_tpu.model_item import _path_to_name
+
+        leaves, _ = jax.tree_util.tree_flatten_with_path(shaped)
+        probe = _path_to_name(leaves[0][0]) if leaves else ""
+        entries = Saver.read_metadata(path)["entries"]
+        if probe and probe not in entries and f"params/{probe}" in entries:
+            return saver.restore_subtree(path, "params", shaped, shardings)
+        return saver.restore(path, target=shaped, shardings=shardings)
+
+    # --------------------------------------------------------------- one-shot
+    def infer(self, batch: Any) -> Any:
+        """One-shot forward (classification, scoring): batch shards over the
+        data axis, output stays a device pytree."""
+        if self._apply_jit is None:
+            raise ValueError("engine built without apply_fn; one-shot "
+                             "inference unavailable")
+        batch = jax.device_put(
+            batch, self.plan.batch_shardings(batch, strict=False))
+        return self._apply_jit(self.params, batch)
 
     # ------------------------------------------------------------ decode pool
     def _cache_shardings(self, init_cache, n_pages: int):
@@ -1143,216 +1118,6 @@ class InferenceEngine(_EngineBase):
                     tokens.append(tok)
                     if len(tokens) >= max_new_tokens or tok == eos:
                         break
-        finally:
-            self.release(slot)
-        return tokens
-
-
-@dataclass(frozen=True)
-class BucketSlot:
-    """One occupied bucketed-engine slot: (bucket timeline length, row)."""
-
-    bucket: int
-    index: int
-
-
-@dataclass
-class _Bucket:
-    """Host-side bookkeeping for one bucket's stacked device cache."""
-
-    length: int                 # timeline capacity per slot
-    n_slots: int
-    cache: Any                  # device pytree, donated through decode
-    lengths: np.ndarray         # [slots] int32 — next write position
-    active: np.ndarray          # [slots] bool
-    last_token: np.ndarray      # [slots] int32 — token to feed next step
-    prefill_fn: Any = None      # compiled lazily
-    decode_fn: Any = None
-
-
-class BucketedInferenceEngine(_EngineBase):
-    """The pre-paging design, kept as the measured baseline: preallocated
-    length-bucketed stacked slot pools (one cache + one prefill + one
-    decode program PER bucket; a request routes to the smallest bucket
-    fitting ``prompt + max_new``). The serve selftest proves the paged
-    engine carries >=2x the concurrent requests of this engine at equal
-    KV HBM with bit-identical greedy streams; keep it for that proof and
-    as a second independent decode-path oracle — production serving is
-    :class:`InferenceEngine`.
-    """
-
-    def __init__(
-        self,
-        params: Any,
-        plan: ShardingPlan,
-        decode_model: DecodeModel,
-        n_slots: int = 8,
-        bucket_lens: Optional[Sequence[int]] = None,
-        max_len: Optional[int] = None,
-    ):
-        super().__init__(params, plan, apply_fn=None)
-        for fn in ("init_cache", "prefill", "decode_step"):
-            if getattr(decode_model, fn) is None:
-                raise ValueError(f"decode_model lacks the bucketed surface "
-                                 f"({fn})")
-        self.decode_model = decode_model
-        if n_slots % self._data_degree:
-            n_slots += self._data_degree - n_slots % self._data_degree
-        self.n_slots = n_slots
-        ceiling = min(
-            x for x in (max_len, decode_model.max_len) if x is not None
-        ) if (max_len or decode_model.max_len) else None
-        lens = list(bucket_lens or DEFAULT_BUCKET_LENS)
-        if ceiling is not None:
-            lens = [l for l in lens if l < ceiling] + [ceiling]
-        self._bucket_lens = tuple(sorted(set(lens)))
-        self.max_len = self._bucket_lens[-1]
-        self._buckets: Dict[int, _Bucket] = {}
-        cache_sh = self._slot_cache_shardings(decode_model.init_cache)
-        for length in self._bucket_lens:
-            cache = jax.device_put(
-                decode_model.init_cache(n_slots, length), cache_sh)
-            self._buckets[length] = _Bucket(
-                length=length,
-                n_slots=n_slots,
-                cache=cache,
-                lengths=np.zeros(n_slots, np.int32),
-                active=np.zeros(n_slots, bool),
-                last_token=np.zeros(n_slots, np.int32),
-            )
-
-    def _slot_cache_shardings(self, init_cache):
-        """Slot dim (dim 1 of rank>=2 leaves) over the data axis."""
-        from autodist_tpu.kernel.mesh import data_sharding
-
-        shaped = jax.eval_shape(lambda: init_cache(self.n_slots, 8))
-
-        def leaf_sh(leaf):
-            if len(leaf.shape) >= 2 and leaf.shape[1] == self.n_slots:
-                return data_sharding(self.mesh, len(leaf.shape), dim=1)
-            return NamedSharding(self.mesh, P())
-
-        return jax.tree_util.tree_map(leaf_sh, shaped)
-
-    def bucket_for(self, total_len: int) -> Optional[int]:
-        """Smallest bucket whose timeline fits ``total_len``; None = too
-        long."""
-        for length in self._bucket_lens:
-            if total_len <= length:
-                return length
-        return None
-
-    @property
-    def free_slots(self) -> int:
-        return sum(int((~b.active).sum()) for b in self._buckets.values())
-
-    @property
-    def active_slots(self) -> int:
-        return sum(int(b.active.sum()) for b in self._buckets.values())
-
-    @property
-    def active_tokens(self) -> int:
-        """Allocated timeline tokens across active slots (capacity
-        reserved, not yet-decoded length)."""
-        return sum(
-            int(b.active.sum()) * b.length for b in self._buckets.values())
-
-    @property
-    def kv_pool_tokens(self) -> int:
-        """Total timeline tokens the stacked pools hold in HBM — the
-        equal-HBM axis the selftest sizes the paged pool against."""
-        return sum(b.n_slots * b.length for b in self._buckets.values())
-
-    def _compile_bucket(self, bucket: _Bucket) -> None:
-        dm = self.decode_model
-        bucket.prefill_fn = jax.jit(
-            lambda p, tokens, length, cache, slot: dm.prefill(
-                self.plan.unpad_params(p), tokens, length, cache, slot),
-            donate_argnums=(3,))
-        bucket.decode_fn = jax.jit(
-            lambda p, tokens, positions, cache: dm.decode_step(
-                self.plan.unpad_params(p), tokens, positions, cache),
-            donate_argnums=(3,))
-
-    def admit(self, prompt: np.ndarray, max_new_tokens: int,
-              token_budget: Optional[int] = None,
-              ) -> Optional[Tuple[BucketSlot, int]]:
-        """Prefill ``prompt`` into a free slot of the smallest fitting
-        bucket (spilling to larger ones when full). Returns ``(slot,
-        first_token)`` or None when every fitting bucket is full; raises
-        ValueError past the largest bucket."""
-        prompt = np.asarray(prompt, np.int32).ravel()
-        total = len(prompt) + max_new_tokens
-        fit = self.bucket_for(total)
-        if fit is None:
-            raise ValueError(
-                f"request needs a {total}-token timeline; largest bucket is "
-                f"{self._bucket_lens[-1]} (prompt {len(prompt)} + "
-                f"max_new_tokens {max_new_tokens})")
-        for length in self._bucket_lens:
-            if length < fit:
-                continue
-            if token_budget is not None and length > token_budget:
-                break  # every later bucket is bigger still
-            bucket = self._buckets[length]
-            free = np.flatnonzero(~bucket.active)
-            if not len(free):
-                continue
-            idx = int(free[0])
-            if bucket.prefill_fn is None:
-                self._compile_bucket(bucket)
-            padded = np.zeros((1, length), np.int32)
-            padded[0, : len(prompt)] = prompt
-            first, bucket.cache = bucket.prefill_fn(
-                self.params, jnp.asarray(padded),
-                jnp.int32(len(prompt)), bucket.cache, jnp.int32(idx))
-            first = int(jax.device_get(first)[0])
-            bucket.active[idx] = True
-            bucket.lengths[idx] = len(prompt)
-            bucket.last_token[idx] = first
-            return BucketSlot(length, idx), first
-        return None
-
-    def step(self) -> Dict[BucketSlot, int]:
-        """One decode step over every bucket with active slots (one
-        compiled program per bucket — the per-length-mix compile cost the
-        paged engine exists to delete)."""
-        out: Dict[BucketSlot, int] = {}
-        for length, bucket in self._buckets.items():
-            if not bucket.active.any():
-                continue
-            if bucket.decode_fn is None:
-                self._compile_bucket(bucket)
-            tokens, bucket.cache = bucket.decode_fn(
-                self.params,
-                jnp.asarray(bucket.last_token),
-                jnp.asarray(bucket.lengths),
-                bucket.cache)
-            tokens = np.asarray(jax.device_get(tokens))
-            for idx in np.flatnonzero(bucket.active):
-                idx = int(idx)
-                bucket.lengths[idx] += 1
-                bucket.last_token[idx] = tokens[idx]
-                out[BucketSlot(length, idx)] = int(tokens[idx])
-        return out
-
-    def release(self, slot: BucketSlot) -> None:
-        bucket = self._buckets[slot.bucket]
-        bucket.active[slot.index] = False
-        bucket.lengths[slot.index] = 0
-        bucket.last_token[slot.index] = 0
-
-    def generate(self, prompt: np.ndarray, max_new_tokens: int) -> List[int]:
-        admitted = self.admit(prompt, max_new_tokens)
-        if admitted is None:
-            raise RuntimeError("no free slot for a single-request generate()")
-        slot, first = admitted
-        tokens = [first]
-        eos = self.decode_model.eos_id
-        try:
-            while len(tokens) < max_new_tokens and (
-                    eos is None or tokens[-1] != eos):
-                tokens.append(self.step()[slot])
         finally:
             self.release(slot)
         return tokens

@@ -363,9 +363,7 @@ def _serve_decode_bench(n_requests: int = 48, max_new: int = 10,
     At these widths the numbers are the serving SCHEDULER's and the
     paged-cache bookkeeping's (decode tokens/sec, p50/p99 request latency,
     peak page-pool utilization), not the chip's; the serving cell at real
-    widths is ROADMAP S1. Against it, a bucketed sequential baseline on the
-    SAME checkpoint gives the ``vs_bucketed_x`` throughput ratio. The line
-    names the device it ran on.
+    widths is ROADMAP S1. The line names the device it ran on.
     """
     import asyncio
 
@@ -378,8 +376,7 @@ def _serve_decode_bench(n_requests: int = 48, max_new: int = 10,
     from autodist_tpu.serve.batcher import ContinuousBatcher, RequestState
     from autodist_tpu.serve.sampling import SamplingParams
     from autodist_tpu.serve.server import (
-        _BASELINE_BUCKETS, _BASELINE_SLOTS, _tiny_engine, async_generate,
-        mock_load_prompt)
+        _tiny_engine, async_generate, mock_load_prompt)
 
     registry = M.MetricsRegistry()
     rng = np.random.default_rng(0)
@@ -389,22 +386,6 @@ def _serve_decode_bench(n_requests: int = 48, max_new: int = 10,
     paged_impl = resolve_paged_impl(
         getattr(_cfg, "paged_attention_impl", "auto"), engine.n_slots,
         engine.max_pages, engine.page_len, _cfg.num_heads)
-
-    # The bucketed sequential baseline on the SAME checkpoint + plan (the
-    # selftest's geometry): the >=2x decode-throughput bar is vs THIS.
-    from autodist_tpu.models.transformer import decode_model as _dm
-    from autodist_tpu.serve.engine import BucketedInferenceEngine
-
-    bucketed = BucketedInferenceEngine(
-        _params, engine.plan, decode_model=_dm(_cfg),
-        n_slots=_BASELINE_SLOTS, bucket_lens=_BASELINE_BUCKETS)
-    base_rng = np.random.default_rng(1)
-    baseline_prompts = [mock_load_prompt(base_rng, i) for i in range(6)]
-    bucketed.generate(baseline_prompts[0], max_new)        # warm compiles
-    b0 = time.perf_counter()
-    btok = sum(len(bucketed.generate(p, max_new)) for p in baseline_prompts)
-    bdt = time.perf_counter() - b0
-    bucketed_tps = btok / bdt if bdt > 0 else 0.0
 
     slo = SLOTracker()
     batcher = ContinuousBatcher(engine, max_queue=max(n_requests, 64),
@@ -499,9 +480,6 @@ def _serve_decode_bench(n_requests: int = 48, max_new: int = 10,
         "paged_attention_impl": paged_impl,
         "quant_capacity_x": round(
             float(getattr(engine, "quant_capacity_x", 1.0)), 2),
-        "bucketed_tokens_per_sec": round(bucketed_tps, 1),
-        "vs_bucketed_x": round((tokens / dt) / bucketed_tps, 2)
-        if dt > 0 and bucketed_tps > 0 else None,
         "device": jax.devices()[0].platform,
     }}
 

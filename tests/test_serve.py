@@ -7,8 +7,9 @@ The load-bearing test is the correctness anchor the acceptance bar names:
 cached greedy decode must match the uncached full-sequence forward
 token-for-token — including a request that JOINS MID-BATCH, which is the
 case continuous batching actually creates (per-slot positions diverge).
-Paged-vs-bucketed engine parity and the chunked-prefill interleaving pins
-live in tests/test_serve_paged.py.
+The paged engine's other oracle pins (page-crossing and multi-chunk
+prompts, both attention paths, chunked-prefill interleaving) live in
+tests/test_serve_paged.py.
 """
 import time
 
@@ -22,7 +23,6 @@ from autodist_tpu.api import AutoDist
 from autodist_tpu.models.transformer import (
     TransformerConfig,
     decode_model,
-    forward,
     init_params,
 )
 from autodist_tpu.serve import (
@@ -32,6 +32,7 @@ from autodist_tpu.serve import (
     InferenceEngine,
     RequestState,
 )
+from autodist_tpu.serve.server import uncached_greedy
 from autodist_tpu.strategy import AllReduce
 
 CFG = TransformerConfig(
@@ -55,23 +56,6 @@ def engine(params):
             n_slots=8, page_len=8, n_pages=33, prefill_chunk=8)
     finally:
         AutoDist.reset_default()
-
-
-def uncached_greedy(params, prompt, n_new, pad_to=CFG.max_seq_len):
-    """Oracle: full uncached forward each step, argmax at the frontier.
-
-    The sequence rides in a fixed [1, pad_to] buffer so the oracle compiles
-    ONCE (a fresh shape per step would dominate the test's runtime); under
-    the causal mask the zero-padding beyond the frontier cannot influence
-    the frontier's logits, so this is exactly the growing-sequence forward.
-    """
-    seq = [int(t) for t in prompt]
-    for _ in range(n_new):
-        padded = np.zeros((1, pad_to), np.int32)
-        padded[0, : len(seq)] = seq
-        logits = forward(params, jnp.asarray(padded), CFG)
-        seq.append(int(jnp.argmax(logits[0, len(seq) - 1])))
-    return seq[len(prompt):]
 
 
 def admit_and_prefill(engine, prompt, n_new):
@@ -108,8 +92,8 @@ def test_cached_greedy_decode_matches_uncached_forward(params, engine):
     engine.release(slot1)
     engine.release(slot2)
 
-    assert got1 == uncached_greedy(params, p1, n_new)
-    assert got2 == uncached_greedy(params, p2, n_new)
+    assert got1 == uncached_greedy(params, CFG, p1, n_new)
+    assert got2 == uncached_greedy(params, CFG, p2, n_new)
 
 
 def test_generate_matches_oracle_across_page_counts(params, engine):
@@ -117,7 +101,7 @@ def test_generate_matches_oracle_across_page_counts(params, engine):
     # same two compiled programs, same oracle stream.
     for prompt, n_new in (([7, 11, 13], 8), (list(range(1, 20)), 8)):
         got = engine.generate(np.asarray(prompt, np.int32), n_new)
-        assert got == uncached_greedy(params, np.asarray(prompt), n_new)
+        assert got == uncached_greedy(params, CFG, np.asarray(prompt), n_new)
     assert engine.compiled_programs == 2
 
 
@@ -199,7 +183,7 @@ def test_batcher_matches_oracle_under_concurrency(params, engine):
             r.wait(timeout=120)
     for p, r in zip(prompts, reqs):
         assert r.state is RequestState.DONE
-        assert r.tokens == uncached_greedy(params, p, 6)
+        assert r.tokens == uncached_greedy(params, CFG, p, 6)
 
 
 def test_backpressure_bounded_queue(engine):
@@ -319,7 +303,7 @@ def test_build_inference_checkpoint_roundtrip(tmp_path, params):
     finally:
         AutoDist.reset_default()
     prompt = np.array([8, 6, 4], np.int32)
-    assert engine.generate(prompt, 6) == uncached_greedy(params, prompt, 6)
+    assert engine.generate(prompt, 6) == uncached_greedy(params, CFG, prompt, 6)
 
 
 def test_stop_fails_leftover_requests_terminally(engine):

@@ -1,9 +1,10 @@
-"""Paged-vs-bucketed serving pins (ISSUE 12 acceptance bars).
+"""Paged serving pins (ISSUE 12 acceptance bars).
 
 - **token-stream bit-equality** on the same checkpoint between the paged
-  engine (page-table gather, chunked prefill) and the bucketed baseline
-  (stacked per-bucket pools) — including a request that joins mid-batch
-  and a chunked prefill interleaved with a live decode;
+  engine (page-table gather or kernel, chunked prefill) and the plain
+  oracle, greedy decode over the uncached ``forward``
+  (``serve.server.uncached_greedy``) — including a request that joins
+  mid-batch and a chunked prefill interleaved with a live decode;
 - **page recycling**: retirement returns pages to the pool and a recycled
   page serves a new request correctly (stale KV rows are dead weight);
 - **exactly two compiled serving programs** for any request-length mix;
@@ -23,8 +24,8 @@ from autodist_tpu.models.transformer import (
     decode_model,
     init_params,
 )
-from autodist_tpu.serve import BucketedInferenceEngine
 from autodist_tpu.serve import pages as serve_pages
+from autodist_tpu.serve.server import uncached_greedy
 from autodist_tpu.strategy import AllReduce
 
 CFG = TransformerConfig(
@@ -51,12 +52,10 @@ def paged(params):
 
 
 @pytest.fixture(scope="module")
-def bucketed(params, paged):
-    # Same checkpoint, same lowered plan: ONLY the KV-cache rendering
-    # differs — the strongest form of the parity claim.
-    return BucketedInferenceEngine(
-        params, paged.plan, decode_model=decode_model(CFG),
-        n_slots=4, bucket_lens=(16, 32))
+def oracle(params):
+    """``oracle(prompt, n_new)``: the uncached forward's greedy stream on
+    the same checkpoint."""
+    return lambda prompt, n_new: uncached_greedy(params, CFG, prompt, n_new)
 
 
 def prefill_all(engine, slot):
@@ -67,9 +66,9 @@ def prefill_all(engine, slot):
 
 
 # ----------------------------------------------------- stream bit-equality
-def test_paged_matches_bucketed_greedy_streams(paged, bucketed):
+def test_paged_matches_oracle_greedy_streams(paged, oracle):
     """Same checkpoint, same prompts: identical greedy token streams from
-    the paged gather path and the stacked bucketed path — short, page-
+    the paged gather path and the uncached forward — short, page-
     crossing, and multi-chunk prompts."""
     rng = np.random.default_rng(7)
     prompts = [
@@ -78,15 +77,15 @@ def test_paged_matches_bucketed_greedy_streams(paged, bucketed):
         rng.integers(1, 96, size=20).astype(np.int32),   # 3 prefill chunks
     ]
     for p in prompts:
-        assert paged.generate(p, 10) == bucketed.generate(p, 10), p
+        assert paged.generate(p, 10) == oracle(p, 10), p
 
 
 @pytest.mark.parametrize("impl", ["gather", "kernel"])
-def test_paged_stream_equals_the_unpaged_oracle(params, paged, bucketed, impl):
+def test_paged_stream_equals_the_uncached_oracle(params, paged, oracle, impl):
     """The pool as a leaf a layer, ``[n_pages, page_len, heads * head_dim]``,
     on both attention paths (the kernel in interpret mode here): the greedy
-    stream equals the unpaged oracle's (``forward_prefill`` /
-    ``forward_decode_step`` over one stacked timeline a slot)."""
+    stream equals the uncached oracle's (``forward`` over the whole
+    sequence for every token)."""
     import dataclasses
 
     from autodist_tpu.serve.engine import InferenceEngine
@@ -100,29 +99,16 @@ def test_paged_stream_equals_the_unpaged_oracle(params, paged, bucketed, impl):
     rng = np.random.default_rng(31)
     for n in (3, 8, 13, 21):         # inside a page, a whole page, across pages
         p = rng.integers(1, 96, size=n).astype(np.int32)
-        assert engine.generate(p, 9) == bucketed.generate(p, 9), (impl, n)
+        assert engine.generate(p, 9) == oracle(p, 9), (impl, n)
 
 
-def test_mid_batch_join_matches_bucketed(paged, bucketed):
-    """A request joining mid-decode sees the same stream on both engines —
-    batching (and paging) is scheduling, never semantics."""
+def test_mid_batch_join_matches_solo_oracle(paged, oracle):
+    """A request joining mid-decode, and the one it joins, each see the
+    stream the oracle gives that request alone — batching (and paging) is
+    scheduling, never semantics."""
     p1 = np.array([3, 9, 27], np.int32)
     p2 = np.array([44, 8, 15, 16, 23], np.int32)
     n = 8
-
-    # Bucketed reference: admit r1, 3 solo steps, r2 joins.
-    b1, bf1 = bucketed.admit(p1, n)
-    ref1 = [bf1] + [bucketed.step()[b1] for _ in range(3)]
-    b2, bf2 = bucketed.admit(p2, n)
-    ref2 = [bf2]
-    while len(ref1) < n or len(ref2) < n:
-        out = bucketed.step()
-        if len(ref1) < n:
-            ref1.append(out[b1])
-        if len(ref2) < n:
-            ref2.append(out[b2])
-    bucketed.release(b1)
-    bucketed.release(b2)
 
     s1 = paged.admit(p1, n)
     got1 = [prefill_all(paged, s1)] + [paged.step()[s1] for _ in range(3)]
@@ -137,11 +123,11 @@ def test_mid_batch_join_matches_bucketed(paged, bucketed):
     paged.release(s1)
     paged.release(s2)
 
-    assert got1 == ref1
-    assert got2 == ref2
+    assert got1 == oracle(p1, n)
+    assert got2 == oracle(p2, n)
 
 
-def test_chunked_prefill_interleaves_with_decode(paged, bucketed):
+def test_chunked_prefill_interleaves_with_decode_oracle(paged, oracle):
     """A long prompt prefills chunk-by-chunk BETWEEN decode steps of an
     already-active request; neither stream changes. This is the stall the
     paged engine deletes: the active decode advances one token per tick
@@ -150,8 +136,8 @@ def test_chunked_prefill_interleaves_with_decode(paged, bucketed):
     p_long = np.arange(1, 21, dtype=np.int32)           # 3 chunks of 8
     n = 8
 
-    ref_short = bucketed.generate(p_short, n)
-    ref_long = bucketed.generate(p_long, n)
+    ref_short = oracle(p_short, n)
+    ref_long = oracle(p_long, n)
 
     s1 = paged.admit(p_short, n)
     got1 = [prefill_all(paged, s1)]
@@ -245,7 +231,7 @@ def test_next_chunk_goes_out_behind_the_decode_step(paged):
 
 
 # ---------------------------------------------------------- page recycling
-def test_page_recycling_after_retirement(paged, bucketed):
+def test_page_recycling_after_retirement_oracle(paged, oracle):
     """Retired pages return to the pool and are REUSED (LIFO) by the next
     admission; a recycled page's stale KV rows never leak into the new
     request's stream."""
@@ -267,7 +253,7 @@ def test_page_recycling_after_retirement(paged, bucketed):
     while len(got) < 12:
         got.append(paged.step()[s2])
     paged.release(s2)
-    assert got == bucketed.generate(q, 12)    # stale rows never read
+    assert got == oracle(q, 12)               # stale rows never read
 
 
 def test_exactly_two_programs_for_any_length_mix(paged):
