@@ -3,10 +3,17 @@
 Every serving-path attention over the paged KV pool lives here (enforced by
 ``tools/check_patterns.py`` rule 12): the gather reference implementations the
 compiled programs shipped with since PR 12, and the pallas kernel that walks
-each row's page table block-by-block directly in HBM — online softmax per
-page (Dao et al., arXiv 2205.14135, rendered over pages instead of contiguous
-K blocks), the position mask folded into the block loop, no materialized
-``[B, P * page_len, H, D]`` timeline. Three entry points match the engine's
+each row's page table directly in HBM, a group of pages a grid step — online
+softmax per group (Dao et al., arXiv 2205.14135, rendered over pages instead
+of contiguous K blocks), the position mask folded into the block loop, no
+materialized ``[B, P * page_len, H, D]`` timeline. How many pages make a
+group is ``paged_blocking``'s, a pure function of the call's shapes; the
+row's ``reach`` (its last query position + 1, a scalar-prefetch operand
+beside the table) tells which groups to skip whole, with no copy issued;
+the first group is always live, because slot 0 is admitted by every query
+and seeds the running maximum with a finite logit. The heads are never
+sliced out of the lanes: a block-diagonal query meets the lane-dense pages
+in one MXU product (``_paged_kernel``). Three entry points match the engine's
 compiled programs: decode step (one query per row), spec verify (K+1 queries
 per row), and prefill-chunk (one row, C queries). A second kernel,
 ``eva_paged_attention``, attends over a window ring and chunk summaries (two
@@ -139,79 +146,171 @@ def _should_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _paged_kernel(tables_ref, qpos_ref, q_ref, *rest, page_len: int,
-                  n_tables: int, quantized: bool, scale: float):
-    """One (row, page) program: stream the row's pages, online softmax.
+#: What the page blocks of a grid step may take of VMEM (``paged_blocking``):
+#: both streams' groups in the pipeline's two buffers and once more as the
+#: operands the body makes of them. The block-diagonal query, the scores
+#: and the accumulator come on top (4 MB for a chunk of 16 at 25 heads of
+#: 64), all inside the 16 MiB a kernel may take unasked: a call that asks
+#: for more (``vmem_limit_bytes``) has XLA's prefetch slice whole pool
+#: leaves into fast memory ahead of it (PERF.md section 6, PR 35).
+_VMEM_BUDGET = 10 << 20
 
-    Grid is ``(B, P)`` with the page dimension minor — for a fixed row the
-    pages run sequentially, carrying fp32 (m, l, acc) stats in VMEM scratch
-    across iterations (init at p == 0, finalize at p == P - 1). The k/v
-    BlockSpec index maps read ``tables_ref`` (scalar-prefetch) so each step
-    DMAs exactly one page out of HBM: traffic scales with the live table,
-    never with a materialized ``[B, P * page_len, H, D]`` timeline.
 
-    A page block is ``[page_len, H * D]``, as the pool holds it (heads x
-    head_dim on the lanes); the heads are taken as static lane slices and
-    stacked heads-major, so the products below are per head as they were
-    over a ``[page_len, H, D]`` block.
+@functools.lru_cache(maxsize=None)
+def paged_blocking(n_q: int, n_tables: int, page_len: int, lanes: int,
+                   itemsize: int, vmem_budget: int = _VMEM_BUDGET):
+    """``(pages a grid step, queries a tile)`` of ``paged_attention``.
 
-    The position mask is folded into the block loop via the absolute slot
-    index ``t = p * page_len + offset``; fully-masked pages contribute
-    exp(NEG_INF - m) == 0 because slot 0 (always admitted: positions >= 0)
-    seeds ``m`` with a finite logit on the first page.
+    One query a row (a decode step) is its own tile; more go in tiles of up
+    to 16, whole sublane tiles of the operands' type (16 rows where the
+    pages are bfloat16, 8 otherwise). A step walks the largest divisor of
+    the table width that keeps it at or under 128 keys (one MXU tile of
+    keys) and whose page blocks fit ``vmem_budget``: the step's fixed cost,
+    the running maximum, the sum and the accumulator's rescale are paid
+    once for the group, and past 128 keys a step costs in proportion to
+    its keys, masked or not, so a larger group only computes more of what
+    no query sees (PERF.md section 6, PR 35). A table width that nothing
+    larger divides walks a page a step. Pure in what the call can see
+    (queries a row, table width, the pages' shape and type), so the host
+    counts the same groups: :func:`paged_group_counts`."""
+    tile = 16 if itemsize == 2 else 8
+    q_tile = 1 if n_q == 1 else min(-(-n_q // tile) * tile, 16)
+    # a stream's element: twice in the pipeline's buffers, once as the
+    # body's operand (int8: as float32, and its scale spread over the lanes)
+    operand = {1: 8, 2: 2}.get(itemsize, 4)
+    per_page = page_len * lanes * 2 * (2 * itemsize + operand)
+    most = max(1, min(128 // page_len, vmem_budget // per_page, n_tables))
+    group = max(g for g in range(1, most + 1) if n_tables % g == 0)
+    return group, q_tile
+
+
+def paged_group_counts(reach, n_q: int, n_tables: int, page_len: int,
+                       lanes: int, itemsize: int):
+    """``(groups, live groups)`` of one ``paged_attention`` call over rows
+    whose queries are ``n_q`` consecutive positions ending one short of
+    ``reach[b]`` (host integers): the page groups of the rows' tables, a
+    query tile each, and those of them at or under a query's position,
+    which are the ones the kernel computes. Every tile's first is live."""
+    group, q_tile = paged_blocking(n_q, n_tables, page_len, lanes, itemsize)
+    n_groups = n_tables // group
+    span = group * page_len
+    n_tiles = -(-n_q // q_tile)
+    live = 0
+    for r in reach:
+        for i in range(n_tiles):
+            # the tile's last query; the pad repeats the row's last
+            end = min(int(r), int(r) - n_q + (i + 1) * q_tile)
+            live += min(n_groups, max(1, -(-end // span)))
+    return n_groups * n_tiles * len(reach), live
+
+
+def _paged_kernel(tables_ref, reach_ref, qpos_ref, q_ref, *rest,
+                  page_len: int, group: int, heads: int, quantized: bool,
+                  scale: float):
+    """One (row, query tile, page group) program: stream the row's pages
+    ``group`` at a time, online softmax a group.
+
+    Grid is ``(B, Q / tile, groups)`` with the group dimension minor: for
+    a fixed row and tile the groups run in order and carry float32 (m, l,
+    acc) in VMEM (init at g == 0, finalize at the last g). The group
+    dimension ends at the last group any query of the call sees (a bound
+    read on the device, not a shape), at most ``P / group``. The k/v
+    BlockSpec index maps read ``tables_ref`` (scalar prefetch), one
+    ``[page_len, H * D]`` block a page as the pool holds it, so traffic
+    scales with the live table and no ``[B, P * page_len, H, D]`` timeline
+    is made.
+
+    **Groups past the tile's last position cost nothing.** ``reach_ref[b,
+    i]`` is the largest query position of row ``b``'s tile ``i`` + 1 (the
+    second scalar-prefetch operand). Under a longer row of the same call,
+    a group that starts at or past it is skipped whole, and its index maps
+    return the pages of the tile's last live group, which the pipeline
+    holds already, so no copy is issued.
+    Group 0 is always live: positions are >= 0, so slot 0 is admitted by
+    every query and seeds ``m`` with a finite logit; inside a live group a
+    masked entry then contributes exp(NEG_INF - m) == 0 (its page, scratch
+    or not, must hold finite values, as serve/pages.py SCRATCH_PAGE
+    promises). An idle row (all-scratch table, position 0) walks one group.
+
+    **No head is sliced out of the lanes.** The group's keys ``[T, H * D]``
+    meet a block-diagonal query ``[H * Q, H * D]`` (row ``h * Q + q`` holds
+    query ``q``'s head ``h`` in lanes ``[h * D, (h + 1) * D)`` and zeros
+    elsewhere; built once a tile): ``s[H * Q, T]`` is one MXU product over
+    all lanes, ``p @ V`` gives ``[H * Q, H * D]``, and the output is its
+    block diagonal, read once a tile by a mask and a sum over the heads'
+    row blocks. That spends ``H`` times the products the math needs on an
+    MXU that is otherwise idle and moves no lane. Operands are bfloat16
+    where pages and query both are (products exact in float32, the
+    probabilities cast as the gather rendering casts them), float32
+    otherwise; int8 pages are scaled to float32 before either product.
     """
+    n_kv = 2 * group
+    k_refs, v_refs = rest[:group], rest[group:n_kv]
     if quantized:
-        k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        ks_refs, vs_refs = rest[n_kv:n_kv + group], rest[n_kv + group:2 * n_kv]
+        rest = rest[2 * n_kv:]
     else:
-        k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    p = pl.program_id(1)
+        rest = rest[n_kv:]
+    o_ref, qbd_ref, m_ref, l_ref, acc_ref = rest
+    bi, qi, gi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    rows, lanes = qbd_ref.shape
+    n_q, d = rows // heads, lanes // heads
+    span = group * page_len
+    mm = qbd_ref.dtype
 
-    @pl.when(p == 0)
+    def diagonal(n):
+        # [heads * n, lanes]: True where a row's head is the lane's head
+        head = jax.lax.broadcasted_iota(jnp.int32, (heads * n, lanes), 0) // n
+        lane = jax.lax.broadcasted_iota(jnp.int32, (heads * n, lanes), 1)
+        return (lane >= head * d) & (lane < (head + 1) * d)
+
+    @pl.when(gi == 0)
     def _init():
+        q = q_ref[0].astype(jnp.float32)                       # [Q, H * D]
+        tiled = (jnp.broadcast_to(q, (rows, lanes)) if n_q == 1
+                 else jnp.concatenate([q] * heads, axis=0))
+        qbd_ref[...] = jnp.where(diagonal(n_q), tiled, 0.0).astype(mm)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]                                   # [Q, H, D]
-    n_q, n_h, d = q.shape
-    qh = jnp.transpose(q, (1, 0, 2)).astype(jnp.float32)   # [H, Q, D]
+    def timeline(page_refs, scale_refs, spread):
+        # [T, H * D]: the group's pages end to end, int8 ones scaled
+        blk = jnp.concatenate([r[0].astype(mm) for r in page_refs], axis=0)
+        if not quantized:
+            return blk
+        sc = jnp.concatenate([r[0] for r in scale_refs], axis=0)   # [T, H]
+        return blk * jnp.dot(sc, spread, precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
 
-    def heads_major(page_ref, scale_ref):
-        blk = page_ref[0].astype(jnp.float32)      # [page_len, H * D]
-        per_head = [blk[:, h * d:(h + 1) * d] for h in range(n_h)]
-        if quantized:
-            sc = scale_ref[0]                      # [page_len, H]
-            per_head = [x * sc[:, h:h + 1] for h, x in enumerate(per_head)]
-        return jnp.stack(per_head, axis=0)         # [H, T, D]
+    @pl.when(gi * span < reach_ref[bi, qi])
+    def _attend():
+        # [H, H * D]: a head's scale over the head's lanes
+        spread = diagonal(1).astype(jnp.float32) if quantized else None
+        k = timeline(k_refs, ks_refs if quantized else None, spread)
+        v = timeline(v_refs, vs_refs if quantized else None, spread)
+        s = jax.lax.dot_general(
+            qbd_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale        # [H * Q, T]
+        t_abs = gi * span + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, span), 1)
+        s = jnp.where(t_abs <= qpos_ref[0, 0], s, NEG_INF)     # [H * Q, 1]
+        m = m_ref[...]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        pexp = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_ref[...] = alpha * l_ref[...] + pexp.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            pexp.astype(mm), v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
-    kh = heads_major(k_ref, ks_ref if quantized else None)
-    vh = heads_major(v_ref, vs_ref if quantized else None)
-    s = jax.lax.dot_general(
-        qh, kh, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * scale                                      # [H, Q, T] fp32
-    t_abs = p * page_len + jax.lax.broadcasted_iota(
-        jnp.int32, (n_q, page_len), 1)
-    admit = t_abs <= qpos_ref[0]                   # [Q, 1] -> [Q, T]
-    s = jnp.where(admit[None, :, :], s, NEG_INF)
-
-    m = m_ref[...]                                 # [H, Q, 1]
-    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-    pexp = jnp.exp(s - m_new)
-    alpha = jnp.exp(m - m_new)
-    l_ref[...] = alpha * l_ref[...] + pexp.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pexp, vh, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                              # [H, Q, D]
-    m_ref[...] = m_new
-
-    @pl.when(p == n_tables - 1)
+    @pl.when(gi == pl.num_programs(2) - 1)
     def _finalize():
         l = l_ref[...]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = acc_ref[...] / l_safe                # [H, Q, D]
-        o_ref[0] = jnp.transpose(out, (1, 0, 2)).astype(o_ref.dtype)
+        out = jnp.where(diagonal(n_q),
+                        acc_ref[...] / jnp.where(l == 0.0, 1.0, l), 0.0)
+        o_ref[0] = out.reshape(heads, n_q, lanes).sum(axis=0).astype(
+            o_ref.dtype)
 
 
 def _kernel_attention(q4, k_pages, v_pages, page_tables, q_positions,
@@ -222,54 +321,88 @@ def _kernel_attention(q4, k_pages, v_pages, page_tables, q_positions,
     dtype."""
     if interpret is None:
         interpret = _should_interpret()
+    return _kernel_call(q4, k_pages, v_pages, page_tables, q_positions,
+                        k_scale, v_scale, interpret=interpret)
+
+
+# Under a jit of its own, so that a program's layers share one trace and
+# one lowering of the call: inline, 48 layers' calls cost a serving process
+# 16 s of set-up on the chip's host (PERF.md section 6, PR 35). XLA inlines
+# the call; the instruction keeps its name.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kernel_call(q4, k_pages, v_pages, page_tables, q_positions, k_scale,
+                 v_scale, *, interpret: bool):
     b, n_q, h, d = q4.shape
     page_len = k_pages.shape[1]
     n_tables = page_tables.shape[1]
     quantized = k_scale is not None
-    scale = 1.0 / (d ** 0.5)
+    mm = (jnp.bfloat16 if q4.dtype == k_pages.dtype == jnp.bfloat16
+          else jnp.float32)
+    group, q_tile = paged_blocking(n_q, n_tables, page_len, h * d,
+                                   k_pages.dtype.itemsize)
+    n_groups = n_tables // group
+    span = group * page_len
+    # Whole tiles of queries: the pad repeats the row's last query (no
+    # tile's ``reach`` grows by it) and is cut off the output.
+    pad = -n_q % q_tile
+    n_tiles = (n_q + pad) // q_tile
+    rows = h * q_tile
+    qpos = jnp.pad(q_positions.astype(jnp.int32), ((0, 0), (0, pad)),
+                   mode="edge").reshape(b, n_tiles, q_tile)
+    q3 = jnp.pad(q4.reshape(b, n_q, h * d), ((0, 0), (0, pad), (0, 0)),
+                 mode="edge")
     tables = page_tables.astype(jnp.int32)
-    # [B, Q, 1]: a (1, Q, 1) block equals the array's last two dims, which
-    # Mosaic requires of a block that is not (8, 128)-aligned — a (1, Q)
-    # block of [B, Q] is refused when Q is 1 (decode).
-    qpos = q_positions.astype(jnp.int32)[..., None]
+    reach = qpos.max(axis=2) + 1                               # [B, tiles]
+    # a position a row of the block-diagonal query: row h * Q + q is query q
+    qpos_rows = jnp.tile(qpos, (1, 1, h))[..., None]    # [B, tiles, H * Q, 1]
 
-    page_spec = pl.BlockSpec(
-        (1, page_len, h * d), lambda bi, pi, t: (t[bi, pi], 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, n_q, 1), lambda bi, pi, t: (bi, 0, 0)),    # qpos
-        pl.BlockSpec((1, n_q, h, d), lambda bi, pi, t: (bi, 0, 0, 0)),
-        page_spec,                                                  # k page
-        page_spec,                                                  # v page
-    ]
-    operands = [qpos, q4, k_pages, v_pages]
+    def page_index(j):
+        def index(bi, qi, gi, t, reach):
+            # a skipped group keeps the tile's last live group's pages
+            last = jax.lax.div(reach[bi, qi] - 1, span)
+            return (t[bi, jnp.minimum(gi, last) * group + j], 0, 0)
+        return index
+
+    def page_specs(width):
+        return [pl.BlockSpec((1, page_len, width), page_index(j))
+                for j in range(group)]
+
+    q_spec = pl.BlockSpec((1, q_tile, h * d), lambda bi, qi, gi, *_: (bi, qi, 0))
+    in_specs = [pl.BlockSpec((1, 1, rows, 1),
+                             lambda bi, qi, gi, *_: (bi, qi, 0, 0)),
+                q_spec] + page_specs(h * d) * 2
+    operands = [qpos_rows, q3] + [k_pages] * group + [v_pages] * group
     if quantized:
-        scale_spec = pl.BlockSpec(
-            (1, page_len, h), lambda bi, pi, t: (t[bi, pi], 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
+        in_specs += page_specs(h) * 2
+        operands += [k_scale] * group + [v_scale] * group
 
+    # the grid ends at the call's last live group: no row walks further
+    n_live = jnp.minimum(n_groups, jax.lax.div(reach.max() + span - 1, span))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, n_tables),
+        num_scalar_prefetch=2,
+        grid=(b, n_tiles, n_live),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n_q, h, d),
-                               lambda bi, pi, t: (bi, 0, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((h, n_q, 1), jnp.float32),   # m
-            pltpu.VMEM((h, n_q, 1), jnp.float32),   # l
-            pltpu.VMEM((h, n_q, d), jnp.float32),   # acc
+            pltpu.VMEM((rows, h * d), mm),                # block-diagonal q
+            pltpu.VMEM((rows, 1), jnp.float32),           # m
+            pltpu.VMEM((rows, 1), jnp.float32),           # l
+            pltpu.VMEM((rows, h * d), jnp.float32),       # acc
         ],
     )
     kernel = functools.partial(
-        _paged_kernel, page_len=page_len, n_tables=n_tables,
-        quantized=quantized, scale=scale)
-    return pl.pallas_call(
+        _paged_kernel, page_len=page_len, group=group, heads=h,
+        quantized=quantized, scale=1.0 / (d ** 0.5))
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_q, h, d), q4.dtype),
+        out_shape=jax.ShapeDtypeStruct(q3.shape, q4.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="paged_attention",
-    )(tables, *operands)
+    )(tables, reach, *operands)
+    return out[:, :n_q].reshape(b, n_q, h, d)
 
 
 def _check_impl(impl: str) -> None:

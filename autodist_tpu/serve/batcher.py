@@ -311,6 +311,10 @@ class ContinuousBatcher:
         self._m_summaries = None
         self._m_ring_pages = None
         self._m_summary_pages = None
+        # The paged kernel's walk over the plain timeline (engine
+        # ``kv_groups`` / ``kv_groups_live``), lazily registered likewise.
+        self._m_kv_groups = None
+        self._m_kv_groups_live = None
 
         reg = registry or M.registry
         self._registry = reg
@@ -765,6 +769,7 @@ class ContinuousBatcher:
             self._update_prefix_metrics()
             self._update_quant_metrics()
             self._update_ring_metrics(sp)
+            self._update_kv_group_metrics()
             with self._lock:
                 self._m_active.set(len(self._active))
             self._m_pool_util.set(self.engine.page_utilization)
@@ -1028,6 +1033,29 @@ class ContinuousBatcher:
         sp["summary_chunks"] = eng.summary_chunks
         sp["ring_pages"] = ring
         sp["summary_pages"] = summary
+
+    def _update_kv_group_metrics(self) -> None:
+        """Publish how often the paged kernel's skip engages, from the
+        engine's cumulative counts: ``serve_kv_groups_total`` (page groups
+        of the rows' tables in a layer's call of every decode step and
+        prefill chunk dispatched) and ``serve_kv_groups_live_total``
+        (those of them at or under a query's position: the rest are
+        skipped whole). The per-call readings ride the
+        ``serve.decode_dispatch`` and ``serve.prefill_chunk`` spans as
+        ``kv_groups`` / ``kv_groups_live``. No-op over a window ring, whose
+        kernel counts its own segments."""
+        eng = self.engine
+        layout = getattr(eng, "layout", None)
+        if layout is None or layout.window:
+            return
+        if self._m_kv_groups is None:
+            self._m_kv_groups = self._registry.counter(
+                "serve_kv_groups_total")
+            self._m_kv_groups_live = self._registry.counter(
+                "serve_kv_groups_live_total")
+        for counter, now in ((self._m_kv_groups, eng.kv_groups),
+                             (self._m_kv_groups_live, eng.kv_groups_live)):
+            counter.inc(now - counter.value)
 
     def _update_quant_metrics(self) -> None:
         """Publish the physical-vs-quantized pool byte split. No-op on fp

@@ -45,6 +45,7 @@ from autodist_tpu.kernel import GraphTransformer, ShardingPlan, build_mesh, data
 from autodist_tpu.model_item import ModelItem
 from autodist_tpu.obs import recorder as obs_recorder
 from autodist_tpu.obs import spans as obs_spans
+from autodist_tpu.ops.paged_attention import paged_group_counts
 from autodist_tpu.serve import pages as serve_pages
 from autodist_tpu.serve import prefix as serve_prefix
 from autodist_tpu.serve import sampling as serve_sampling
@@ -255,6 +256,13 @@ class InferenceEngine:
             int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
             for leaf in jax.tree_util.tree_leaves(page_shaped))
         self.page_bytes = page_bytes
+        # Over the plain timeline: the lanes and item size of a page of
+        # keys (the cache's widest leaf), what the paged kernel's blocking
+        # reads beside the table width (ops/paged_attention.py).
+        wide = max(jax.tree_util.tree_leaves(page_shaped),
+                   key=lambda leaf: leaf.shape[-1])
+        self._kv_page = None if layout.window else (
+            int(wide.shape[-1]), np.dtype(wide.dtype).itemsize)
         # Quantized pool mode (int8 pages + f32 scale planes, PR 20):
         # detected from the model's own cache pytree, so the engine needs
         # no config plumbing — the scale leaves share the page dim and ride
@@ -380,6 +388,12 @@ class InferenceEngine:
         self.window_rolls = 0
         self.window_rolls_decode = 0
         self.summary_chunks = 0
+        # Over the plain timeline: the page groups of the rows' tables in
+        # a layer's call of the programs dispatched, and those of them the
+        # paged kernel does not skip (``_count_kv_groups``). Cumulative; the
+        # batcher publishes them (serve_kv_groups_total, ..._live_total).
+        self.kv_groups = 0
+        self.kv_groups_live = 0
         # Replica identity carried into the chaos seams so a schedule can
         # target ONE replica of a fleet (replica_death injects
         # EngineDeadError only where host matches — docs/chaos.md).
@@ -873,6 +887,20 @@ class InferenceEngine:
         one chunk per tick (chunked prefill interleaves with decode)."""
         return [Slot(int(i)) for i in np.flatnonzero(self._phase == _PREFILL)]
 
+    def _count_kv_groups(self, sp, reach, n_q: int) -> None:
+        """Stamp on the open span ``sp`` how many page groups the rows'
+        tables hold for one layer's call of the paged kernel over rows
+        whose last query sits one short of ``reach[b]``, and how many of
+        them are live (the rest it skips): the kernel's own blocking
+        (``paged_group_counts``) on the host's integers, no device work.
+        Over a window ring: nothing."""
+        if self._kv_page is None:
+            return
+        sp["kv_groups"], sp["kv_groups_live"] = paged_group_counts(
+            reach, n_q, self.max_pages, self.page_len, *self._kv_page)
+        self.kv_groups += sp["kv_groups"]
+        self.kv_groups_live += sp["kv_groups_live"]
+
     def _dispatch_chunk(self, idx: int):
         """Dispatch the next chunk of row ``idx``'s prompt and advance its
         position; returns ``(first token, still on the device; final)``.
@@ -888,7 +916,8 @@ class InferenceEngine:
         final = start + c >= len(prompt)
         with obs_spans.span("serve.prefill_chunk", start=start,
                             prompt_len=len(prompt), final=final,
-                            request_id=self._request_ids[idx]):
+                            request_id=self._request_ids[idx]) as sp:
+            self._count_kv_groups(sp, [start + c], c)
             chunk = np.zeros((1, c), np.int32)
             valid = prompt[start:start + c]
             chunk[0, : len(valid)] = valid
@@ -990,7 +1019,8 @@ class InferenceEngine:
             # The host's part (the per-tick puts and the call) apart from
             # the wait for the device: a device left idle under the first
             # is the host's to cure, under the second it is not idle.
-            with obs_spans.span("serve.decode_dispatch"):
+            with obs_spans.span("serve.decode_dispatch") as sp:
+                self._count_kv_groups(sp, self._lengths + 1, 1)
                 tokens, self._cache = self._decode_fn(
                     self.params,
                     jnp.asarray(self._last_token),

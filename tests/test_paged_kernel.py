@@ -175,6 +175,163 @@ class TestLaneDenseWidths:
                 out[0, :, 0], np.arange(1, h + 1, dtype=np.float32))
 
 
+# ------------------------------------------------ the grouped walk (PR 35)
+# Tables of 32 pages of 8 walk 16 pages (128 keys) a grid step, two groups a
+# row; a table of 7 pages (a prime: nothing larger divides it) walks a page
+# a step, as the kernel did before it walked groups.
+GROUPED = {"grouped": 32, "prime": 7}
+ENTRIES = ("decode", "chunk", "verify")
+
+
+def _walk_case(rng, entry, quantized, n_tables, poison=False):
+    """One call of ``entry`` over rows that end at 0 (an idle row on an
+    all-scratch table), one short of a group's edge, on it, one past it and
+    at the table's last slot. Returns ``run(impl) -> output``. With
+    ``poison`` every page past each row's last live group holds NaN (the
+    scale planes of an int8 pool)."""
+    group, _ = pa.paged_blocking(
+        {"decode": 1, "chunk": PAGE_LEN, "verify": 5}[entry], n_tables,
+        PAGE_LEN, H * D, 1 if quantized else 4)
+    span, timeline = group * PAGE_LEN, n_tables * PAGE_LEN
+    ends = [0, span - 1, span, span + 1, timeline - 1]
+    n_pages = 1 + len(ends) * n_tables
+    k = rng.standard_normal((n_pages, PAGE_LEN, H, D)).astype(np.float32)
+    v = rng.standard_normal((n_pages, PAGE_LEN, H, D)).astype(np.float32)
+    tables = 1 + rng.permutation(n_pages - 1).reshape(len(ends), n_tables)
+    tables[0] = 0                                   # the idle row: scratch
+    if quantized:
+        kp, ks = pa.quantize_kv(jnp.asarray(k))
+        vp, vs = pa.quantize_kv(jnp.asarray(v))
+        kp, vp, ks, vs = (np.array(x) for x in (_lanes(kp), _lanes(vp), ks, vs))
+    else:
+        kp, vp, ks, vs = np.array(_lanes(k)), np.array(_lanes(v)), None, None
+    if poison:
+        for row, end in zip(tables[1:], ends[1:]):
+            dead = row[(end // span + 1) * group:]
+            for plane in ((ks, vs) if quantized else (kp, vp)):
+                plane[dead] = np.nan
+    kp, vp = jnp.asarray(kp), jnp.asarray(vp)
+    scales = dict(k_scale=None if ks is None else jnp.asarray(ks),
+                  v_scale=None if vs is None else jnp.asarray(vs))
+    tables = jnp.asarray(tables, jnp.int32)
+    ends_a = jnp.asarray(ends, jnp.int32)
+    if entry == "decode":
+        q = jnp.asarray(rng.standard_normal((len(ends), H, D)), jnp.float32)
+        return lambda impl: pa.paged_decode_attention(
+            q, kp, vp, tables, ends_a, impl=impl, **scales)
+    if entry == "verify":
+        k1 = 5
+        q = jnp.asarray(rng.standard_normal((len(ends), k1, H, D)), jnp.float32)
+        rows_pos = jnp.minimum(
+            jnp.maximum(ends_a - k1 + 1, 0)[:, None] + jnp.arange(k1)[None],
+            ends_a[:, None])
+        return lambda impl: pa.paged_verify_attention(
+            q, kp, vp, tables, rows_pos, impl=impl, **scales)
+    q = jnp.asarray(rng.standard_normal((PAGE_LEN, H, D)), jnp.float32)
+
+    def chunks(impl):
+        # a chunk a row, its last query at the row's end
+        return jnp.stack([pa.paged_prefill_attention(
+            q, kp, vp, tables[i],
+            jnp.maximum(end - PAGE_LEN + 1 + jnp.arange(PAGE_LEN), 0),
+            impl=impl, **scales) for i, end in enumerate(ends)])
+    return chunks
+
+
+class TestGroupedWalk:
+    """The kernel walks a group of pages a grid step and skips the groups
+    past a row's last position; against the gather reference at every
+    entry point, fp and int8, at a table width the group divides and at
+    one only a single page divides."""
+
+    @pytest.mark.parametrize("width", GROUPED.values(), ids=GROUPED.keys())
+    @pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+    @pytest.mark.parametrize("entry", ENTRIES)
+    def test_rows_around_a_group_edge(self, entry, quantized, width):
+        run = _walk_case(np.random.default_rng(35), entry, quantized, width)
+        np.testing.assert_allclose(run("gather"), run("kernel"),
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_a_chunk_of_several_query_tiles(self):
+        # 40 queries go in three tiles of 16 (the pad repeats the last);
+        # a tile walks the groups up to its own last query
+        rng = np.random.default_rng(37)
+        n_tables, c = 32, 40
+        n_pages = 1 + n_tables
+        kp = _lanes(jnp.asarray(rng.standard_normal(
+            (n_pages, PAGE_LEN, H, D)), jnp.float32))
+        vp = _lanes(jnp.asarray(rng.standard_normal(
+            (n_pages, PAGE_LEN, H, D)), jnp.float32))
+        table = jnp.asarray(1 + rng.permutation(n_tables), jnp.int32)
+        q = jnp.asarray(rng.standard_normal((c, H, D)), jnp.float32)
+        positions = jnp.arange(100, 100 + c, dtype=jnp.int32)
+        outs = [pa.paged_prefill_attention(q, kp, vp, table, positions,
+                                           impl=impl)
+                for impl in ("gather", "kernel")]
+        np.testing.assert_allclose(outs[0], outs[1], atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+    @pytest.mark.parametrize("entry", ENTRIES)
+    def test_skipped_groups_are_never_read(self, entry, quantized):
+        clean = _walk_case(np.random.default_rng(36), entry, quantized,
+                           GROUPED["grouped"])("kernel")
+        dirty = _walk_case(np.random.default_rng(36), entry, quantized,
+                           GROUPED["grouped"], poison=True)("kernel")
+        assert np.isfinite(np.asarray(dirty)).all()
+        np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
+
+
+# (queries a row, table width, page_len, lanes, item size) -> pages a step
+SERVED = {
+    "xl-decode": ((1, 64, 16, 1600, 2), 8),
+    "xl-chunk": ((16, 64, 16, 1600, 2), 8),
+    "xl-verify": ((5, 64, 16, 1600, 2), 8),
+    "xl-decode-int8": ((1, 64, 16, 1600, 1), 8),
+    "xl-chunk-f32": ((16, 64, 16, 1600, 4), 8),
+    "xl-chunk-int8": ((16, 64, 16, 1600, 1), 8),
+    "medium-decode": ((1, 64, 16, 1024, 2), 8),
+    "medium-chunk": ((16, 64, 16, 1024, 2), 8),
+    "4x128-decode": ((1, 64, 16, 512, 2), 8),
+    "prime-width": ((1, 61, 16, 1600, 2), 1),
+    "narrow-table": ((16, 6, 16, 1600, 2), 6),
+    "short-pages": ((1, 64, 4, 1600, 2), 32),
+    "wide-lanes": ((1, 64, 16, 8192, 4), 2),
+}
+
+
+class TestBlocking:
+    @pytest.mark.parametrize("shape, want", SERVED.values(), ids=SERVED.keys())
+    def test_group_divides_the_table_and_fits(self, shape, want):
+        n_q, n_tables, page_len, lanes, itemsize = shape
+        group, q_tile = pa.paged_blocking(*shape)
+        assert group == want and n_tables % group == 0
+        assert group * page_len <= 128
+        operand = {1: 8, 2: 2, 4: 4}[itemsize]
+        blocks = group * page_len * lanes * 2 * (2 * itemsize + operand)
+        assert group == 1 or blocks <= pa._VMEM_BUDGET
+        assert q_tile == (1 if n_q == 1 else 16 if itemsize == 2 else
+                          min(16, -(-n_q // 8) * 8))
+
+    def test_a_smaller_budget_walks_fewer_pages(self):
+        shape = (16, 64, 16, 1600, 2)
+        assert pa.paged_blocking(*shape, vmem_budget=1 << 20)[0] == 2
+        assert pa.paged_blocking(*shape, vmem_budget=1)[0] == 1
+
+    def test_group_counts_follow_the_rows_reach(self):
+        # XL decode: 8 groups of 128 keys a row. Reach 1 (an idle row), 128
+        # and 129 are 1, 1 and 2 live groups; the table's end is all 8.
+        shape = (1, 64, 16, 1600, 2)
+        assert pa.paged_group_counts([1, 128, 129, 1024], *shape) == (32, 12)
+        # XL chunk: the same groups; a chunk ending at 128 sees one.
+        shape = (16, 64, 16, 1600, 2)
+        assert pa.paged_group_counts([16], *shape) == (8, 1)
+        assert pa.paged_group_counts([128], *shape) == (8, 1)
+        assert pa.paged_group_counts([144], *shape) == (8, 2)
+        # 40 queries (480..519) go in three tiles of 16, the pad repeating
+        # the last: the tiles end at 495, 511 and 519.
+        assert pa.paged_group_counts([520], 40, *shape[1:]) == (24, 4 + 4 + 5)
+
+
 class TestQuantization:
     def test_round_trip_error_bounded(self):
         rng = np.random.default_rng(4)

@@ -267,6 +267,67 @@ def test_exactly_two_programs_for_any_length_mix(paged):
 
 
 # ------------------------------------------------------------- pages.py unit
+def test_dispatch_spans_count_the_paged_kernels_groups():
+    """The decode step's and the prefill chunk's dispatch spans carry the
+    page groups the paged kernel walks in a layer's call and those it does
+    not skip (``kv_groups``, ``kv_groups_live``): the kernel's own blocking
+    on the host's lengths. A table of 32 pages of 8 is two groups of 128
+    keys a row, so a prompt of 140 tokens has its first 16 chunks skip the
+    second group and the last two see it, and its decode steps walk two
+    live groups beside the idle rows' one each. The registry's two
+    counters add up to the spans' sums."""
+    from autodist_tpu import metrics as M
+    from autodist_tpu.obs import spans as obs_spans
+    from autodist_tpu.ops.paged_attention import paged_group_counts
+    from autodist_tpu.serve.batcher import ContinuousBatcher, RequestState
+
+    cfg = TransformerConfig(
+        vocab_size=97, num_layers=2, d_model=32, num_heads=2, d_ff=64,
+        max_seq_len=256, causal=True, dtype=jnp.float32)
+    AutoDist.reset_default()
+    try:
+        engine = AutoDist(strategy_builder=AllReduce()).build_inference(
+            init_params(jax.random.PRNGKey(0), cfg),
+            decode_model=decode_model(cfg),
+            n_slots=4, page_len=8, n_pages=65, prefill_chunk=8)
+    finally:
+        AutoDist.reset_default()
+    assert engine.max_pages == 32
+    shape = (engine.max_pages, 8, 32, 4)    # table, page_len, lanes, float32
+
+    tracer = obs_spans.get_tracer()
+    tracer.clear()
+    registry = M.MetricsRegistry()
+    batcher = ContinuousBatcher(engine, registry=registry)
+    batcher.start()
+    try:
+        req = batcher.submit(np.arange(1, 141, dtype=np.int32) % 96 + 1, 4)
+        assert req.wait(300.0).state is RequestState.DONE
+    finally:
+        batcher.stop()
+    spans = tracer.spans()
+    chunks = [s for s in spans if s.name == "serve.prefill_chunk"]
+    steps = [s for s in spans if s.name == "serve.decode_dispatch"]
+    assert len(chunks) == 18 and len(steps) == 3
+    for sp in chunks:
+        want = paged_group_counts([sp.attrs["start"] + 8], 8, *shape)
+        assert (sp.attrs["kv_groups"], sp.attrs["kv_groups_live"]) == want
+    assert [s.attrs["kv_groups_live"] for s in chunks] == [1] * 16 + [2] * 2
+    idle = engine.n_slots - 1                   # rows at position 0
+    for i, sp in enumerate(steps):
+        want = paged_group_counts([140 + i + 1] + [1] * idle, 1, *shape)
+        assert (sp.attrs["kv_groups"], sp.attrs["kv_groups_live"]) == want
+        assert want == (2 * engine.n_slots, 2 + idle)
+    for sp in chunks + steps:
+        assert 1 <= sp.attrs["kv_groups_live"] <= sp.attrs["kv_groups"]
+    assert registry.counter("serve_kv_groups_total").value == sum(
+        s.attrs["kv_groups"] for s in chunks + steps) == (
+            18 * 2 + 3 * 2 * engine.n_slots)
+    assert registry.counter("serve_kv_groups_live_total").value == sum(
+        s.attrs["kv_groups_live"] for s in chunks + steps) == (
+            16 + 4 + 3 * (2 + idle))
+
+
 class TestPagePool:
     def test_alloc_is_all_or_nothing_and_scratch_reserved(self):
         pool = serve_pages.build_pool(5, page_len=8)     # 4 usable

@@ -479,6 +479,54 @@ def test_eva_kernel_compiles_for_the_chip_at_published_widths(one_chip, rows, qu
     assert not re.search(r"= bf16\[1025,32,16,128\]\S* copy\(", text)
 
 
+# (rows, queries a row, heads, head_dim, table width, pages' type)
+PAGED_SHAPES = {
+    "bfloat16": {
+        "xl-decode": (4, 1, 25, 64, 64, jnp.bfloat16),
+        "xl-chunk": (1, 16, 25, 64, 64, jnp.bfloat16),
+        "xl-verify": (4, 5, 25, 64, 64, jnp.bfloat16),
+        "xl-chunk-of-64": (1, 64, 25, 64, 64, jnp.bfloat16),
+        "medium-decode": (8, 1, 16, 64, 64, jnp.bfloat16),
+        "prime-width": (4, 1, 25, 64, 61, jnp.bfloat16),
+    },
+    "int8-and-float32": {
+        "xl-decode-int8": (4, 1, 25, 64, 64, jnp.int8),
+        "xl-chunk-int8": (1, 16, 25, 64, 64, jnp.int8),
+        "4x128-f32": (4, 1, 4, 128, 64, jnp.float32),
+    },
+}
+
+
+@pytest.mark.parametrize("shapes", PAGED_SHAPES.values(), ids=PAGED_SHAPES.keys())
+def test_paged_kernel_compiles_for_the_chip_as_blocked(one_chip, shapes):
+    """The groups and query tiles ``paged_blocking`` derives pass Mosaic and
+    fit the VMEM a kernel may take unasked, at the widths the chip serves:
+    bfloat16, float32 and int8 pages, one query a row, a chunk, a verify
+    window, a chunk of several tiles, a table no group divides. (Two cases
+    and not nine: this file stays smaller than ``test_attrib.py``, whose
+    profile fixture finds no device plane in a process that has described a
+    TPU, and the workers take files largest first.)"""
+    from autodist_tpu.ops import paged_attention as PA
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(q, kp, vp, tables, pos, *scales):
+        ks, vs = scales if scales else (None, None)
+        return PA.paged_verify_attention(q, kp, vp, tables, pos, k_scale=ks,
+                                         v_scale=vs, impl="kernel",
+                                         interpret=False)
+
+    for name, (rows, n_q, h, d, n_tables, dtype) in shapes.items():
+        pages = sds((257, 16, h * d), dtype)
+        scales = (sds((257, 16, h), jnp.float32),) * 2 if dtype == jnp.int8 else ()
+        q_type = jnp.float32 if dtype == jnp.float32 else jnp.bfloat16
+        calls = _custom_calls(analysis.compiled_text(
+            jax.jit(attend), sds((rows, n_q, h, d), q_type), pages, pages,
+            sds((rows, n_tables), jnp.int32), sds((rows, n_q), jnp.int32), *scales))
+        assert len(calls) == 1 and calls[0].startswith("paged_attention"), (name, calls)
+
+
 # ------------------------------------------- the page pool stays where it lies
 XL_PAGES, XL_PAGE_LEN, XL_HEADS, XL_HEAD_DIM, XL_TABLE = 257, 16, 25, 64, 64
 
