@@ -489,7 +489,7 @@ class AutoDist:
         checkpoint: Optional[str] = None,
         n_slots: int = 8,
         max_len: Optional[int] = None,
-        page_len: int = 16,
+        page_len: Optional[int] = None,
         n_pages: Optional[int] = None,
         prefill_chunk: Optional[int] = None,
         draft_params: Any = None,

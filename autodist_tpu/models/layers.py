@@ -300,7 +300,14 @@ def rope(x, positions, theta: float):
     theta^(-2i/D)``; angles and the rotation in float32, result in ``x``'s
     type."""
     d = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    return rope_freqs(
+        x, positions, theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+
+
+def rope_freqs(x, positions, inv_freq):
+    """:func:`rope` with the ``D / 2`` frequencies given (a model that
+    scales them, as YaRN does, states its own)."""
+    d = x.shape[-1]
     angle = positions.astype(jnp.float32)[..., None, None] * inv_freq
     cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
     sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)

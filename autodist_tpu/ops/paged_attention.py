@@ -750,3 +750,201 @@ def eva_paged_attention(q, k_pages, v_pages, page_tables, q_positions, *,
                                      interpret)
     return _eva_gather_attention(q, k_pages, v_pages, page_tables,
                                  q_positions, ring_pages, window)
+
+
+# ------------------------------------------------ latent pages, no head axis
+# A cache whose page holds one row a position for all heads (latent
+# attention: DeepSeek-V2, arXiv 2405.04434): ``[n_pages, page_len, W]`` a
+# layer, the first ``value_width`` columns the normalised latent (key and
+# value alike, through two per-head projections), then one rotated key
+# shared by every head, then zeros up to whole lane tiles (a query's columns
+# there are zero too). Two paths over the one pool. A decode step absorbs
+# the projections into the query and the output and attends in the latent
+# space, every query head against the one shared ``W``-wide key: the kernel
+# below, the opposite shape of ``paged_attention`` (many query heads of one
+# position a row, one key head, one pool that is key and value at once and
+# is read once for both). A chunk of queries expands the latents of the keys
+# it sees into per-head keys and values, a block of positions at a time.
+def latent_blocking(n_tables: int, page_len: int, keys: int = 1024) -> int:
+    """Pages a grid step of ``mla_paged_attention``: the largest divisor of
+    the table width that keeps a step at or under ``keys`` positions."""
+    most = max(1, min(keys // page_len, n_tables))
+    return max(g for g in range(1, most + 1) if n_tables % g == 0)
+
+
+def _mla_gather_attention(q, pages, page_tables, positions, value_width,
+                          scale):
+    lat = _paged_gather(pages, page_tables)                     # [B, T, W]
+    logits = jnp.einsum("bhw,btw->bht", q, lat,
+                        preferred_element_type=jnp.float32) * scale
+    mask = position_mask(lat.shape[1], positions)               # [B, T]
+    probs = jax.nn.softmax(apply_mask(logits, mask[:, None, :]), axis=-1)
+    return jnp.einsum("bht,btc->bhc", probs.astype(q.dtype),
+                      lat[..., :value_width],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _mla_kernel(tables_ref, reach_ref, q_ref, *rest, page_len: int,
+                group: int, value_width: int, scale: float):
+    """One (row, page group) program: the row's latent pages ``group`` at a
+    time, each read once and used as key (all ``W`` columns) and as value
+    (the first ``value_width``); online softmax a group in float32. Groups
+    past the row's position are skipped whole and their index maps return
+    the last live group's pages, so no copy is issued; the grid ends at the
+    call's last live group (``_paged_kernel`` has the same two devices)."""
+    page_refs, (o_ref, m_ref, l_ref, acc_ref) = rest[:group], rest[group:]
+    bi, gi = pl.program_id(0), pl.program_id(1)
+    span = group * page_len
+    heads = q_ref.shape[1]
+
+    @pl.when(gi == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(gi * span < reach_ref[bi])
+    def _attend():
+        kv = jnp.concatenate([r[0] for r in page_refs], axis=0)  # [T, W]
+        s = jax.lax.dot_general(
+            q_ref[0], kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale          # [H, T]
+        t_abs = gi * span + jax.lax.broadcasted_iota(
+            jnp.int32, (heads, span), 1)
+        s = jnp.where(t_abs < reach_ref[bi], s, NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        pexp = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_ref[...] = alpha * l_ref[...] + pexp.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            pexp.astype(kv.dtype), kv[:, :value_width],
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(gi == pl.num_programs(1) - 1)
+    def _finalize():
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "value_width", "scale", "keys", "interpret"))
+def _mla_kernel_call(q, pages, page_tables, positions, *, value_width: int,
+                     scale: float, keys: int, interpret: bool):
+    b, h, w = q.shape
+    page_len = pages.shape[1]
+    n_tables = page_tables.shape[1]
+    group = latent_blocking(n_tables, page_len, keys)
+    span = group * page_len
+    reach = positions.astype(jnp.int32) + 1                       # [B]
+
+    def page_index(j):
+        def index(bi, gi, t, reach):
+            last = jax.lax.div(reach[bi] - 1, span)
+            return (t[bi, jnp.minimum(gi, last) * group + j], 0, 0)
+        return index
+
+    n_live = jnp.minimum(n_tables // group,
+                         jax.lax.div(reach.max() + span - 1, span))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, n_live),
+        in_specs=[pl.BlockSpec((1, h, w), lambda bi, gi, *_: (bi, 0, 0))]
+        + [pl.BlockSpec((1, page_len, w), page_index(j)) for j in range(group)],
+        out_specs=pl.BlockSpec((1, h, value_width),
+                               lambda bi, gi, *_: (bi, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),                  # m
+            pltpu.VMEM((h, 1), jnp.float32),                  # l
+            pltpu.VMEM((h, value_width), jnp.float32),        # acc
+        ],
+    )
+    kernel = functools.partial(_mla_kernel, page_len=page_len, group=group,
+                               value_width=value_width, scale=scale)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, value_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="mla_paged_attention",
+    )(page_tables.astype(jnp.int32), reach, q, *([pages] * group))
+
+
+def mla_paged_decode_attention(q, pages, page_tables, positions, *,
+                               value_width: int, scale: float,
+                               impl: str = "gather", keys: int = 1024,
+                               interpret: Optional[bool] = None):
+    """Decode-step attention in the latent space: ``q [B, H, W]`` (each
+    head's query with the key projection absorbed, then its rotated part),
+    ``pages [n_pages, page_len, W]`` one layer's pool, ``page_tables [B,
+    P]``, ``positions [B]``. Returns ``[B, H, value_width]``: per head the
+    softmax-weighted sum of the latents, for the value projection to take
+    out of the latent space. ``scale`` multiplies the float32 scores."""
+    _check_impl(impl)
+    if impl == "kernel":
+        if interpret is None:
+            interpret = _should_interpret()
+        return _mla_kernel_call(q, pages, page_tables, positions,
+                                value_width=value_width, scale=float(scale),
+                                keys=keys, interpret=interpret)
+    return _mla_gather_attention(q, pages, page_tables, positions,
+                                 value_width, scale)
+
+
+def mla_paged_prefill_attention(q_nope, q_rope, pages, page_table, positions,
+                                w_k, w_v, *, rope_width: int, scale: float,
+                                block: int = 512):
+    """A chunk's attention with the latents expanded: ``q_nope [C, H, dn]``,
+    ``q_rope [C, H, dr]`` (rotated) at absolute ``positions [C]``; ``pages
+    [n_pages, page_len, W]`` one layer's pool (``W >= Ckv + dr``: the latent,
+    the rotated key of ``rope_width`` columns, zeros) through ``page_table
+    [P]`` (the chunk's own rows written already); ``w_k [Ckv, H, dn]``,
+    ``w_v [Ckv, H, dv]`` the two halves of the latent's up-projection. A
+    block of ``block`` positions at a time the latents become per-head keys
+    and values and meet the queries under one online softmax (float32);
+    blocks past the chunk's last position are not walked, so a chunk pays
+    for the context it has. Returns ``[C, H, dv]`` in the queries' type."""
+    c, h, _ = q_nope.shape
+    page_len = pages.shape[1]
+    n_tables = page_table.shape[0]
+    ckv = w_k.shape[0]
+    group = latent_blocking(n_tables, page_len, block)
+    span = group * page_len
+    dt = q_nope.dtype
+    pos = positions.astype(jnp.int32)
+
+    def body(j, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(page_table, j * group, group)
+        lat = pages[ids].reshape(span, -1).astype(dt)             # [T, W]
+        k_nope = jnp.einsum("tc,chd->thd", lat[:, :ckv], w_k.astype(dt),
+                            preferred_element_type=jnp.float32).astype(dt)
+        v = jnp.einsum("tc,chd->thd", lat[:, :ckv], w_v.astype(dt),
+                       preferred_element_type=jnp.float32).astype(dt)
+        s = (jnp.einsum("qhd,thd->hqt", q_nope, k_nope,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("qhr,tr->hqt", q_rope, lat[:, ckv:ckv + rope_width],
+                          preferred_element_type=jnp.float32)) * scale
+        t_abs = j * span + jnp.arange(span, dtype=jnp.int32)
+        s = jnp.where(t_abs[None, None, :] <= pos[None, :, None], s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        pexp = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + pexp.sum(axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hqt,thd->hqd", pexp.astype(dt), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    n_live = jnp.minimum(n_tables // group,
+                         jax.lax.div(pos.max() + span, span))
+    init = (jnp.full((h, c), NEG_INF, jnp.float32),
+            jnp.zeros((h, c), jnp.float32),
+            jnp.zeros((h, c, w_v.shape[2]), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_live, body, init)
+    out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+    return out.transpose(1, 0, 2).astype(dt)

@@ -315,6 +315,9 @@ class ContinuousBatcher:
         # ``kv_groups`` / ``kv_groups_live``), lazily registered likewise.
         self._m_kv_groups = None
         self._m_kv_groups_live = None
+        # What only the device knows of a decode step (the engine's
+        # ``fact_totals``, a model's ``step_facts``), lazily registered.
+        self._m_facts = None
 
         reg = registry or M.registry
         self._registry = reg
@@ -770,6 +773,7 @@ class ContinuousBatcher:
             self._update_quant_metrics()
             self._update_ring_metrics(sp)
             self._update_kv_group_metrics()
+            self._update_step_fact_metrics(sp)
             with self._lock:
                 self._m_active.set(len(self._active))
             self._m_pool_util.set(self.engine.page_utilization)
@@ -1056,6 +1060,35 @@ class ContinuousBatcher:
         for counter, now in ((self._m_kv_groups, eng.kv_groups),
                              (self._m_kv_groups_live, eng.kv_groups_live)):
             counter.inc(now - counter.value)
+
+    def _update_step_fact_metrics(self, sp) -> None:
+        """Publish what only the device knows of the decode steps (a
+        model's ``step_facts``, which reach the host with the tokens), from
+        the engine's cumulative counts: a counter ``serve_<fact>_total`` a
+        fact (``serve_moe_pairs_total``: token-expert pairs that fell on
+        the experts this chip holds; ``serve_moe_experts_hit_total``: held
+        experts with at least one pair, summed over the expert layers) and
+        the decode steps they were summed over, under the name the model
+        states (``steps_fact``: ``serve_moe_steps_total``). The cumulative
+        readings ride the
+        ``serve.tick_metrics`` span under the counters' names without
+        ``serve_`` and ``_total``; a step's own are on its
+        ``serve.decode_step`` span and a chunk's on its
+        ``serve.prefill_chunk`` span. No-op for a model that states none."""
+        eng = self.engine
+        facts = getattr(eng, "step_facts", ())
+        if not facts:
+            return
+        readings = dict(eng.fact_totals)
+        if eng.steps_fact:
+            readings[eng.steps_fact] = eng.fact_steps
+        if self._m_facts is None:
+            self._m_facts = {
+                name: self._registry.counter(f"serve_{name}_total")
+                for name in readings}
+        for name, now in readings.items():
+            self._m_facts[name].inc(now - self._m_facts[name].value)
+            sp[name] = now
 
     def _update_quant_metrics(self) -> None:
         """Publish the physical-vs-quantized pool byte split. No-op on fp

@@ -91,7 +91,26 @@ class DecodeModel:
     it, the prefill chunk it asks for and the constraint any chunk must
     keep, the page dim of its leaves. None is the plain paged timeline.
     The engine sizes tables and reservations from it; it refuses prefix
-    sharing, int8 pages and speculative verification over a ring.
+    sharing, int8 pages and speculative verification over a ring. A model
+    whose page has no head axis (one row a position for all heads, key
+    and value the same bytes) states ``latent=True`` and a page length of
+    its own: its one leaf a layer is priced, sharded and copied by the
+    page dim like any other, and int8 pages and speculative verification
+    over it are refused.
+
+    ``step_facts``: names of int32 scalars that only the device knows and
+    that both programs append to the token vector they return
+    (``prefill_chunk`` then returns ``[1 + F]``, ``decode_paged`` ``[B +
+    F]``): a model that holds a share of its experts reports the pairs
+    that fell on them and how many of them were hit. They reach the host
+    with the fetch that brings the tokens (no second round trip): the
+    engine stamps them on the ``serve.decode_step`` / ``serve.prefill_chunk``
+    span of the program that produced them and adds them up
+    (``InferenceEngine.fact_totals``). ``steps_fact`` names the count of
+    decode steps they were added up over (``moe_steps``), published beside
+    them. A row that is not decoding carries position 0 and an all-scratch
+    table in ``decode_paged``: a model whose facts count rows has that to
+    tell them by.
 
     ``autodist_tpu.models.transformer.decode_model(cfg)`` builds one for
     the zoo transformer; any model matching the contract serves the same
@@ -110,6 +129,8 @@ class DecodeModel:
     eos_id: Optional[int] = None
     max_len: Optional[int] = None
     cache_layout: Optional[serve_pages.CacheLayout] = None
+    step_facts: Tuple[str, ...] = ()
+    steps_fact: Optional[str] = None
 
 
 @dataclass(frozen=True)
@@ -174,7 +195,7 @@ class InferenceEngine:
         apply_fn: Optional[Callable] = None,
         decode_model: Optional[DecodeModel] = None,
         n_slots: int = 8,
-        page_len: int = serve_pages.DEFAULT_PAGE_LEN,
+        page_len: Optional[int] = None,
         n_pages: Optional[int] = None,
         prefill_chunk: Optional[int] = None,
         max_len: Optional[int] = None,
@@ -217,11 +238,12 @@ class InferenceEngine:
             n_slots += self._data_degree - n_slots % self._data_degree
         self.n_slots = n_slots
         # The model's statement of its cache; none is the plain paged
-        # timeline at the page length asked for.
+        # timeline at the page length asked for (16 where none is).
         layout = decode_model.cache_layout
         if layout is None:
-            layout = serve_pages.CacheLayout(page_len=int(page_len))
-        elif int(page_len) != layout.page_len:
+            layout = serve_pages.CacheLayout(
+                page_len=int(page_len or serve_pages.DEFAULT_PAGE_LEN))
+        elif page_len is not None and int(page_len) != layout.page_len:
             raise ValueError(
                 f"this model's cache has pages of {layout.page_len} "
                 f"positions; page_len={page_len} was asked for")
@@ -261,7 +283,7 @@ class InferenceEngine:
         # reads beside the table width (ops/paged_attention.py).
         wide = max(jax.tree_util.tree_leaves(page_shaped),
                    key=lambda leaf: leaf.shape[-1])
-        self._kv_page = None if layout.window else (
+        self._kv_page = None if layout.window or layout.latent else (
             int(wide.shape[-1]), np.dtype(wide.dtype).itemsize)
         # Quantized pool mode (int8 pages + f32 scale planes, PR 20):
         # detected from the model's own cache pytree, so the engine needs
@@ -279,6 +301,11 @@ class InferenceEngine:
                 + " over a window ring: the ring is overwritten as the "
                 "window moves and its summaries are made from unquantised "
                 "keys (ROADMAP.md Queue 2)")
+        if layout.latent and self.kv_quant:
+            raise serve_pages.CacheFeatureRefused(
+                "int8 pages over a latent pool: a latent row is shared by "
+                "every head and no scale plane is defined for it "
+                "(ROADMAP.md Queue 2)")
         if self.kv_quant:
             fp_itemsize = np.dtype(jax.tree_util.tree_leaves(jax.eval_shape(
                 lambda: decode_model.init_paged_cache(
@@ -394,6 +421,16 @@ class InferenceEngine:
         # batcher publishes them (serve_kv_groups_total, ..._live_total).
         self.kv_groups = 0
         self.kv_groups_live = 0
+        # What only the device knows of a step (``DecodeModel.step_facts``):
+        # summed over the decode steps fetched (``fact_steps`` of them), for
+        # the batcher to publish; a chunk's facts stay on its span. A chunk
+        # whose token nobody waits for leaves its vector on the device
+        # until the next fetch brings it along.
+        self.step_facts = tuple(decode_model.step_facts)
+        self.steps_fact = decode_model.steps_fact
+        self.fact_totals = dict.fromkeys(self.step_facts, 0)
+        self.fact_steps = 0
+        self._facts_pending: List[Tuple[Dict[str, Any], Any]] = []
         # Replica identity carried into the chaos seams so a schedule can
         # target ONE replica of a fleet (replica_death injects
         # EngineDeadError only where host matches — docs/chaos.md).
@@ -903,7 +940,8 @@ class InferenceEngine:
 
     def _dispatch_chunk(self, idx: int):
         """Dispatch the next chunk of row ``idx``'s prompt and advance its
-        position; returns ``(first token, still on the device; final)``.
+        position; returns ``(first token, still on the device; final; the
+        span's attributes)``.
 
         The ``serve.prefill_chunk`` span covers the host's preparation and
         the asynchronous DISPATCH of the chunk, not its run on the device
@@ -931,7 +969,9 @@ class InferenceEngine:
             self.window_rolls += rolls
             self.summary_chunks += chunks
         self._prefill_pos[idx] = start + c
-        return first, final
+        if self.step_facts and not final:
+            self._facts_pending.append((sp, first))
+        return first, final, sp
 
     def _dispatch_chunks_ahead(self) -> None:
         """Behind a decode step that has just been dispatched: the next
@@ -945,6 +985,29 @@ class InferenceEngine:
                 continue
             self._dispatch_chunk(idx)
             self._chunk_ahead[idx] = True
+
+    def _fetch(self, tokens, attrs, pending=None, decode=False) -> np.ndarray:
+        """The one host fetch of a tick: a program's token vector and,
+        with it, the vectors of the chunks dispatched before it whose
+        tokens nobody waited for (``pending``: ``(span attributes,
+        vector)``; all that are left where none is given), which have run
+        by now. Returns the tokens. Where the model states ``step_facts``
+        each vector's tail is stamped on the span that produced it
+        (``attrs`` for ``tokens``' own) and a decode step's is added to
+        ``fact_totals``."""
+        if not self.step_facts:
+            return np.asarray(jax.device_get(tokens))
+        if pending is None:
+            pending, self._facts_pending = self._facts_pending, []
+        n = len(self.step_facts)
+        got = jax.device_get([tokens] + [vec for _, vec in pending])
+        for where, vec in zip([attrs] + [sp for sp, _ in pending], got):
+            where.update(zip(self.step_facts, (int(v) for v in vec[-n:])))
+        if decode:
+            for name in self.step_facts:
+                self.fact_totals[name] += attrs[name]
+            self.fact_steps += 1
+        return np.asarray(got[0])[:-n]
 
     def prefill_step(self, slot: Slot) -> Optional[int]:
         """Run ONE prefill chunk for ``slot``. Returns the first generated
@@ -960,11 +1023,11 @@ class InferenceEngine:
             return None
         prompt = self._prompts[idx]
         c = self.prefill_chunk
-        first, final = self._dispatch_chunk(idx)
+        first, final, chunk_sp = self._dispatch_chunk(idx)
         if not final:
             return None
         with obs_spans.span("serve.token_fetch", program="prefill_chunk"):
-            first = int(jax.device_get(first)[0])
+            first = int(self._fetch(first, chunk_sp)[0])
         self._phase[idx] = _DECODE
         self._lengths[idx] = len(prompt)
         self._last_token[idx] = first
@@ -1015,7 +1078,7 @@ class InferenceEngine:
                 if self._request_ids[int(i)]]
         self.decode_invocations += 1
         with obs_spans.span("serve.decode_step", active=int(len(decoding)),
-                            request_ids=rids):
+                            request_ids=rids) as step_sp:
             # The host's part (the per-tick puts and the call) apart from
             # the wait for the device: a device left idle under the first
             # is the host's to cure, under the second it is not idle.
@@ -1028,10 +1091,13 @@ class InferenceEngine:
                     self._cache,
                     jnp.asarray(self._decode_table_np),
                     self._samp_dev())
+            # the chunks that went out before this step have run by the
+            # time its tokens arrive; those about to go out behind it have not
+            ran, self._facts_pending = self._facts_pending, []
             if self.prefill_lookahead:
                 self._dispatch_chunks_ahead()
             with obs_spans.span("serve.token_fetch", program="decode_step"):
-                tokens = np.asarray(jax.device_get(tokens))
+                tokens = self._fetch(tokens, step_sp, ran, decode=True)
         for idx in decoding:
             idx = int(idx)
             if self.layout.window:

@@ -55,8 +55,8 @@ def pages_for_tokens(n_tokens: int, page_len: int) -> int:
 class CacheFeatureRefused(ValueError):
     """A feature of the serving stack was asked for over a cache that
     cannot carry it (prefix sharing, int8 pages or speculative verification
-    over a window ring). Raised when the engine is built, never under
-    load."""
+    over a window ring; int8 pages or speculative verification over latent
+    pages). Raised when the engine is built, never under load."""
 
 
 @dataclass(frozen=True)
@@ -80,14 +80,24 @@ class CacheLayout:
     ``prefill_chunk`` is the chunk the model asks for; any chunk has to be
     a multiple of ``page_len`` and, with a window, divide it (no chunk
     straddles a window). ``page_axis`` is where the cache's leaves carry
-    the page dim: 0 for a leaf a layer (both model families), 1 for a pool
-    stacked over layers.
+    the page dim: 0 for a leaf a layer (every model family here), 1 for a
+    pool stacked over layers.
+
+    ``latent`` says that a page has no head axis: one row a position for
+    all heads, key and value the same bytes (one leaf a layer, ``[n_pages,
+    page_len, width]``), read by a kernel of the model's own. The timeline
+    is plain, so tables, reservation and prefix sharing are what they are
+    for any plain timeline, and a page is priced from the leaf's shape like
+    any other; the engine counts no page groups of ``paged_attention``'s
+    blocking for it, and int8 pages and speculative verification over it
+    are refused.
     """
 
     page_len: int = DEFAULT_PAGE_LEN
     window: Optional[int] = None
     prefill_chunk: Optional[int] = None
     page_axis: int = 0
+    latent: bool = False
 
     def __post_init__(self):
         if self.window is not None and self.window % self.page_len:

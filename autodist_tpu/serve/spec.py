@@ -153,6 +153,12 @@ class SpecDecodeEngine(InferenceEngine):
                     "speculative verification over a window ring: a "
                     "rejected draft would have to take back ring entries "
                     "it overwrote (ROADMAP.md Queue 2)")
+            if dm is not None and dm.cache_layout is not None \
+                    and dm.cache_layout.latent:
+                raise serve_pages.CacheFeatureRefused(
+                    "speculative verification over a latent pool: no "
+                    "program verifies several positions a row against "
+                    "latent pages (ROADMAP.md Queue 2)")
         super().__init__(params, plan, apply_fn=apply_fn,
                          decode_model=decode_model, **engine_kwargs)
         if decode_model is None or decode_model.verify_paged is None:

@@ -616,3 +616,69 @@ def test_serving_programs_leave_the_page_pool_where_it_lies(one_chip, monkeypatc
         else:
             assert op in in_place, f"{op} of a leaf's size: {line[:300]}"
     assert arrivals <= 2, f"{arrivals} leaves ride the prefetch"
+
+
+@pytest.mark.parametrize("program", ["decode-step", "prefill-chunk"])
+def test_latent_serving_programs_leave_the_page_pool_where_it_lies(one_chip, monkeypatch,
+                                                                  program):
+    """The twin for a pool with no head axis (PR 36): Kimi K2's published
+    widths, the dense layer and one expert layer, 32 rows, pages of 128
+    positions, the cache donated, the real Mosaic calls. A layer's one leaf
+    ``[n_pages, 128, 640]`` (576 columns in whole lane tiles) is a parameter,
+    is written by a scatter in place and is read by ``mla_paged_attention``
+    (decode) or gathered a block of positions at a time (a chunk) as it
+    lies: no copy, transpose or other fusion of a leaf's size. At 576
+    columns XLA laid the 128 positions minor and copied the whole pool
+    twice a layer in both programs (PERF.md section 6, PR 36). The decode
+    program holds one latent call a layer and three grouped products an
+    expert layer, by name."""
+    from autodist_tpu.models import kimi_k2 as K
+    from autodist_tpu.ops import grouped_matmul as GM
+    from autodist_tpu.ops import paged_attention as PA
+
+    monkeypatch.setattr(PA, "_should_interpret", lambda: False)
+    monkeypatch.setattr(GM, "_should_interpret", lambda: False)
+    monkeypatch.setattr(K, "_resolve", lambda choice, off_chip: "kernel")   # as on the chip
+    pages, rows, table = 301, 32, 64
+    cfg = K.KimiK2Config(vocab_size=20480, num_hidden_layers=2, experts_held=(0, 12),
+                         max_position_embeddings=8192)
+
+    def described(tree, dtype=None):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype or x.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = described(jax.eval_shape(
+        lambda: K.init_params(jax.random.PRNGKey(0), cfg)), jnp.bfloat16)
+    cache = described(jax.eval_shape(lambda: K.init_paged_cache(cfg, pages, cfg.page_len)))
+
+    def serve_decode_step(params, tokens, positions, cache, tables):
+        return K.forward_paged_decode_step(params, tokens, positions, cache, tables, cfg)
+
+    def serve_prefill_chunk(params, tokens, start, length, cache, table_):
+        return K.forward_paged_prefill_chunk(params, tokens, start, length, cache,
+                                             table_, cfg)
+
+    fn, args, donated = {
+        "decode-step": (serve_decode_step,
+                        (params, i32(rows), i32(rows), cache, i32(rows, table)), 3),
+        "prefill-chunk": (serve_prefill_chunk,
+                          (params, i32(1, cfg.prefill_chunk), i32(), i32(), cache,
+                           i32(table)), 4),
+    }[program]
+    text = analysis.compiled_text(jax.jit(fn, donate_argnums=(donated,)), *args)
+
+    calls = sorted(c.split(".")[0] for c in _custom_calls(text))
+    latent = ["mla_paged_attention"] * 2 if program == "decode-step" else []
+    assert calls == ["gmm"] * 3 + latent, calls
+    assert cfg.page_width == 640
+    found = _leaf_sized(text, f"bf16[{pages},{cfg.page_len},640]")
+    assert sum(op == "scatter" for op, _ in found) == 2
+    in_place = {"parameter", "scatter", "tuple", "get-tuple-element", "bitcast", "while"}
+    for op, line in found:
+        if op == "fusion":
+            assert "/scatter\"" in line, f"a fusion of a leaf's size, no write: {line[:300]}"
+        else:
+            assert op in in_place, f"{op} of a leaf's size: {line[:300]}"
