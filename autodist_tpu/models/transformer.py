@@ -199,19 +199,73 @@ def _block(block_params, x, cfg: TransformerConfig):
     return _mlp_part(block_params, x, cfg)
 
 
+#: Tokens up to which :func:`_columns` reads a column a token: a column
+#: touches ``D / 8`` tiles, about 1/800 of what re-laying a 50,257-row table
+#: out costs, and the slices are unrolled into the program.
+_COLUMN_TOKENS = 256
+
+
+def _columns(table, ids):
+    """``table[:, ids]`` moved to ``ids.shape + (D,)``, for a table held
+    ``[D, V]``. A serving program's few tokens take one dynamic slice each,
+    which reads a column where it lies (clamped where a gather would
+    fill); more take one gather, which re-lays the table out first."""
+    flat = ids.reshape(-1)
+    if flat.shape[0] > _COLUMN_TOKENS:
+        return jnp.moveaxis(jnp.take(table, ids, axis=1), 0, -1)
+    cols = [jax.lax.dynamic_slice_in_dim(table, flat[j], 1, axis=1)
+            for j in range(flat.shape[0])]
+    return jnp.concatenate(cols, axis=1).T.reshape(ids.shape + table.shape[:1])
+
+
 def _embed(params, tokens, cfg: TransformerConfig, positions=None):
     """Token + learned position embedding in the compute dtype;
-    ``positions`` None is the sequence's own ``0..S-1``."""
-    x = L.embedding_lookup(params["embed"], tokens).astype(cfg.dtype)
+    ``positions`` None is the sequence's own ``0..S-1``. A tree that holds
+    the tied table as the head reads it (``head``, :func:`serving_params`)
+    gives a token's row from its column."""
+    if "head" in params:
+        x = _columns(params["head"], tokens)
+    else:
+        x = L.embedding_lookup(params["embed"], tokens)
     if positions is None:
         positions = jnp.arange(tokens.shape[-1])
-    return x + L.embedding_lookup(params["pos_embed"], positions).astype(cfg.dtype)
+    return (x.astype(cfg.dtype)
+            + L.embedding_lookup(params["pos_embed"], positions).astype(cfg.dtype))
 
 
 def _head(params, x, cfg: TransformerConfig):
     """Tied output embedding: one big ``[.., D] x [D, V]`` matmul on the
-    MXU, in the compute dtype."""
-    return x.astype(cfg.dtype) @ params["embed"]["embedding"].T.astype(cfg.dtype)
+    MXU, in the compute dtype; a tree that holds ``head`` is read as it
+    lies, training's transposes the embedding."""
+    head = params["head"] if "head" in params else params["embed"]["embedding"].T
+    return x.astype(cfg.dtype) @ head.astype(cfg.dtype)
+
+
+def serving_params(params, cfg: TransformerConfig):
+    """The tree the paged programs read (``DecodeModel.serving_params``):
+    every leaf whose only use there is behind ``.astype(cfg.dtype)`` cast
+    to it once — the projections' kernels and biases (``L.dense``) and both
+    embeddings (:func:`_embed` casts after the lookup, :func:`_head` casts
+    the table). The LayerNorm leaves stay as given: ``L.layernorm``
+    multiplies float32 by them. The same values reach every product, at
+    half the bytes.
+
+    The tied table is held once, as the head's product reads it: ``head``
+    ``[D, V]`` in place of ``embed``. On the TPU ``[V, D]`` lies with V
+    minor at these widths, as the product wants it, so a gather of its
+    rows re-lays all of it out on every call; :func:`_embed` reads a
+    token's column instead."""
+    def cast(tree):
+        return jax.tree_util.tree_map(lambda x: x.astype(cfg.dtype), tree)
+
+    out = {k: v for k, v in params.items() if k != "embed"}
+    out["head"] = params["embed"]["embedding"].T.astype(cfg.dtype)
+    out["pos_embed"] = cast(params["pos_embed"])
+    for i in range(cfg.num_layers):
+        block = params[f"layers_{i}"]
+        out[f"layers_{i}"] = dict(block, attn=cast(block["attn"]),
+                                  mlp=cast(block["mlp"]))
+    return out
 
 
 def forward(params, tokens, cfg: TransformerConfig):
@@ -548,6 +602,7 @@ def decode_model(cfg: TransformerConfig, eos_id: Optional[int] = None):
                 params, tokens, positions, cache, tables, cfg, samp=samp),
         eos_id=eos_id,
         max_len=cfg.max_seq_len,
+        serving_params=partial(serving_params, cfg=cfg),
     )
 
 

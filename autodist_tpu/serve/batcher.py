@@ -28,7 +28,8 @@ recycle; liveness is a property, not a tuning outcome — the
 
 Metrics (through :mod:`autodist_tpu.metrics`' registry):
 ``serve_queue_depth`` / ``serve_active_slots`` /
-``serve_page_pool_utilization`` / ``serve_page_fragmentation`` gauges,
+``serve_page_pool_utilization`` / ``serve_page_fragmentation`` /
+``serve_param_bytes`` (the engine's placed parameter tree) gauges,
 ``serve_requests_{submitted,completed,timeout,rejected}_total`` counters,
 ``serve_tokens_generated_total`` / ``serve_decode_tokens_generated_total``
 counters (a rate is their increase over the reader's own interval), and
@@ -335,6 +336,10 @@ class ContinuousBatcher:
         self._m_latency = reg.histogram("serve_request_latency_s")
         self._m_ttft = reg.histogram("serve_ttft_s")
         self._m_itl = reg.histogram("serve_itl_s")
+        # The bytes of the parameter tree the engine placed for its
+        # programs (a model's ``serving_params`` of the caller's, or it).
+        reg.gauge("serve_param_bytes").set(
+            float(getattr(engine, "param_bytes", 0)))
 
     # ---------------------------------------------------------------- clients
     def submit(

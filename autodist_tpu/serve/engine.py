@@ -49,6 +49,7 @@ from autodist_tpu.ops.paged_attention import paged_group_counts
 from autodist_tpu.serve import pages as serve_pages
 from autodist_tpu.serve import prefix as serve_prefix
 from autodist_tpu.serve import sampling as serve_sampling
+from autodist_tpu.utils import logging
 
 #: Slot phases (host bookkeeping; single scheduler-thread writer).
 _FREE, _PREFILL, _DECODE = 0, 1, 2
@@ -112,6 +113,16 @@ class DecodeModel:
     table in ``decode_paged``: a model whose facts count rows has that to
     tell them by.
 
+    ``serving_params``: a pure function from the caller's parameter tree
+    to the tree the paged programs read (``prefill_chunk``,
+    ``decode_paged``, ``verify_paged``), which the engine applies once and
+    places in place of the caller's (a one-shot ``apply_fn`` reads the
+    same tree). What a model may put there leaves its arithmetic alone: a
+    leaf whose every use in those programs is behind a cast to its compute
+    dtype, cast once (the programs then read half the bytes and convert
+    nothing), or a leaf held in the form its use reads. None places the
+    caller's leaves as given.
+
     ``autodist_tpu.models.transformer.decode_model(cfg)`` builds one for
     the zoo transformer; any model matching the contract serves the same
     way.
@@ -131,6 +142,29 @@ class DecodeModel:
     cache_layout: Optional[serve_pages.CacheLayout] = None
     step_facts: Tuple[str, ...] = ()
     steps_fact: Optional[str] = None
+    serving_params: Optional[Callable[[Any], Any]] = None
+
+
+def tree_bytes(tree: Any) -> int:
+    """Bytes of a pytree's leaves (arrays or shapes)."""
+    return sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
+def place_params(params: Any, plan: ShardingPlan,
+                 decode_model: Optional[DecodeModel] = None) -> Any:
+    """The parameters the engine's programs read, on the device in the
+    plan's shardings: the model's ``serving_params`` of ``params`` where
+    it states one, else ``params`` as given. Storage view (pad-and-mask
+    plans store padded; the programs unpad under the trace) and
+    ``device_view``: serving ignores host-offload markers, params stay
+    HBM-resident (offload is a training-memory bargain inference has no
+    reason to pay per step). The model's function runs as one program."""
+    if decode_model is not None and decode_model.serving_params is not None:
+        params = jax.jit(decode_model.serving_params)(params)
+    stored = plan.pad_params(params)
+    return jax.device_put(stored, plan.params_shardings(
+        jax.eval_shape(lambda: stored), device_view=True))
 
 
 @dataclass(frozen=True)
@@ -213,16 +247,12 @@ class InferenceEngine:
         self._data_degree = dict(
             zip(self.mesh.axis_names, self.mesh.devices.shape))[self._data_axis]
         # Storage view + plan shardings: the same parameter contract the
-        # train step uses (pad-and-mask plans store padded; the wrapped fns
-        # below unpad under the trace). device_view: serving ignores
-        # host-offload markers — params stay HBM-resident (offload is a
-        # training-memory bargain inference has no reason to pay per step).
-        self.params = jax.device_put(
-            plan.pad_params(params),
-            plan.params_shardings(
-                jax.eval_shape(lambda: plan.pad_params(params)),
-                device_view=True),
-        )
+        # train step uses, in the tree the model's programs read.
+        self.param_bytes_given = tree_bytes(params)
+        self.params = place_params(params, plan, decode_model)
+        self.param_bytes = tree_bytes(self.params)
+        logging.info("serving parameters placed: %d bytes (%d given)",
+                     self.param_bytes, self.param_bytes_given)
         self._apply_fn = apply_fn
         self._apply_jit = (
             jax.jit(lambda p, b: apply_fn(plan.unpad_params(p), b))
@@ -274,9 +304,7 @@ class InferenceEngine:
         # correctly.
         page_shaped = jax.eval_shape(
             lambda: decode_model.init_paged_cache(1, self.page_len))
-        page_bytes = sum(
-            int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-            for leaf in jax.tree_util.tree_leaves(page_shaped))
+        page_bytes = tree_bytes(page_shaped)
         self.page_bytes = page_bytes
         # Over the plain timeline: the lanes and item size of a page of
         # keys (the cache's widest leaf), what the paged kernel's blocking
@@ -326,12 +354,8 @@ class InferenceEngine:
         sharing_factor = 2.0 if prefix_cache else 1.0
         if n_pages is None:
             if resource_spec is not None:
-                params_bytes = sum(
-                    int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-                    for leaf in jax.tree_util.tree_leaves(
-                        jax.eval_shape(lambda: params)))
                 n_pages = serve_pages.pool_size_from_spec(
-                    resource_spec, page_bytes, params_bytes=params_bytes,
+                    resource_spec, page_bytes, params_bytes=self.param_bytes,
                     serve_frac=serve_hbm_frac,
                     shard_degree=self._data_degree,
                     max_useful_pages=max_useful,

@@ -82,6 +82,8 @@ from autodist_tpu.serve.engine import (
     DecodeModel,
     InferenceEngine,
     Slot,
+    place_params,
+    tree_bytes,
 )
 
 __all__ = ["SpecDecodeEngine", "build_draft_plan", "selftest_spec"]
@@ -177,15 +179,13 @@ class SpecDecodeEngine(InferenceEngine):
         self.spec_k = int(spec_k)
         self.draft_decode_model = draft_decode_model
         self.draft_plan = draft_plan
-        # Draft params land in THEIR plan's shardings (device view), the
-        # same contract the target params keep — a draft checkpoint
-        # restores through InferenceEngine.restore_params with this plan
-        # (the Saver.restore_subtree path), see SpecDecodeEngine.build.
-        self.draft_params = jax.device_put(
-            draft_plan.pad_params(draft_params),
-            draft_plan.params_shardings(
-                jax.eval_shape(lambda: draft_plan.pad_params(draft_params)),
-                device_view=True))
+        # Draft params land in THEIR plan's shardings (device view), in
+        # the tree the draft's programs read — the same contract the
+        # target params keep; a draft checkpoint restores through
+        # InferenceEngine.restore_params with this plan (the
+        # Saver.restore_subtree path), see SpecDecodeEngine.build.
+        self.draft_params = place_params(
+            draft_params, draft_plan, draft_decode_model)
         # Draft pool: its pages are cheap (the draft is small), so default
         # to the target pool's page count — enough to shadow every target
         # timeline. Best-effort by contract: exhaustion starves drafting,
@@ -196,9 +196,7 @@ class SpecDecodeEngine(InferenceEngine):
             dn += self._data_degree - dn % self._data_degree
         draft_shaped = jax.eval_shape(
             lambda: draft_decode_model.init_paged_cache(1, self.page_len))
-        self.draft_page_bytes = sum(
-            int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-            for leaf in jax.tree_util.tree_leaves(draft_shaped))
+        self.draft_page_bytes = tree_bytes(draft_shaped)
         # Quantized draft pages ride the same detection the target pool
         # uses — spec losslessness under quantization holds because draft
         # and verify both read the SAME quantized page contents.

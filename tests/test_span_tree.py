@@ -618,6 +618,79 @@ def test_serving_programs_leave_the_page_pool_where_it_lies(one_chip, monkeypatc
     assert arrivals <= 2, f"{arrivals} leaves ride the prefetch"
 
 
+def _entry(text):
+    """The compiled program's entry computation (what runs at the top
+    level, fusions' insides left out)."""
+    return text[text.index("\nENTRY "):]
+
+
+@pytest.mark.parametrize("program", ["decode-step", "prefill-chunk"])
+def test_serving_programs_read_the_weights_as_placed(one_chip, monkeypatch, program):
+    """The witness of ``DecodeModel.serving_params`` (PR 37): GPT-2 XL's
+    widths, a few layers, the real Mosaic call. From the tree the engine
+    places, the compiled program holds no float32 array of a projection's
+    shape (no float32 weight streamed, nothing converted from one) and, at
+    its top level, nothing of the tied table's size but the parameter the
+    head's product reads: the float32 tree's program copies all 50,257 x
+    1,600 of it for the lookup on every call (the check sees that too)."""
+    import re
+
+    from autodist_tpu.models import transformer as T
+    from autodist_tpu.ops import paged_attention as PA
+
+    monkeypatch.setattr(PA, "_should_interpret", lambda: False)
+    cfg = T.TransformerConfig(
+        vocab_size=50257, num_layers=2, d_model=XL_HEADS * XL_HEAD_DIM,
+        num_heads=XL_HEADS, d_ff=6400, max_seq_len=1024, dtype=jnp.bfloat16,
+        paged_attention_impl="kernel")
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    given = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+    placed = jax.eval_shape(lambda: T.decode_model(cfg).serving_params(
+        T.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = described(jax.eval_shape(
+        lambda: T.init_paged_kv_cache(cfg, XL_PAGES, XL_PAGE_LEN)))
+
+    def serve_decode_step(params, tokens, positions, cache, tables):
+        return T.forward_paged_decode_step(params, tokens, positions, cache, tables, cfg)
+
+    def serve_prefill_chunk(params, tokens, start, length, cache, table):
+        return T.forward_paged_prefill_chunk(params, tokens, start, length, cache,
+                                             table, cfg)
+
+    def compiled(params):
+        fn, args, donated = {
+            "decode-step": (serve_decode_step,
+                            (params, i32(4), i32(4), cache, i32(4, XL_TABLE)), 3),
+            "prefill-chunk": (serve_prefill_chunk,
+                              (params, i32(1, 16), i32(), i32(), cache, i32(XL_TABLE)), 4),
+        }[program]
+        return analysis.compiled_text(jax.jit(fn, donate_argnums=(donated,)), *args)
+
+    weights = r"f32\[(?:1600,1600|1600,6400|6400,1600)\]"
+    table = r"\[(?:50257,1600|1600,50257)\]"
+
+    def table_ops(text):
+        return [m.group(1) for m in re.finditer(
+            r"^\s*(?:ROOT )?%\S+ = \S*" + table + r"\S* ([\w-]+)\(", _entry(text), flags=re.M)]
+
+    text = compiled(described(placed))
+    assert not re.search(r"= " + weights, text), "a float32 weight in the program"
+    assert not re.search(r"= f32" + table, _entry(text))
+    assert table_ops(text) == ["parameter"], table_ops(text)
+    assert len(_custom_calls(text)) == cfg.num_layers
+
+    parent = compiled(described(given))         # what the check would see
+    assert re.search(r"= " + weights, parent)
+    assert "copy" in table_ops(parent), table_ops(parent)
+
+
 @pytest.mark.parametrize("program", ["decode-step", "prefill-chunk"])
 def test_latent_serving_programs_leave_the_page_pool_where_it_lies(one_chip, monkeypatch,
                                                                   program):
