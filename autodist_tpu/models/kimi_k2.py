@@ -27,7 +27,8 @@ space (``ops/paged_attention.py``). The two are the same mathematics.
 ``sigma = sigmoid(u W_g)`` over all ``n_routed_experts``; the
 ``num_experts_per_tok`` largest of ``sigma + b`` are chosen; their weights
 are the chosen ``sigma`` (without ``b``) over their sum, times
-``routed_scaling_factor``; ``FFN(u) = sum_chosen w_e E_e(u) + E_shared(u)``.
+``routed_scaling_factor``; ``FFN(u) = sum_chosen w_e E_e(u) + E_shared(u)``
+(``models/routed.py``, the layer Nemotron-H shares).
 No capacity, no token dropped. **A chip holds experts ``[first, first +
 count)``** (``experts_held``): it routes over all experts, normalises over
 all chosen, and adds the terms of the chosen experts it holds plus the
@@ -57,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from autodist_tpu.models import layers as L
-from autodist_tpu.ops import grouped_matmul as gm
+from autodist_tpu.models import routed
 from autodist_tpu.ops import paged_attention as pa_ops
 from autodist_tpu.serve import pages as serve_pages
 
@@ -106,6 +107,7 @@ class KimiK2Config:
     page_len: int = 128
     prefill_chunk: int = 512
     kv_quant: bool = False              # int8 latent pages: refused
+    expert_act: str = "silu_gated"      # models/routed.py EXPERT_ACTS
 
     @property
     def qk_head_dim(self) -> int:
@@ -279,51 +281,6 @@ def _attn_out(attn_p, x, o, cfg: KimiK2Config):
     return x + _dense(attn_p["wo"], o, cfg).astype(jnp.float32)
 
 
-def route(router_p, u, cfg: KimiK2Config):
-    """``u [T, D]`` (float32) -> ``(experts [T, K], weights [T, K])``: scores
-    in float32 at the highest precision, the ``K`` largest of ``sigma + b``,
-    weighted by ``sigma`` over their sum times the scaling factor."""
-    scores = jax.nn.sigmoid(jnp.matmul(
-        u.astype(jnp.float32), router_p["kernel"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, experts = jax.lax.top_k(
-        scores + router_p["bias"].astype(jnp.float32), cfg.num_experts_per_tok)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
-    if cfg.norm_topk_prob:
-        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
-    return experts.astype(jnp.int32), weights * cfg.routed_scaling_factor
-
-
-def _resolve(choice: str, off_chip: str) -> str:
-    """``auto`` is the Mosaic kernel on a TPU and plain ``jnp`` off it."""
-    if choice != "auto":
-        return choice
-    return "kernel" if jax.default_backend() == "tpu" else off_chip
-
-
-def expert_ffn(layer_p, u, cfg: KimiK2Config, live=None, impl: str = "auto"):
-    """The expert layer on ``u [T, D]`` (float32): the shared expert plus
-    the held experts' share of the routed sum. ``live [T]`` (bool) leaves
-    tokens that are padding out of the routing. ``impl`` is the grouped
-    product's: ``kernel``, ``reference``, or ``auto`` as the programs call
-    it. Returns ``(out [T, D] float32, pairs on held experts, held experts
-    hit)``."""
-    first, count = cfg.held
-    experts, weights = route(layer_p["router"], u, cfg)
-    if live is not None:
-        experts = jnp.where(live[:, None], experts, -1)
-    groups = gm.group_rows(experts, first, count)
-    impl = _resolve(impl, "reference")
-    e = layer_p["experts"]
-    rows = gm.gather_rows(u.astype(cfg.dtype), groups)
-    mid = (jax.nn.silu(gm.grouped_matmul(rows, e["gate"], groups, impl=impl))
-           * gm.grouped_matmul(rows, e["up"], groups, impl=impl))
-    routed = gm.combine_rows(
-        gm.grouped_matmul(mid, e["down"], groups, impl=impl), groups, weights)
-    shared = L.gated_mlp(layer_p["shared"], u, compute_dtype=cfg.dtype)
-    return routed + shared.astype(jnp.float32), groups.n_pairs, groups.n_hit
-
-
 def _ffn(layer_p, x, cfg: KimiK2Config, live=None):
     """``x [T, D]`` float32 residual -> ``(x + FFN(N2 x), pairs, hit)``."""
     u = _norm(layer_p["norm2"], x, cfg)
@@ -331,7 +288,7 @@ def _ffn(layer_p, x, cfg: KimiK2Config, live=None):
         zero = jnp.zeros((), jnp.int32)
         return x + L.gated_mlp(layer_p["mlp"], u, compute_dtype=cfg.dtype
                                ).astype(jnp.float32), zero, zero
-    out, pairs, hit = expert_ffn(layer_p, u, cfg, live)
+    out, pairs, hit = routed.expert_ffn(layer_p, u, cfg, live)
     return x + out, pairs, hit
 
 
@@ -509,7 +466,7 @@ def forward_paged_decode_step(params, tokens, positions, cache, page_tables,
         o_lat = pa_ops.mla_paged_decode_attention(
             q_lat, pools[i], page_tables, positions,
             value_width=cfg.kv_lora_rank, scale=cfg.softmax_scale,
-            impl=_resolve(cfg.paged_attention_impl, "gather"))
+            impl=routed.resolve(cfg.paged_attention_impl, "gather"))
         o = jnp.einsum("bhc,chd->bhd", o_lat, w_v,
                        preferred_element_type=jnp.float32).astype(cfg.dtype)
         x, p, e = _ffn(lp, _attn_out(lp["attn"], x, o, cfg), cfg, live)

@@ -29,7 +29,8 @@ recycle; liveness is a property, not a tuning outcome — the
 Metrics (through :mod:`autodist_tpu.metrics`' registry):
 ``serve_queue_depth`` / ``serve_active_slots`` /
 ``serve_page_pool_utilization`` / ``serve_page_fragmentation`` /
-``serve_param_bytes`` (the engine's placed parameter tree) gauges,
+``serve_param_bytes`` (the engine's placed parameter tree) and, for a
+model with per-slot state, ``serve_ssm_state_bytes`` gauges,
 ``serve_requests_{submitted,completed,timeout,rejected}_total`` counters,
 ``serve_tokens_generated_total`` / ``serve_decode_tokens_generated_total``
 counters (a rate is their increase over the reader's own interval), and
@@ -319,6 +320,9 @@ class ContinuousBatcher:
         # What only the device knows of a decode step (the engine's
         # ``fact_totals``, a model's ``step_facts``), lazily registered.
         self._m_facts = None
+        # Rows whose per-slot state the decode steps updated (the engine's
+        # ``ssm_rows``), lazily registered.
+        self._m_ssm_rows = None
 
         reg = registry or M.registry
         self._registry = reg
@@ -340,6 +344,10 @@ class ContinuousBatcher:
         # programs (a model's ``serving_params`` of the caller's, or it).
         reg.gauge("serve_param_bytes").set(
             float(getattr(engine, "param_bytes", 0)))
+        # ... and of the per-slot state placed beside the pool, where the
+        # model carries one.
+        if getattr(engine, "ssm_state_bytes", 0):
+            reg.gauge("serve_ssm_state_bytes").set(float(engine.ssm_state_bytes))
 
     # ---------------------------------------------------------------- clients
     def submit(
@@ -779,6 +787,7 @@ class ContinuousBatcher:
             self._update_ring_metrics(sp)
             self._update_kv_group_metrics()
             self._update_step_fact_metrics(sp)
+            self._update_ssm_metrics(sp)
             with self._lock:
                 self._m_active.set(len(self._active))
             self._m_pool_util.set(self.engine.page_utilization)
@@ -1094,6 +1103,20 @@ class ContinuousBatcher:
         for name, now in readings.items():
             self._m_facts[name].inc(now - self._m_facts[name].value)
             sp[name] = now
+
+    def _update_ssm_metrics(self, sp) -> None:
+        """Publish ``serve_ssm_rows_total``: rows whose per-slot state the
+        decode steps dispatched updated, from the engine's cumulative count
+        (a step's own rides its ``serve.decode_dispatch`` span as
+        ``ssm_rows``; the cumulative reading rides ``serve.tick_metrics``).
+        No-op for a model that carries no per-slot state."""
+        eng = self.engine
+        if not getattr(eng, "ssm_state_bytes", 0):
+            return
+        if self._m_ssm_rows is None:
+            self._m_ssm_rows = self._registry.counter("serve_ssm_rows_total")
+        self._m_ssm_rows.inc(eng.ssm_rows - self._m_ssm_rows.value)
+        sp["ssm_rows"] = eng.ssm_rows
 
     def _update_quant_metrics(self) -> None:
         """Publish the physical-vs-quantized pool byte split. No-op on fp

@@ -123,6 +123,21 @@ class DecodeModel:
     nothing), or a leaf held in the form its use reads. None places the
     caller's leaves as given.
 
+    ``slot_state``: ``slot_state(n_slots) -> tree of zeros``, what a model
+    carries for each slot beside its pages (a recurrent layer's state and
+    the tail of its convolution), a leaf per layer with the slot dim first.
+    The engine places it once and both programs take it and give it back
+    donated: ``prefill_chunk(..., samp=, *, state, slot)`` and
+    ``decode_paged(..., samp=, *, state)`` then return ``(tokens, cache,
+    state)``. A chunk writes its row's slot and starts from zeros where it
+    starts the prompt (a slot's state is never cleared on the host); a
+    decode step updates the rows it decodes and leaves every other row's
+    state as it was (position 0 tells them). The engine prices the state
+    before the pool takes its share of the headroom, and refuses prefix
+    sharing, int8 pages and speculative verification over it: sharing a
+    prefix would need the state at the prefix's end, and a rejected draft
+    would need the state rolled back (ROADMAP.md M4).
+
     ``autodist_tpu.models.transformer.decode_model(cfg)`` builds one for
     the zoo transformer; any model matching the contract serves the same
     way.
@@ -143,6 +158,7 @@ class DecodeModel:
     step_facts: Tuple[str, ...] = ()
     steps_fact: Optional[str] = None
     serving_params: Optional[Callable[[Any], Any]] = None
+    slot_state: Optional[Callable[[int], Any]] = None
 
 
 def tree_bytes(tree: Any) -> int:
@@ -334,6 +350,21 @@ class InferenceEngine:
                 "int8 pages over a latent pool: a latent row is shared by "
                 "every head and no scale plane is defined for it "
                 "(ROADMAP.md Queue 2)")
+        # Per-slot state (a recurrent layer's): priced here, placed once
+        # beside the pool; what it cannot carry is refused at build.
+        self.ssm_state_bytes = 0
+        if decode_model.slot_state is not None:
+            if prefix_cache:
+                raise serve_pages.CacheFeatureRefused(
+                    "prefix sharing over per-slot recurrent state: a "
+                    "shared prefix would need the state at the prefix's "
+                    "end (ROADMAP.md M4)")
+            if self.kv_quant:
+                raise serve_pages.CacheFeatureRefused(
+                    "int8 pages beside per-slot recurrent state: no scale "
+                    "plane is defined for them (ROADMAP.md M4)")
+            self.ssm_state_bytes = tree_bytes(jax.eval_shape(
+                lambda: decode_model.slot_state(self.n_slots)))
         if self.kv_quant:
             fp_itemsize = np.dtype(jax.tree_util.tree_leaves(jax.eval_shape(
                 lambda: decode_model.init_paged_cache(
@@ -356,6 +387,7 @@ class InferenceEngine:
             if resource_spec is not None:
                 n_pages = serve_pages.pool_size_from_spec(
                     resource_spec, page_bytes, params_bytes=self.param_bytes,
+                    state_bytes=self.ssm_state_bytes / self._data_degree,
                     serve_frac=serve_hbm_frac,
                     shard_degree=self._data_degree,
                     max_useful_pages=max_useful,
@@ -375,6 +407,14 @@ class InferenceEngine:
         self._cache = jax.device_put(
             decode_model.init_paged_cache(n_pages, self.page_len),
             self._cache_sh)
+        self._state = self._state_sh = None
+        if decode_model.slot_state is not None:
+            self._state_sh = self._slot_shardings(decode_model.slot_state)
+            self._state = jax.jit(lambda: decode_model.slot_state(n_slots),
+                                  out_shardings=self._state_sh)()
+            logging.info("per-slot state placed: %d bytes beside %d bytes "
+                         "of parameters", self.ssm_state_bytes,
+                         self.param_bytes)
         # Copy-on-write prefix sharing (serve/prefix.py): pass True to
         # build the refcounted radix cache over this engine's pool, or an
         # already-built PrefixCache (the spec engine hands one spanning
@@ -445,6 +485,10 @@ class InferenceEngine:
         # batcher publishes them (serve_kv_groups_total, ..._live_total).
         self.kv_groups = 0
         self.kv_groups_live = 0
+        # Over per-slot state: rows whose state the decode steps dispatched
+        # updated (the decoding rows; the rest are skipped). Cumulative;
+        # the batcher publishes it (serve_ssm_rows_total).
+        self.ssm_rows = 0
         # What only the device knows of a step (``DecodeModel.step_facts``):
         # summed over the decode steps fetched (``fact_steps`` of them), for
         # the batcher to publish; a chunk's facts stay on its span. A chunk
@@ -577,6 +621,15 @@ class InferenceEngine:
 
         return jax.tree_util.tree_map(leaf_sh, shaped)
 
+    def _slot_shardings(self, init_state):
+        """Per-slot state: the slot dim (dim 0) over the data axis, as the
+        decode rows are."""
+        from autodist_tpu.kernel.mesh import data_sharding
+
+        shaped = jax.eval_shape(lambda: init_state(self.n_slots))
+        return jax.tree_util.tree_map(
+            lambda leaf: data_sharding(self.mesh, len(leaf.shape), dim=0), shaped)
+
     def _compile(self) -> None:
         dm = self.decode_model
         # Donate the cache: both programs rewrite the page pool in place on
@@ -590,22 +643,29 @@ class InferenceEngine:
         # Named functions: the device trace's module line reads
         # jit_serve_prefill_chunk / jit_serve_decode_step, which is how the
         # benchmark's readers tell the two programs apart.
-        def serve_prefill_chunk(p, tokens, start, length, cache, table, samp):
+        # A model that carries per-slot state hands it to both programs
+        # (and the chunk its row's slot), donated beside the pool; ``state``
+        # is empty for one that carries none.
+        def serve_prefill_chunk(p, tokens, start, length, cache, table, samp,
+                                *state):
             return dm.prefill_chunk(
                 self.plan.unpad_params(p), tokens, start, length, cache,
-                table, samp=samp)
+                table, samp=samp, **dict(zip(("state", "slot"), state)))
 
-        def serve_decode_step(p, tokens, positions, cache, tables, samp):
+        def serve_decode_step(p, tokens, positions, cache, tables, samp,
+                              *state):
             return dm.decode_paged(
                 self.plan.unpad_params(p), tokens, positions, cache, tables,
-                samp=samp)
+                samp=samp, **dict(zip(("state",), state)))
 
+        stateful = self._state is not None
+        out_sh = (token_sh, self._cache_sh) + ((self._state_sh,) if stateful else ())
         self._prefill_fn = jax.jit(
-            serve_prefill_chunk, donate_argnums=(4,),
-            out_shardings=(token_sh, self._cache_sh))
+            serve_prefill_chunk, donate_argnums=(4, 7)[:1 + stateful],
+            out_shardings=out_sh)
         self._decode_fn = jax.jit(
-            serve_decode_step, donate_argnums=(3,),
-            out_shardings=(token_sh, self._cache_sh))
+            serve_decode_step, donate_argnums=(3, 6)[:1 + stateful],
+            out_shardings=out_sh)
 
     @property
     def compiled_programs(self) -> int:
@@ -983,10 +1043,12 @@ class InferenceEngine:
             chunk = np.zeros((1, c), np.int32)
             valid = prompt[start:start + c]
             chunk[0, : len(valid)] = valid
-            first, self._cache = self._prefill_fn(
-                self.params, jnp.asarray(chunk), np.int32(start),
-                np.int32(len(prompt)), self._cache,
-                jnp.asarray(self._table_np[idx]), self._samp_dev(idx))
+            args = (self.params, jnp.asarray(chunk), np.int32(start),
+                    np.int32(len(prompt)), self._cache,
+                    jnp.asarray(self._table_np[idx]), self._samp_dev(idx))
+            state = () if self._state is None else (self._state, np.int32(idx))
+            first, self._cache, *state = self._prefill_fn(*args, *state)
+            self._state = state[0] if state else None
         if self.layout.window:
             rolls, chunks = self.layout.rolls(
                 start, min(start + c, len(prompt)))
@@ -1108,13 +1170,16 @@ class InferenceEngine:
             # is the host's to cure, under the second it is not idle.
             with obs_spans.span("serve.decode_dispatch") as sp:
                 self._count_kv_groups(sp, self._lengths + 1, 1)
-                tokens, self._cache = self._decode_fn(
-                    self.params,
-                    jnp.asarray(self._last_token),
-                    jnp.asarray(self._lengths),
-                    self._cache,
-                    jnp.asarray(self._decode_table_np),
-                    self._samp_dev())
+                args = (self.params, jnp.asarray(self._last_token),
+                        jnp.asarray(self._lengths), self._cache,
+                        jnp.asarray(self._decode_table_np), self._samp_dev())
+                state = () if self._state is None else (self._state,)
+                if state:
+                    # the rows whose state the step updates: the decoding
+                    sp["ssm_rows"] = int(len(decoding))
+                    self.ssm_rows += sp["ssm_rows"]
+                tokens, self._cache, *state = self._decode_fn(*args, *state)
+                self._state = state[0] if state else None
             # the chunks that went out before this step have run by the
             # time its tokens arrive; those about to go out behind it have not
             ran, self._facts_pending = self._facts_pending, []

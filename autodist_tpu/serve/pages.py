@@ -55,8 +55,9 @@ def pages_for_tokens(n_tokens: int, page_len: int) -> int:
 class CacheFeatureRefused(ValueError):
     """A feature of the serving stack was asked for over a cache that
     cannot carry it (prefix sharing, int8 pages or speculative verification
-    over a window ring; int8 pages or speculative verification over latent
-    pages). Raised when the engine is built, never under load."""
+    over a window ring or beside per-slot recurrent state; int8 pages or
+    speculative verification over latent pages). Raised when the engine is
+    built, never under load."""
 
 
 @dataclass(frozen=True)
@@ -394,6 +395,7 @@ def pool_size_from_spec(
     resource_spec,
     bytes_per_page: float,
     params_bytes: float = 0.0,
+    state_bytes: float = 0.0,
     headroom: float = 0.8,
     serve_frac: float = 0.5,
     shard_degree: int = 1,
@@ -413,6 +415,10 @@ def pool_size_from_spec(
     could hold replicated (``params_bytes`` stays the conservative full
     logical size: exact for replicated-param serving, an under-estimate
     of headroom for model-parallel plans — never an overcommit).
+    ``state_bytes`` is what a chip holds of the model's per-slot state (a
+    recurrent layer's, beside the pages): placed before the pool, it is
+    taken off the headroom with the parameters, so that pool and state
+    never overcommit the chip together.
     ``max_useful_pages`` caps at the point more pages cannot help (every
     decode row at the full ``max_len`` timeline); ``min_useful_pages``
     floors at a functioning pool — an overcommit is the analyzer's SLM
@@ -429,7 +435,8 @@ def pool_size_from_spec(
     prefix cache is attached.
     """
     capacity = float(resource_spec.tpu.hbm_bytes) if resource_spec else 0.0
-    budget = max(0.0, capacity * headroom - float(params_bytes)) * serve_frac
+    budget = max(0.0, capacity * headroom - float(params_bytes)
+                 - float(state_bytes)) * serve_frac
     budget *= max(int(shard_degree), 1)
     n = int(budget // max(float(bytes_per_page), 1.0))
     if max_useful_pages is not None:

@@ -161,6 +161,11 @@ class SpecDecodeEngine(InferenceEngine):
                     "speculative verification over a latent pool: no "
                     "program verifies several positions a row against "
                     "latent pages (ROADMAP.md Queue 2)")
+            if dm is not None and dm.slot_state is not None:
+                raise serve_pages.CacheFeatureRefused(
+                    "speculative verification over per-slot recurrent "
+                    "state: a rejected draft would have to roll the state "
+                    "back (ROADMAP.md M4)")
         super().__init__(params, plan, apply_fn=apply_fn,
                          decode_model=decode_model, **engine_kwargs)
         if decode_model is None or decode_model.verify_paged is None:

@@ -14,6 +14,7 @@ import pytest
 from autodist_tpu import metrics as M
 from autodist_tpu.api import AutoDist
 from autodist_tpu.models import kimi_k2 as K
+from autodist_tpu.models import routed
 from autodist_tpu.obs import spans as obs_spans
 from autodist_tpu.ops import grouped_matmul as GM
 from autodist_tpu.ops import paged_attention as PA
@@ -193,7 +194,7 @@ def test_the_grouped_product_against_plain_jnp(tokens, k, first, count, tile):
 # ------------------------------------------------------------------ routing
 def _dense_experts(layer, u, cfg, first, count):
     """A per-token loop: every chosen expert in ``[first, first + count)``."""
-    experts, weights = (np.asarray(a) for a in K.route(layer["router"], u, cfg))
+    experts, weights = (np.asarray(a) for a in routed.route(layer["router"], u, cfg))
     e = jax.tree.map(np.asarray, layer["experts"])
     out = np.zeros(u.shape, np.float32)
     for t in range(u.shape[0]):
@@ -218,7 +219,7 @@ def test_routing_against_a_per_token_loop_and_nothing_dropped_under_skew():
                                     kernel=layer["router"]["kernel"] + 0.0 * push))
     u = u.at[:12].add(3.0 * layer["router"]["kernel"][:, 5] /
                       jnp.linalg.norm(layer["router"]["kernel"][:, 5]))
-    experts, weights = K.route(layer["router"], u, cfg)
+    experts, weights = routed.route(layer["router"], u, cfg)
     sigma = jax.nn.sigmoid(u @ layer["router"]["kernel"])
     for t in range(24):
         chosen = np.argsort(-np.asarray(sigma[t] + layer["router"]["bias"]))[:4]
@@ -226,18 +227,18 @@ def test_routing_against_a_per_token_loop_and_nothing_dropped_under_skew():
         w = np.asarray(sigma[t])[np.asarray(experts[t])]
         np.testing.assert_allclose(weights[t], w / w.sum() * 2.827, rtol=1e-5)
     assert int((experts[:12] == 5).any(-1).sum()) == 12, "the skew holds"
-    out, pairs, hit = K.expert_ffn(layer, u, cfg)
+    out, pairs, hit = routed.expert_ffn(layer, u, cfg)
     shared = K.L.gated_mlp(layer["shared"], u)
     np.testing.assert_allclose(out - shared, _dense_experts(layer, u, cfg, 4, 4),
                                atol=1e-4)
     # the Mosaic kernel (interpreted here) in the layer's place
-    np.testing.assert_allclose(K.expert_ffn(layer, u, cfg, impl="kernel")[0],
+    np.testing.assert_allclose(routed.expert_ffn(layer, u, cfg, impl="kernel")[0],
                                out, atol=1e-4)
     held = (experts >= 4) & (experts < 8)
     assert int(pairs) == int(held.sum()) and int(pairs) >= 12
     assert int(hit) == len(set(np.asarray(experts)[np.asarray(held)].tolist()))
     # a token that is padding chooses no expert
-    _, fewer, _ = K.expert_ffn(layer, u, cfg, live=jnp.arange(24) >= 12)
+    _, fewer, _ = routed.expert_ffn(layer, u, cfg, live=jnp.arange(24) >= 12)
     assert int(fewer) == int(held[12:].sum())
 
 
@@ -248,7 +249,7 @@ def test_the_shares_add_up():
     params = _params(whole, seed=11)
     layer = params["layers_2"]
     u = jax.random.normal(jax.random.PRNGKey(2), (20, 32))
-    uncut, pairs, hit = K.expert_ffn(layer, u, whole)
+    uncut, pairs, hit = routed.expert_ffn(layer, u, whole)
     assert int(pairs) == 20 * 4 and int(hit) <= 16
     shared = K.L.gated_mlp(layer["shared"], u)
     total = shared
@@ -256,7 +257,7 @@ def test_the_shares_add_up():
         cut = dataclasses.replace(whole, experts_held=(first, 4))
         part = dict(layer, experts=jax.tree.map(
             lambda w: w[first:first + 4], layer["experts"]))
-        out, n, _ = K.expert_ffn(part, u, cut)
+        out, n, _ = routed.expert_ffn(part, u, cut)
         total = total + (out - shared)
         assert 0 < int(n) < 80
     np.testing.assert_allclose(total, uncut, atol=1e-4)

@@ -449,6 +449,83 @@ def test_derived_flash_tiles_compile_for_the_chip(one_chip, shape, dtype):
                          tile, s, d, jnp.dtype(dtype).itemsize) <= F._VMEM_BYTES
 
 
+@pytest.mark.parametrize("program", ["decode-step", "prefill-chunk"])
+def test_recurrent_state_stays_where_it_lies_in_the_serving_programs(one_chip, monkeypatch,
+                                                                     program):
+    """Nemotron-H's published widths (a Mamba, an attention and an expert
+    layer), 16 rows, the cache and the per-slot state donated, the real
+    Mosaic calls. A Mamba layer's state leaf ``[rows, 64, 64, 128]`` float32
+    is a parameter and is updated in place: by the call named
+    ``ssm_state_update``, whose output is aliased onto it (decode), or by a
+    dynamic-update-slice of the chunk's row (a chunk). The compiled
+    program holds no copy or other fusion of a leaf's size; the decode
+    program holds one state call a Mamba layer, one paged call an attention
+    layer and two grouped products an expert layer (relu², no gate), and
+    no copy of the experts' weights as ``serving_params`` holds them."""
+    from autodist_tpu.models import nemotron_h as N
+    from autodist_tpu.models import routed
+    from autodist_tpu.ops import grouped_matmul as GM
+    from autodist_tpu.ops import paged_attention as PA
+    from autodist_tpu.ops import ssm as SSM
+
+    for mod in (PA, GM, SSM):
+        monkeypatch.setattr(mod, "_should_interpret", lambda: False)
+    monkeypatch.setattr(routed, "resolve", lambda choice, off_chip: "kernel")  # as on the chip
+    pages, rows, table = 129, 16, 16
+    cfg = N.NemotronHConfig(vocab_size=16384, hybrid_override_pattern="M*E",
+                            experts_held=(0, 16), max_position_embeddings=2048)
+
+    def described(tree, dtype=None):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype or x.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = described(jax.eval_shape(lambda: N.serving_params(
+        N.init_params(jax.random.PRNGKey(0), cfg), cfg)), jnp.bfloat16)
+    cache = described(jax.eval_shape(lambda: N.init_paged_cache(cfg, pages, cfg.page_len)))
+    state = described(jax.eval_shape(lambda: N.init_slot_state(cfg, rows)))
+
+    def serve_decode_step(params, tokens, positions, cache, tables, state):
+        return N.forward_paged_decode_step(params, tokens, positions, cache, tables,
+                                           cfg, state=state)
+
+    def serve_prefill_chunk(params, tokens, start, length, cache, table_, state, slot):
+        return N.forward_paged_prefill_chunk(params, tokens, start, length, cache,
+                                             table_, cfg, state=state, slot=slot)
+
+    fn, args, donated = {
+        "decode-step": (serve_decode_step,
+                        (params, i32(rows), i32(rows), cache, i32(rows, table), state),
+                        (3, 5)),
+        "prefill-chunk": (serve_prefill_chunk,
+                          (params, i32(1, cfg.prefill_chunk), i32(), i32(), cache,
+                           i32(table), state, i32()), (4, 6)),
+    }[program]
+    text = analysis.compiled_text(jax.jit(fn, donate_argnums=donated), *args)
+
+    calls = sorted(c.split(".")[0] for c in _custom_calls(text))
+    own = ["paged_attention", "ssm_state_update"] if program == "decode-step" \
+        else ["paged_attention"]
+    assert calls == ["gmm"] * 2 + own, calls
+    found = _leaf_sized(text, f"f32[{rows},64,64,128]")
+    in_place = {"parameter", "tuple", "get-tuple-element", "bitcast",
+                "dynamic-update-slice"}
+    for op, line in found:
+        if op == "fusion":
+            assert "dynamic-update-slice" in line or "dynamic_update_slice" in line, \
+                f"a fusion of the state's size, no write of a row: {line[:300]}"
+        elif op == "custom-call":
+            assert "ssm_state_update" in line, line[:300]
+        else:
+            assert op in in_place, f"{op} of the state's size: {line[:300]}"
+    assert sum(op == "custom-call" for op, _ in found) == (program == "decode-step")
+    # the held experts as the served tree holds them (width 1,856 padded to
+    # 1,920) are read where they lie, not re-laid out a run
+    assert not [op for op, _ in _leaf_sized(text, "bf16[16,2688,1920]") if op == "copy"]
+
+
 @pytest.mark.parametrize("rows, queries", [(4, 1), (1, 1024)],
                          ids=["decode-step", "prefill-chunk"])
 def test_eva_kernel_compiles_for_the_chip_at_published_widths(one_chip, rows, queries):
@@ -706,12 +783,13 @@ def test_latent_serving_programs_leave_the_page_pool_where_it_lies(one_chip, mon
     program holds one latent call a layer and three grouped products an
     expert layer, by name."""
     from autodist_tpu.models import kimi_k2 as K
+    from autodist_tpu.models import routed
     from autodist_tpu.ops import grouped_matmul as GM
     from autodist_tpu.ops import paged_attention as PA
 
     monkeypatch.setattr(PA, "_should_interpret", lambda: False)
     monkeypatch.setattr(GM, "_should_interpret", lambda: False)
-    monkeypatch.setattr(K, "_resolve", lambda choice, off_chip: "kernel")   # as on the chip
+    monkeypatch.setattr(routed, "resolve", lambda choice, off_chip: "kernel")   # as on the chip
     pages, rows, table = 301, 32, 64
     cfg = K.KimiK2Config(vocab_size=20480, num_hidden_layers=2, experts_held=(0, 12),
                          max_position_embeddings=8192)

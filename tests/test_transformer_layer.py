@@ -86,7 +86,7 @@ def test_one_serving_engine_and_a_paged_adapter_only():
     fields = {f.name for f in dataclasses.fields(DecodeModel)}
     assert fields == {"init_paged_cache", "prefill_chunk", "decode_paged",
                       "verify_paged", "eos_id", "max_len", "cache_layout",
-                      "step_facts", "steps_fact", "serving_params"}
+                      "step_facts", "steps_fact", "serving_params", "slot_state"}
     assert not fields & {"init_cache", "prefill", "decode_step"}
     gone = "Bucketed" + "InferenceEngine"
     assert not hasattr(serve, gone) and gone not in serve.__all__
