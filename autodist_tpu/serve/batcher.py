@@ -323,6 +323,9 @@ class ContinuousBatcher:
         # Rows whose per-slot state the decode steps updated (the engine's
         # ``ssm_rows``), lazily registered.
         self._m_ssm_rows = None
+        # Arrays put on the device for the programs' inputs (the engine's
+        # ``input_puts``), lazily registered.
+        self._m_input_puts = None
 
         reg = registry or M.registry
         self._registry = reg
@@ -788,6 +791,7 @@ class ContinuousBatcher:
             self._update_kv_group_metrics()
             self._update_step_fact_metrics(sp)
             self._update_ssm_metrics(sp)
+            self._update_input_put_metrics(sp)
             with self._lock:
                 self._m_active.set(len(self._active))
             self._m_pool_util.set(self.engine.page_utilization)
@@ -1117,6 +1121,22 @@ class ContinuousBatcher:
             self._m_ssm_rows = self._registry.counter("serve_ssm_rows_total")
         self._m_ssm_rows.inc(eng.ssm_rows - self._m_ssm_rows.value)
         sp["ssm_rows"] = eng.ssm_rows
+
+    def _update_input_put_metrics(self, sp) -> None:
+        """Publish ``serve_input_puts_total``: arrays the engine put on the
+        device for its programs' inputs, from its cumulative count (a
+        call's own rides its ``serve.decode_dispatch`` /
+        ``serve.prefill_chunk`` span as ``puts``; the cumulative reading
+        rides ``serve.tick_metrics`` as ``input_puts``). No-op for an
+        engine that keeps no such count."""
+        puts = getattr(self.engine, "input_puts", None)
+        if puts is None:
+            return
+        if self._m_input_puts is None:
+            self._m_input_puts = self._registry.counter(
+                "serve_input_puts_total")
+        self._m_input_puts.inc(puts - self._m_input_puts.value)
+        sp["input_puts"] = puts
 
     def _update_quant_metrics(self) -> None:
         """Publish the physical-vs-quantized pool byte split. No-op on fp

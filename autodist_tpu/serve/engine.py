@@ -53,6 +53,8 @@ from autodist_tpu.utils import logging
 
 #: Slot phases (host bookkeeping; single scheduler-thread writer).
 _FREE, _PREFILL, _DECODE = 0, 1, 2
+#: The per-slot sampling arrays, in the order the programs take them.
+_SAMP_KEYS = ("temperature", "top_k", "top_p", "key_hi", "key_lo")
 
 
 class EngineDeadError(RuntimeError):
@@ -463,6 +465,30 @@ class InferenceEngine:
         # compiled programs as traced per-slot ARRAYS, so per-request
         # params never recompile anything and the program pins hold.
         self._samp = serve_sampling.slot_arrays(n_slots)
+        # The host mirrors the programs read, by name: the decode step's
+        # tokens, lengths, tables and sampling arrays, and (a row of them)
+        # a chunk's table and sampling rows. They stay the truth the
+        # batcher, the spec engine, the prefix tree and the tests read.
+        self._mirrors = {
+            "tokens": (self._last_token,), "lengths": (self._lengths,),
+            "tables": (self._decode_table_np,), "table": (self._table_np,),
+            "samp": tuple(self._samp[k] for k in _SAMP_KEYS)}
+        # Their device copies between calls, keyed ``(name, None)`` for the
+        # decode batch's and ``(name, row)`` for a row's chunk inputs. An
+        # absent key is stale: the next call that reads it puts it again,
+        # whole, from its mirror (``_inputs``). ``_set_row`` is the one
+        # writer of the mirrors and drops what a changed value makes
+        # stale; the decode step hands back the next tokens and lengths
+        # itself, so a tick with no admission, prefill completion or
+        # release puts nothing.
+        self._dev: Dict[Tuple[str, Optional[int]], Any] = {}
+        self._in_sh = NamedSharding(self.mesh, P())
+        # Arrays put on the device for the programs' inputs (a chunk's
+        # tokens among them). Cumulative; the batcher publishes it
+        # (serve_input_puts_total); a call's own rides its
+        # ``serve.decode_dispatch`` / ``serve.prefill_chunk`` span as
+        # ``puts``.
+        self.input_puts = 0
         self._prefill_fn = None
         self._decode_fn = None
         self._decode_step_count = 0
@@ -637,35 +663,49 @@ class InferenceEngine:
         # OUTPUT sharding is pinned to the canonical pool sharding: left to
         # GSPMD's choice it can drift between programs, and a
         # differently-sharded cache argument would silently compile a third
-        # serving program (the exactly-2 acceptance pin).
-        token_sh = NamedSharding(self.mesh, P())
+        # serving program (the exactly-2 acceptance pin). The small inputs
+        # are put replicated (``_in_sh``) and the decode step's next tokens
+        # and lengths come back the same way, so a put copy and a handed-back
+        # one are the same argument to the compiled program.
+        token_sh = self._in_sh
+        n_slots = self.n_slots
 
         # Named functions: the device trace's module line reads
         # jit_serve_prefill_chunk / jit_serve_decode_step, which is how the
         # benchmark's readers tell the two programs apart.
         # A model that carries per-slot state hands it to both programs
         # (and the chunk its row's slot), donated beside the pool; ``state``
-        # is empty for one that carries none.
-        def serve_prefill_chunk(p, tokens, start, length, cache, table, samp,
-                                *state):
+        # is empty for one that carries none. A chunk's one int32 operand
+        # is its tokens followed by ``start``, the prompt's length and the
+        # row (``_dispatch_chunk``).
+        def serve_prefill_chunk(p, chunk, cache, table, samp, *state):
+            c = chunk.shape[0] - 3
+            slot = dict(state=state[0], slot=chunk[c + 2]) if state else {}
             return dm.prefill_chunk(
-                self.plan.unpad_params(p), tokens, start, length, cache,
-                table, samp=samp, **dict(zip(("state", "slot"), state)))
+                self.plan.unpad_params(p), chunk[None, :c], chunk[c],
+                chunk[c + 1], cache, table, samp=samp, **slot)
 
+        # Beside what the model returns, the next step's inputs: each
+        # decoding row's token and its length + 1. A row that is not
+        # decoding carries length 0 and keeps both as they were.
         def serve_decode_step(p, tokens, positions, cache, tables, samp,
                               *state):
-            return dm.decode_paged(
+            out, *rest = dm.decode_paged(
                 self.plan.unpad_params(p), tokens, positions, cache, tables,
                 samp=samp, **dict(zip(("state",), state)))
+            decoding = positions > 0
+            return (out, *rest,
+                    jnp.where(decoding, out[:n_slots], tokens),
+                    jnp.where(decoding, positions + 1, positions))
 
         stateful = self._state is not None
         out_sh = (token_sh, self._cache_sh) + ((self._state_sh,) if stateful else ())
         self._prefill_fn = jax.jit(
-            serve_prefill_chunk, donate_argnums=(4, 7)[:1 + stateful],
+            serve_prefill_chunk, donate_argnums=(2, 5)[:1 + stateful],
             out_shardings=out_sh)
         self._decode_fn = jax.jit(
             serve_decode_step, donate_argnums=(3, 6)[:1 + stateful],
-            out_shardings=out_sh)
+            out_shardings=out_sh + (token_sh, token_sh))
 
     @property
     def compiled_programs(self) -> int:
@@ -838,15 +878,48 @@ class InferenceEngine:
                 f"{max_new_tokens})", retryable=False)
         return None
 
-    def _samp_dev(self, idx: Optional[int] = None):
-        """The per-slot sampling arrays as the device 5-tuple the compiled
-        programs consume — one row for a prefill call, the full batch for
-        decode/verify. Always passed (greedy rows are temperature 0), so
-        sampling params never change a program's signature."""
-        s = self._samp
-        pick = (lambda a: a) if idx is None else (lambda a: a[idx:idx + 1])
-        return tuple(jnp.asarray(pick(s[k])) for k in
-                     ("temperature", "top_k", "top_p", "key_hi", "key_lo"))
+    def _set_row(self, idx: int, **values) -> None:
+        """Write row ``idx`` of the host mirrors the programs read, by
+        name (``tokens``, ``lengths``, ``tables``: the row of the decode
+        batch's; ``table``: the row's chunk table; ``samp``: its five
+        sampling values in ``_SAMP_KEYS`` order), and drop the device
+        copies that a changed value makes stale: the batch's and the
+        row's own."""
+        for name, value in values.items():
+            changed = False
+            for mirror, v in zip(self._mirrors[name],
+                                 value if name == "samp" else (value,)):
+                old = mirror[idx].copy()
+                mirror[idx] = v
+                changed |= not np.array_equal(old, mirror[idx])
+            if changed:
+                self._dev.pop((name, None), None)
+                self._dev.pop((name, idx), None)
+
+    def _mirror(self, key: Tuple[str, Optional[int]]):
+        """What the device copy ``key`` is put from: the decode batch's
+        arrays, or one row of them as a chunk takes it (the table's row
+        ``[P]``, the sampling rows ``[1]``); a tuple for ``samp``. Copies:
+        on a host platform a put may alias the array it is given, and the
+        mirrors change under the copies."""
+        name, idx = key
+        pick = (slice(None) if idx is None else idx if name == "table"
+                else slice(idx, idx + 1))
+        rows = tuple(np.array(a[pick]) for a in self._mirrors[name])
+        return rows if name == "samp" else rows[0]
+
+    def _inputs(self, sp, *keys):
+        """The device copies of ``keys``, those that are stale put again
+        from their mirrors in one transfer; adds the arrays put to the
+        span's ``puts`` and to :attr:`input_puts`."""
+        stale = [k for k in keys if k not in self._dev]
+        if stale:
+            hosts = [self._mirror(k) for k in stale]
+            self._dev.update(zip(stale, jax.device_put(hosts, self._in_sh)))
+            n = len(jax.tree_util.tree_leaves(hosts))
+            sp["puts"] += n
+            self.input_puts += n
+        return [self._dev[k] for k in keys]
 
     def admit(self, prompt: np.ndarray, max_new_tokens: int,
               request_id: str = "",
@@ -924,23 +997,18 @@ class InferenceEngine:
         idx = int(free[0])
         self._phase[idx] = _PREFILL
         self._tables[idx] = table
-        self._table_np[idx] = table.padded(self.max_pages)
-        self._decode_table_np[idx] = serve_pages.SCRATCH_PAGE
-        self._lengths[idx] = 0
-        self._last_token[idx] = 0
+        sp = sampling or serve_sampling.SamplingParams()
+        request_id = str(request_id or "")
+        hi, lo = serve_sampling.request_key(request_id, sp.seed)
+        self._set_row(idx, table=table.padded(self.max_pages),
+                      tables=serve_pages.SCRATCH_PAGE, lengths=0, tokens=0,
+                      samp=(sp.temperature, sp.top_k, sp.top_p, hi, lo))
         self._prompts[idx] = prompt
-        self._request_ids[idx] = str(request_id or "")
+        self._request_ids[idx] = request_id
         self._prefill_pos[idx] = start_pos
         self._prefill_start[idx] = start_pos
         self._leases[idx] = lease
         self._cached[idx] = start_pos > 0
-        sp = sampling or serve_sampling.SamplingParams()
-        hi, lo = serve_sampling.request_key(self._request_ids[idx], sp.seed)
-        self._samp["temperature"][idx] = sp.temperature
-        self._samp["top_k"][idx] = sp.top_k
-        self._samp["top_p"][idx] = sp.top_p
-        self._samp["key_hi"][idx] = hi
-        self._samp["key_lo"][idx] = lo
         self._prefill_t0[idx] = time.perf_counter()
         # Flight-record the admit (non-critical: batched fsync — serve load
         # must not turn into an fsync storm). Rate is bounded by request
@@ -1038,16 +1106,19 @@ class InferenceEngine:
         final = start + c >= len(prompt)
         with obs_spans.span("serve.prefill_chunk", start=start,
                             prompt_len=len(prompt), final=final,
-                            request_id=self._request_ids[idx]) as sp:
+                            request_id=self._request_ids[idx], puts=1) as sp:
             self._count_kv_groups(sp, [start + c], c)
-            chunk = np.zeros((1, c), np.int32)
+            # the one put of a chunk whose row's inputs are on the device
             valid = prompt[start:start + c]
-            chunk[0, : len(valid)] = valid
-            args = (self.params, jnp.asarray(chunk), np.int32(start),
-                    np.int32(len(prompt)), self._cache,
-                    jnp.asarray(self._table_np[idx]), self._samp_dev(idx))
-            state = () if self._state is None else (self._state, np.int32(idx))
-            first, self._cache, *state = self._prefill_fn(*args, *state)
+            chunk = np.zeros(c + 3, np.int32)
+            chunk[: len(valid)] = valid
+            chunk[c:] = start, len(prompt), idx
+            self.input_puts += 1
+            table, samp = self._inputs(sp, ("table", idx), ("samp", idx))
+            state = () if self._state is None else (self._state,)
+            first, self._cache, *state = self._prefill_fn(
+                self.params, jax.device_put(chunk, self._in_sh), self._cache,
+                table, samp, *state)
             self._state = state[0] if state else None
         if self.layout.window:
             rolls, chunks = self.layout.rolls(
@@ -1115,9 +1186,8 @@ class InferenceEngine:
         with obs_spans.span("serve.token_fetch", program="prefill_chunk"):
             first = int(self._fetch(first, chunk_sp)[0])
         self._phase[idx] = _DECODE
-        self._lengths[idx] = len(prompt)
-        self._last_token[idx] = first
-        self._decode_table_np[idx] = self._table_np[idx]
+        self._set_row(idx, lengths=len(prompt), tokens=first,
+                      tables=self._table_np[idx])
         if self._leases[idx] is not None:
             # Adopt this prompt's novel full blocks into the prefix tree:
             # the NEXT admission sharing them becomes a page-table copy.
@@ -1165,20 +1235,22 @@ class InferenceEngine:
         self.decode_invocations += 1
         with obs_spans.span("serve.decode_step", active=int(len(decoding)),
                             request_ids=rids) as step_sp:
-            # The host's part (the per-tick puts and the call) apart from
-            # the wait for the device: a device left idle under the first
-            # is the host's to cure, under the second it is not idle.
-            with obs_spans.span("serve.decode_dispatch") as sp:
+            # The host's part (the puts of stale inputs and the call) apart
+            # from the wait for the device: a device left idle under the
+            # first is the host's to cure, under the second it is not idle.
+            with obs_spans.span("serve.decode_dispatch", puts=0) as sp:
                 self._count_kv_groups(sp, self._lengths + 1, 1)
-                args = (self.params, jnp.asarray(self._last_token),
-                        jnp.asarray(self._lengths), self._cache,
-                        jnp.asarray(self._decode_table_np), self._samp_dev())
+                last, lengths, tables, samp = self._inputs(
+                    sp, ("tokens", None), ("lengths", None),
+                    ("tables", None), ("samp", None))
                 state = () if self._state is None else (self._state,)
                 if state:
                     # the rows whose state the step updates: the decoding
                     sp["ssm_rows"] = int(len(decoding))
                     self.ssm_rows += sp["ssm_rows"]
-                tokens, self._cache, *state = self._decode_fn(*args, *state)
+                tokens, self._cache, *state, last, lengths = self._decode_fn(
+                    self.params, last, lengths, self._cache, tables, samp,
+                    *state)
                 self._state = state[0] if state else None
             # the chunks that went out before this step have run by the
             # time its tokens arrive; those about to go out behind it have not
@@ -1198,6 +1270,9 @@ class InferenceEngine:
             self._lengths[idx] += 1
             self._last_token[idx] = tokens[idx]
             out[Slot(idx)] = int(tokens[idx])
+        # the step's own next inputs, equal to the mirrors just advanced
+        self._dev[("tokens", None)] = last
+        self._dev[("lengths", None)] = lengths
         # Sampled flight record (1 per 64 decode rounds): enough black-box
         # trail to show "serving was alive and at depth N" in a postmortem
         # without a per-token write amplifying the hot loop.
@@ -1247,19 +1322,13 @@ class InferenceEngine:
         self._prefill_start[idx] = 0
         self._tables[idx] = None
         self._phase[idx] = _FREE
-        self._table_np[idx] = serve_pages.SCRATCH_PAGE
-        self._decode_table_np[idx] = serve_pages.SCRATCH_PAGE
-        self._lengths[idx] = 0
-        self._last_token[idx] = 0
+        self._set_row(idx, table=serve_pages.SCRATCH_PAGE,
+                      tables=serve_pages.SCRATCH_PAGE, lengths=0, tokens=0,
+                      samp=(0.0, 0, 1.0, 0, 0))
         self._prompts[idx] = None
         self._request_ids[idx] = ""
         self._prefill_pos[idx] = 0
         self._chunk_ahead[idx] = False
-        self._samp["temperature"][idx] = 0.0
-        self._samp["top_k"][idx] = 0
-        self._samp["top_p"][idx] = 1.0
-        self._samp["key_hi"][idx] = 0
-        self._samp["key_lo"][idx] = 0
 
     @property
     def prefilling_slots(self) -> int:

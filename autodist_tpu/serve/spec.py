@@ -580,7 +580,7 @@ class SpecDecodeEngine(InferenceEngine):
         pos_dev = jnp.asarray(positions)
         draft_tables = jnp.asarray(self._draft_decode_np)
         cur = jnp.asarray(self._last_token)
-        samp = self._samp_dev()
+        samp = tuple(map(jnp.asarray, self._mirror(("samp", None))))
         proposals = []
         for j in range(k + 1):
             # k+1 invocations of the ONE draft decode program: feed j
@@ -625,8 +625,8 @@ class SpecDecodeEngine(InferenceEngine):
             # rejected positions' target KV is garbage that the next
             # round's write-then-mask order can never read (the same
             # future-slot contract chunked prefill relies on).
-            self._lengths[idx] = int(positions[idx]) + m + 1
-            self._last_token[idx] = emit[-1]
+            self._set_row(idx, lengths=int(positions[idx]) + m + 1,
+                          tokens=emit[-1])
             out[Slot(idx)] = emit
             self.proposed_total += k
             self.accepted_total += m

@@ -330,15 +330,20 @@ def test_serving_modules_are_named(tiny_engine):
     if e._decode_fn is None:
         e._compile()
     cache = jax.eval_shape(lambda: e._cache)
-    decode = analysis.compiled_text(
-        e._decode_fn, e.params, jnp.asarray(e._last_token),
-        jnp.asarray(e._lengths), cache, jnp.asarray(e._decode_table_np),
-        e._samp_dev())
+    # the decode step's operands as the engine keeps them on the device
+    # (its outputs end with the next step's tokens and lengths)
+    args = (e.params, e._mirror(("tokens", None)), e._mirror(("lengths", None)),
+            cache, e._mirror(("tables", None)), e._mirror(("samp", None)))
+    decode = analysis.compiled_text(e._decode_fn, *args)
     assert "jit_serve_decode_step," in _module_line(decode)
-    chunk = np.zeros((1, e.prefill_chunk), np.int32)
+    *_, tokens, lengths = jax.eval_shape(e._decode_fn, *args)
+    assert tokens.shape == lengths.shape == (e.n_slots,)
+    # a chunk's one int32 operand: its tokens, then start, length and row
+    chunk = np.zeros(e.prefill_chunk + 3, np.int32)
+    chunk[e.prefill_chunk:] = 0, 5, 0
     prefill = analysis.compiled_text(
-        e._prefill_fn, e.params, jnp.asarray(chunk), np.int32(0), np.int32(5),
-        cache, jnp.asarray(e._table_np[0]), e._samp_dev(0))
+        e._prefill_fn, e.params, chunk, cache, e._mirror(("table", 0)),
+        e._mirror(("samp", 0)))
     assert "jit_serve_prefill_chunk," in _module_line(prefill)
     copy = e._make_page_copy_fn(e.pool.n_pages, e._cache_sh)
     assert "jit_serve_cow_copy," in _module_line(analysis.compiled_text(
