@@ -322,22 +322,43 @@ class Histogram:
         self._rng = np.random.default_rng(0)
 
     def observe(self, v: float) -> None:
+        self.observe_many((v,))
+
+    def observe_many(self, values) -> None:
+        """Observe each of ``values`` in order, under one lock: the count,
+        sum and reservoir are those of one observation at a time. Past the
+        reservoir's fill the batch's draws are taken in one call of the
+        generator, which draws an array of bounds as it draws them one at
+        a time (the same stream of ``j``)."""
         import bisect
 
-        v = float(v)
+        values = [float(v) for v in values]
+        if not values:
+            return
         with self._lock:
-            self._count += 1
-            self._sum += v
-            if len(self._samples) < self._max:
-                bisect.insort(self._samples, v)
+            samples, first = self._samples, self._count + 1
+            for v in values:
+                self._sum += v
+            self._count += len(values)
+            fill = min(len(values), self._max - len(samples))
+            for v in values[:fill]:
+                bisect.insort(samples, v)
+            rest = values[fill:]
+            if not rest:
+                return
+            bounds = range(first + fill, first + len(values))
+            if len(rest) < 8:       # numpy's array call costs ~5 scalar draws
+                js = [int(self._rng.integers(0, c)) for c in bounds]
             else:
-                j = int(self._rng.integers(0, self._count))
+                js = self._rng.integers(0, np.asarray(
+                    bounds, dtype=np.int64)).tolist()
+            for j, v in zip(js, rest):
                 if j < self._max:
                     # Evicting the j-th order statistic for uniform random
                     # j evicts a uniform-random retained sample — same
                     # algorithm-R distribution as the unsorted variant.
-                    del self._samples[j]
-                    bisect.insort(self._samples, v)
+                    del samples[j]
+                    bisect.insort(samples, v)
 
     @property
     def count(self) -> int:

@@ -10,7 +10,11 @@ processes of one launch* stitch into a single timeline.
 Mechanics:
 
 - :class:`SpanTracer` holds a thread-safe ring buffer of completed spans
-  (bounded memory; a long-running server can trace forever). Spans are
+  (bounded memory; a long-running server can trace forever), kept as
+  plain tuples and made into :class:`Span` objects when read: a span
+  costs its two clocks, its annotation and one append. The OS pid is
+  taken once a process (again after a fork), the thread id once a
+  thread, the role and trace id once a tracer. Spans are
   opened with the :meth:`SpanTracer.span` context manager or the
   :func:`traced` decorator, or recorded retroactively with
   :meth:`SpanTracer.add_span` (e.g. queue-wait time measured by the
@@ -78,7 +82,39 @@ __all__ = [
 
 _PART_PREFIX = "obs-part-"
 _span_ids = itertools.count(1)
-_open = threading.local()        # .stack: ids of the spans open on this thread
+# .state: (the ids of the spans open on this thread, the thread's id as a
+# span carries it), made on the thread's first span
+_open = threading.local()
+_pid = os.getpid()
+_jax_profiler = None        # jax.profiler, once jax is imported
+
+
+def _thread_state():
+    try:
+        return _open.state
+    except AttributeError:
+        _open.state = ([], threading.get_ident() % 1_000_000)
+        return _open.state
+
+
+def _after_fork() -> None:
+    global _pid
+    _pid = os.getpid()
+    stack = getattr(_open, "state", ([],))[0]
+    _open.state = (stack, threading.get_ident() % 1_000_000)
+
+
+os.register_at_fork(after_in_child=_after_fork)
+
+
+def _find_profiler():
+    """``jax.profiler`` where ``jax`` is imported, else None; kept once
+    found (the module is the same for the process's life)."""
+    global _jax_profiler
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        _jax_profiler = jax.profiler
+    return _jax_profiler
 
 
 def current_trace_id() -> str:
@@ -134,22 +170,21 @@ class _OpenSpan:
     generator: a serving tick opens ten of them)."""
 
     __slots__ = ("tracer", "name", "attrs", "id", "parent", "region",
-                 "t_wall", "t0")
+                 "t_wall", "t0", "stack", "tid")
 
     def __init__(self, tracer: "SpanTracer", name: str, attrs: Dict[str, Any]):
         self.tracer, self.name, self.attrs = tracer, name, attrs
 
     def __enter__(self) -> Dict[str, Any]:
-        stack = getattr(_open, "stack", None)
-        if stack is None:
-            stack = _open.stack = []
+        stack, self.tid = _thread_state()
+        self.stack = stack
         self.id = next(_span_ids)
         self.parent = stack[-1] if stack else None
         stack.append(self.id)
-        jax = sys.modules.get("jax")
+        profiler = _jax_profiler or _find_profiler()
         self.region = None
-        if jax is not None:
-            self.region = jax.profiler.TraceAnnotation(self.name)
+        if profiler is not None:
+            self.region = profiler.TraceAnnotation(self.name)
             self.region.__enter__()
         self.t_wall = time.time()
         self.t0 = time.perf_counter()
@@ -159,11 +194,11 @@ class _OpenSpan:
         dur = time.perf_counter() - self.t0
         if self.region is not None:
             self.region.__exit__(None, None, None)
-        _open.stack.pop()
+        self.stack.pop()
         if exc_type is not None:
             self.attrs["error"] = True
-        self.tracer._add(self.name, self.t_wall, dur, self.id, self.parent,
-                         self.attrs)
+        self.tracer._record((self.name, self.t_wall, dur, self.id,
+                             self.parent, self.attrs, _pid, self.tid))
 
 
 class SpanTracer:
@@ -221,26 +256,36 @@ class SpanTracer:
         """Record a span measured elsewhere (retroactive — e.g. queue wait
         computed at admission time). ``t_start_s`` is wall-clock seconds;
         ``parent`` is the id of the span that caused it, if any."""
-        return self._add(name, t_start_s, dur_s, next(_span_ids), parent,
-                         attrs)
+        rec = (name, float(t_start_s), float(dur_s), next(_span_ids), parent,
+               attrs, _pid, _thread_state()[1])
+        self._record(rec)
+        return self._span(rec)
 
-    def _add(self, name, t_start_s, dur_s, span_id, parent, attrs) -> Span:
-        sp = Span(
-            name=name, t_start_s=float(t_start_s), dur_s=float(dur_s),
-            trace_id=self.trace_id, process=self.process,
-            os_pid=os.getpid(), tid=threading.get_ident() % 1_000_000,
-            attrs=attrs, id=span_id, parent=parent,
-        )
+    def _pin(self) -> None:
+        """Resolve the trace id and the role for good (at the first span)."""
+        self._trace_id, self._process = self.trace_id, self.process
+
+    def _record(self, rec: tuple) -> None:
+        """Append ``(name, wall start, duration, id, parent, attrs, OS pid,
+        thread id)`` to the ring."""
+        if self._trace_id is None or self._process is None:
+            self._pin()
         with self._lock:
             if len(self._spans) == self._spans.maxlen:
                 self._dropped += 1
-            self._spans.append(sp)
-        return sp
+            self._spans.append(rec)
+
+    def _span(self, rec: tuple) -> Span:
+        name, t_start_s, dur_s, span_id, parent, attrs, pid, tid = rec
+        return Span(name=name, t_start_s=t_start_s, dur_s=dur_s,
+                    trace_id=self.trace_id, process=self.process, os_pid=pid,
+                    tid=tid, attrs=attrs, id=span_id, parent=parent)
 
     # --------------------------------------------------------------- reading
     def spans(self) -> List[Span]:
         with self._lock:
-            return list(self._spans)
+            recs = list(self._spans)
+        return [self._span(rec) for rec in recs]
 
     @property
     def dropped(self) -> int:
@@ -295,20 +340,19 @@ class SpanTracer:
 # ----------------------------------------------------------- default tracer
 _default_tracer: Optional[SpanTracer] = None
 _default_lock = threading.Lock()
-_autoflush_installed = False
 
 
 def get_tracer() -> SpanTracer:
     """The process-default tracer (every built-in instrumentation point
-    writes here). First use arms the ``AUTODIST_TRACE_OUT`` at-exit flush
-    when that env var names a directory."""
-    global _default_tracer, _autoflush_installed
-    with _default_lock:
-        if _default_tracer is None:
-            _default_tracer = SpanTracer()
-        if not _autoflush_installed and ENV.AUTODIST_TRACE_OUT.val:
-            _autoflush_installed = True
-            atexit.register(_flush_at_exit)
+    writes here). Its first use registers the at-exit flush, which writes
+    this process's part-file where ``AUTODIST_TRACE_OUT`` names a directory
+    at exit."""
+    global _default_tracer
+    if _default_tracer is None:
+        with _default_lock:
+            if _default_tracer is None:
+                _default_tracer = SpanTracer()
+                atexit.register(_flush_at_exit)
     return _default_tracer
 
 
@@ -331,8 +375,9 @@ def enable_trace_out(directory: str) -> None:
 
 
 def span(name: str, **attrs):
-    """Module-level convenience over the default tracer."""
-    return get_tracer().span(name, **attrs)
+    """Module-level convenience over the default tracer (``SpanTracer.span``
+    without its call: the serving tick opens its spans here)."""
+    return _OpenSpan(_default_tracer or get_tracer(), name, attrs)
 
 
 def traced(name: Optional[str] = None):

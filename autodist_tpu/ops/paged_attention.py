@@ -171,8 +171,7 @@ def paged_blocking(n_q: int, n_tables: int, page_len: int, lanes: int,
     its keys, masked or not, so a larger group only computes more of what
     no query sees (PERF.md section 6, PR 35). A table width that nothing
     larger divides walks a page a step. Pure in what the call can see
-    (queries a row, table width, the pages' shape and type), so the host
-    counts the same groups: :func:`paged_group_counts`."""
+    (queries a row, table width, the pages' shape and type)."""
     tile = 16 if itemsize == 2 else 8
     q_tile = 1 if n_q == 1 else min(-(-n_q // tile) * tile, 16)
     # a stream's element: twice in the pipeline's buffers, once as the
@@ -182,26 +181,6 @@ def paged_blocking(n_q: int, n_tables: int, page_len: int, lanes: int,
     most = max(1, min(128 // page_len, vmem_budget // per_page, n_tables))
     group = max(g for g in range(1, most + 1) if n_tables % g == 0)
     return group, q_tile
-
-
-def paged_group_counts(reach, n_q: int, n_tables: int, page_len: int,
-                       lanes: int, itemsize: int):
-    """``(groups, live groups)`` of one ``paged_attention`` call over rows
-    whose queries are ``n_q`` consecutive positions ending one short of
-    ``reach[b]`` (host integers): the page groups of the rows' tables, a
-    query tile each, and those of them at or under a query's position,
-    which are the ones the kernel computes. Every tile's first is live."""
-    group, q_tile = paged_blocking(n_q, n_tables, page_len, lanes, itemsize)
-    n_groups = n_tables // group
-    span = group * page_len
-    n_tiles = -(-n_q // q_tile)
-    live = 0
-    for r in reach:
-        for i in range(n_tiles):
-            # the tile's last query; the pad repeats the row's last
-            end = min(int(r), int(r) - n_q + (i + 1) * q_tile)
-            live += min(n_groups, max(1, -(-end // span)))
-    return n_groups * n_tiles * len(reach), live
 
 
 def _paged_kernel(tables_ref, reach_ref, qpos_ref, q_ref, *rest,

@@ -313,10 +313,6 @@ class ContinuousBatcher:
         self._m_summaries = None
         self._m_ring_pages = None
         self._m_summary_pages = None
-        # The paged kernel's walk over the plain timeline (engine
-        # ``kv_groups`` / ``kv_groups_live``), lazily registered likewise.
-        self._m_kv_groups = None
-        self._m_kv_groups_live = None
         # What only the device knows of a decode step (the engine's
         # ``fact_totals``, a model's ``step_facts``), lazily registered.
         self._m_facts = None
@@ -343,6 +339,11 @@ class ContinuousBatcher:
         self._m_latency = reg.histogram("serve_request_latency_s")
         self._m_ttft = reg.histogram("serve_ttft_s")
         self._m_itl = reg.histogram("serve_itl_s")
+        # The gaps of the tokens delivered since the last observation: the
+        # histogram takes a decode round's at once, at the end of its
+        # ``serve.emit`` (or before a request retires, so that a finished
+        # request's gaps are in it).
+        self._itl_pending: List[float] = []
         # The bytes of the parameter tree the engine placed for its
         # programs (a model's ``serving_params`` of the caller's, or it).
         reg.gauge("serve_param_bytes").set(
@@ -788,7 +789,6 @@ class ContinuousBatcher:
             self._update_prefix_metrics()
             self._update_quant_metrics()
             self._update_ring_metrics(sp)
-            self._update_kv_group_metrics()
             self._update_step_fact_metrics(sp)
             self._update_ssm_metrics(sp)
             self._update_input_put_metrics(sp)
@@ -938,16 +938,25 @@ class ContinuousBatcher:
                 self._maybe_retire(slot, req)
             self._m_tokens.inc(n_appended)
             self._m_decode_tokens.inc(n_appended)
+            self._observe_gaps()
         return True
 
     def _append_token(self, req: GenRequest, token: int, now: float) -> None:
-        """Deliver one token: stamp its time on the request and observe
-        the gap since the request's previous token (``serve_itl_s`` holds
-        every inter-token gap, so one stalled tick shows in its tail)."""
+        """Deliver one token: stamp its time on the request and keep the
+        gap since the request's previous token for ``_observe_gaps``
+        (``serve_itl_s`` holds every inter-token gap, so one stalled tick
+        shows in its tail)."""
         if req.t_tokens:
-            self._m_itl.observe(now - req.t_tokens[-1])
+            self._itl_pending.append(now - req.t_tokens[-1])
         req.tokens.append(token)
         req.t_tokens.append(now)
+
+    def _observe_gaps(self) -> None:
+        """Hand the kept gaps to ``serve_itl_s`` in one call, in the order
+        they were delivered."""
+        if self._itl_pending:
+            self._m_itl.observe_many(self._itl_pending)
+            self._itl_pending = []
 
     def _update_spec_metrics(self) -> None:
         """Publish speculative-decode gauges + feed the SLO tracker's
@@ -1056,29 +1065,6 @@ class ContinuousBatcher:
         sp["ring_pages"] = ring
         sp["summary_pages"] = summary
 
-    def _update_kv_group_metrics(self) -> None:
-        """Publish how often the paged kernel's skip engages, from the
-        engine's cumulative counts: ``serve_kv_groups_total`` (page groups
-        of the rows' tables in a layer's call of every decode step and
-        prefill chunk dispatched) and ``serve_kv_groups_live_total``
-        (those of them at or under a query's position: the rest are
-        skipped whole). The per-call readings ride the
-        ``serve.decode_dispatch`` and ``serve.prefill_chunk`` spans as
-        ``kv_groups`` / ``kv_groups_live``. No-op over a window ring, whose
-        kernel counts its own segments."""
-        eng = self.engine
-        layout = getattr(eng, "layout", None)
-        if layout is None or layout.window:
-            return
-        if self._m_kv_groups is None:
-            self._m_kv_groups = self._registry.counter(
-                "serve_kv_groups_total")
-            self._m_kv_groups_live = self._registry.counter(
-                "serve_kv_groups_live_total")
-        for counter, now in ((self._m_kv_groups, eng.kv_groups),
-                             (self._m_kv_groups_live, eng.kv_groups_live)):
-            counter.inc(now - counter.value)
-
     def _update_step_fact_metrics(self, sp) -> None:
         """Publish what only the device knows of the decode steps (a
         model's ``step_facts``, which reach the host with the tokens), from
@@ -1183,6 +1169,7 @@ class ContinuousBatcher:
 
     def _retire(self, slot: Slot, req: GenRequest, state: RequestState,
                 why: str) -> None:
+        self._observe_gaps()
         with self._lock:
             self._active.pop(slot, None)
         self.engine.release(slot)

@@ -45,7 +45,6 @@ from autodist_tpu.kernel import GraphTransformer, ShardingPlan, build_mesh, data
 from autodist_tpu.model_item import ModelItem
 from autodist_tpu.obs import recorder as obs_recorder
 from autodist_tpu.obs import spans as obs_spans
-from autodist_tpu.ops.paged_attention import paged_group_counts
 from autodist_tpu.serve import pages as serve_pages
 from autodist_tpu.serve import prefix as serve_prefix
 from autodist_tpu.serve import sampling as serve_sampling
@@ -324,13 +323,6 @@ class InferenceEngine:
             lambda: decode_model.init_paged_cache(1, self.page_len))
         page_bytes = tree_bytes(page_shaped)
         self.page_bytes = page_bytes
-        # Over the plain timeline: the lanes and item size of a page of
-        # keys (the cache's widest leaf), what the paged kernel's blocking
-        # reads beside the table width (ops/paged_attention.py).
-        wide = max(jax.tree_util.tree_leaves(page_shaped),
-                   key=lambda leaf: leaf.shape[-1])
-        self._kv_page = None if layout.window or layout.latent else (
-            int(wide.shape[-1]), np.dtype(wide.dtype).itemsize)
         # Quantized pool mode (int8 pages + f32 scale planes, PR 20):
         # detected from the model's own cache pytree, so the engine needs
         # no config plumbing — the scale leaves share the page dim and ride
@@ -505,12 +497,6 @@ class InferenceEngine:
         self.window_rolls = 0
         self.window_rolls_decode = 0
         self.summary_chunks = 0
-        # Over the plain timeline: the page groups of the rows' tables in
-        # a layer's call of the programs dispatched, and those of them the
-        # paged kernel does not skip (``_count_kv_groups``). Cumulative; the
-        # batcher publishes them (serve_kv_groups_total, ..._live_total).
-        self.kv_groups = 0
-        self.kv_groups_live = 0
         # Over per-slot state: rows whose state the decode steps dispatched
         # updated (the decoding rows; the rest are skipped). Cumulative;
         # the batcher publishes it (serve_ssm_rows_total).
@@ -1076,20 +1062,6 @@ class InferenceEngine:
         one chunk per tick (chunked prefill interleaves with decode)."""
         return [Slot(int(i)) for i in np.flatnonzero(self._phase == _PREFILL)]
 
-    def _count_kv_groups(self, sp, reach, n_q: int) -> None:
-        """Stamp on the open span ``sp`` how many page groups the rows'
-        tables hold for one layer's call of the paged kernel over rows
-        whose last query sits one short of ``reach[b]``, and how many of
-        them are live (the rest it skips): the kernel's own blocking
-        (``paged_group_counts``) on the host's integers, no device work.
-        Over a window ring: nothing."""
-        if self._kv_page is None:
-            return
-        sp["kv_groups"], sp["kv_groups_live"] = paged_group_counts(
-            reach, n_q, self.max_pages, self.page_len, *self._kv_page)
-        self.kv_groups += sp["kv_groups"]
-        self.kv_groups_live += sp["kv_groups_live"]
-
     def _dispatch_chunk(self, idx: int):
         """Dispatch the next chunk of row ``idx``'s prompt and advance its
         position; returns ``(first token, still on the device; final; the
@@ -1107,7 +1079,6 @@ class InferenceEngine:
         with obs_spans.span("serve.prefill_chunk", start=start,
                             prompt_len=len(prompt), final=final,
                             request_id=self._request_ids[idx], puts=1) as sp:
-            self._count_kv_groups(sp, [start + c], c)
             # the one put of a chunk whose row's inputs are on the device
             valid = prompt[start:start + c]
             chunk = np.zeros(c + 3, np.int32)
@@ -1151,13 +1122,28 @@ class InferenceEngine:
         by now. Returns the tokens. Where the model states ``step_facts``
         each vector's tail is stamped on the span that produced it
         (``attrs`` for ``tokens``' own) and a decode step's is added to
-        ``fact_totals``."""
+        ``fact_totals``.
+
+        The copies to the host start first, so that each runs on the
+        device as soon as its program has. The wait for the program to
+        finish is then a span of its own, ``wait.tokens`` (``program``):
+        its end, on the device trace's clock, is the instant the host learns
+        the program has run, and what the enclosing ``serve.token_fetch``
+        holds beyond it is what is left of the copy."""
+        vecs = [tokens]
+        if self.step_facts:
+            if pending is None:
+                pending, self._facts_pending = self._facts_pending, []
+            vecs += [vec for _, vec in pending]
+        for vec in vecs:
+            vec.copy_to_host_async()
+        with obs_spans.span("wait.tokens", program="decode_step" if decode
+                            else "prefill_chunk"):
+            jax.block_until_ready(tokens)
+        got = jax.device_get(vecs)
         if not self.step_facts:
-            return np.asarray(jax.device_get(tokens))
-        if pending is None:
-            pending, self._facts_pending = self._facts_pending, []
+            return np.asarray(got[0])
         n = len(self.step_facts)
-        got = jax.device_get([tokens] + [vec for _, vec in pending])
         for where, vec in zip([attrs] + [sp for sp, _ in pending], got):
             where.update(zip(self.step_facts, (int(v) for v in vec[-n:])))
         if decode:
@@ -1239,7 +1225,6 @@ class InferenceEngine:
             # from the wait for the device: a device left idle under the
             # first is the host's to cure, under the second it is not idle.
             with obs_spans.span("serve.decode_dispatch", puts=0) as sp:
-                self._count_kv_groups(sp, self._lengths + 1, 1)
                 last, lengths, tables, samp = self._inputs(
                     sp, ("tokens", None), ("lengths", None),
                     ("tables", None), ("samp", None))

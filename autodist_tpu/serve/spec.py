@@ -614,8 +614,12 @@ class SpecDecodeEngine(InferenceEngine):
             acc, out_tok, self._cache = self._verify_fn(
                 self.params, tokens_mat, pos_dev, self._cache,
                 jnp.asarray(self._decode_table_np), samp)
-            acc = np.asarray(jax.device_get(acc))
-            out_tok = np.asarray(jax.device_get(out_tok))
+            acc.copy_to_host_async()
+            out_tok.copy_to_host_async()
+            with obs_spans.span("wait.tokens", program="spec_verify"):
+                jax.block_until_ready(out_tok)
+            acc, out_tok = (np.asarray(v)
+                            for v in jax.device_get((acc, out_tok)))
         self.spec_rounds += 1
         for i in decoding:
             idx = int(i)

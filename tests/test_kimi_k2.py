@@ -292,7 +292,7 @@ def test_the_engine_serves_the_dense_forwards_greedy_stream(cfg, params):
     try:
         engine = _engine(params, cfg)
         assert engine.page_len == PAGE and engine.prefill_chunk == 16
-        assert engine.layout.latent and engine._kv_page is None
+        assert engine.layout.latent
         # a page is priced from the one leaf a layer: rows x columns x layers
         assert engine.page_bytes == PAGE * cfg.page_width * 4 * 3
         prompt = np.arange(3, 40) % 64

@@ -228,3 +228,61 @@ def test_ranking_metrics_over_real_ncf():
     assert 0.0 <= got["ndcg@5"] <= 1.0
     assert got["examples"] == 8
     ad.AutoDist.reset_default()
+
+
+# ------------------------------------------------- Histogram.observe_many
+def _reservoir(values, max_samples=4096):
+    """Algorithm R over a sorted reservoir, one value at a time, as
+    ``Histogram.observe`` kept it before ``observe_many``: the reference."""
+    import bisect
+
+    rng, samples, count, total = np.random.default_rng(0), [], 0, 0.0
+    for v in values:
+        v = float(v)
+        count += 1
+        total += v
+        if len(samples) < max_samples:
+            bisect.insort(samples, v)
+        else:
+            j = int(rng.integers(0, count))
+            if j < max_samples:
+                del samples[j]
+                bisect.insort(samples, v)
+    return samples, count, total
+
+
+@pytest.mark.parametrize("n,batches", [
+    (10, [3]), (4096, [1, 128, 7]), (4097, [4097]), (5000, [4000, 1000]),
+    (20_000, [4, 1, 128, 33]), (20_000, [0, 20_000])])
+def test_observe_many_leaves_what_observe_leaves(n, batches):
+    """The same count, sum, retained reservoir and rendered text whether
+    the values come one ``observe`` at a time or in ``observe_many``
+    batches of any size, past 4,096 samples too (where algorithm R draws
+    from the generator), and both equal to the one-at-a-time reference."""
+    values = np.random.default_rng(n).exponential(0.01, n).tolist()
+    one, many = metrics.MetricsRegistry(), metrics.MetricsRegistry()
+    for v in values:
+        one.histogram("serve_itl_s").observe(v)
+    at, i = 0, 0
+    while at < len(values):
+        size = batches[i % len(batches)]
+        many.histogram("serve_itl_s").observe_many(values[at:at + size])
+        at, i = at + size, i + 1
+    a, b = one.histogram("serve_itl_s"), many.histogram("serve_itl_s")
+    samples, count, total = _reservoir(values)
+    assert (a._samples, a.count, a.sum) == (samples, count, total)
+    assert (b._samples, b.count, b.sum) == (samples, count, total)
+    assert len(samples) == min(n, 4096)
+    assert many.render_text() == one.render_text()
+    # and the generator stands where it did: the next value lands alike
+    a.observe(0.5)
+    b.observe_many([0.5])
+    assert b._samples == a._samples == _reservoir(values + [0.5])[0]
+
+
+def test_observe_many_of_nothing_changes_nothing():
+    h = metrics.Histogram()
+    h.observe_many([])
+    assert h.count == 0 and h.sum == 0.0 and h._samples == []
+    h.observe_many(iter([2, 1.0, np.float32(3)]))
+    assert h._samples == [1.0, 2.0, 3.0] and h.count == 3 and h.sum == 6.0

@@ -317,20 +317,6 @@ class TestBlocking:
         assert pa.paged_blocking(*shape, vmem_budget=1 << 20)[0] == 2
         assert pa.paged_blocking(*shape, vmem_budget=1)[0] == 1
 
-    def test_group_counts_follow_the_rows_reach(self):
-        # XL decode: 8 groups of 128 keys a row. Reach 1 (an idle row), 128
-        # and 129 are 1, 1 and 2 live groups; the table's end is all 8.
-        shape = (1, 64, 16, 1600, 2)
-        assert pa.paged_group_counts([1, 128, 129, 1024], *shape) == (32, 12)
-        # XL chunk: the same groups; a chunk ending at 128 sees one.
-        shape = (16, 64, 16, 1600, 2)
-        assert pa.paged_group_counts([16], *shape) == (8, 1)
-        assert pa.paged_group_counts([128], *shape) == (8, 1)
-        assert pa.paged_group_counts([144], *shape) == (8, 2)
-        # 40 queries (480..519) go in three tiles of 16, the pad repeating
-        # the last: the tiles end at 495, 511 and 519.
-        assert pa.paged_group_counts([520], 40, *shape[1:]) == (24, 4 + 4 + 5)
-
 
 class TestQuantization:
     def test_round_trip_error_bounded(self):

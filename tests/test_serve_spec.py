@@ -80,6 +80,50 @@ def test_greedy_equivalence_divergent_draft(rig, prompts, expected, k):
     assert spec.spec_stats()["acceptance_rate"] < 0.5
 
 
+def test_each_verify_round_waits_under_one_wait_span(rig, prompts, expected,
+                                                     monkeypatch):
+    """The verify round's wait for the device is its own span: one
+    ``wait.tokens`` (``program`` ``spec_verify``) inside every
+    ``serve.spec_verify``, as the plain engine's fetches have theirs, on
+    an array whose copy to the host was started before it."""
+    import jax
+    from jax._src.array import ArrayImpl
+
+    from autodist_tpu.obs import spans as obs_spans
+
+    events = []
+    copy, wait = ArrayImpl.copy_to_host_async, jax.block_until_ready
+
+    def copying(self):
+        events.append(("copy", self))
+        return copy(self)
+
+    def waiting(x):
+        events.append(("wait", x))
+        return wait(x)
+
+    monkeypatch.setattr(ArrayImpl, "copy_to_host_async", copying)
+    monkeypatch.setattr(jax, "block_until_ready", waiting)
+    spec = rig.spec_engine(2, same_draft=True)
+    tracer = obs_spans.get_tracer()
+    tracer.clear()
+    assert spec.generate(prompts[3], MAX_NEW) == expected[3]
+    waits = [i for i, (kind, _) in enumerate(events) if kind == "wait"]
+    assert waits
+    since = 0
+    for i in waits:
+        assert any(kind == "copy" and x is events[i][1]
+                   for kind, x in events[since:i]), i
+        since = i + 1
+    spans = tracer.spans()
+    rounds = [s for s in spans if s.name == "serve.spec_verify"]
+    assert rounds
+    for r in rounds:
+        waits = [s for s in spans if s.parent == r.id and s.name == "wait.tokens"]
+        assert [w.attrs for w in waits] == [{"program": "spec_verify"}]
+        assert waits[0].dur_s <= r.dur_s
+
+
 def test_invocation_reduction_at_friendly_workload(rig, prompts, expected):
     """The perf bar: >=2x fewer target-model program invocations per
     emitted token than plain greedy at the acceptance-friendly

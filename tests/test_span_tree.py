@@ -229,6 +229,10 @@ def test_one_request_yields_the_tick_tree(tiny_engine):
     step = children(third)[3]
     assert [(c.name, c.attrs.get("program")) for c in children(step)] == [
         ("serve.decode_dispatch", None), ("serve.token_fetch", "decode_step")]
+    # each fetch holds the wait for its program and nothing else
+    for fetch in (children(third)[1], children(step)[1]):
+        assert [(c.name, c.attrs["program"]) for c in children(fetch)] == [
+            ("wait.tokens", fetch.attrs["program"])]
     hooks = [s for s in spans if s.name == "serve.on_tick"]
     assert len(hooks) == len(seen) and all(h.parent is None for h in hooks)
     # a tick's self time is what its children leave
@@ -239,6 +243,176 @@ def test_one_request_yields_the_tick_tree(tiny_engine):
     assert len(req.t_tokens) == len(req.tokens) == 4
     assert req.t_tokens == sorted(req.t_tokens)
     assert req.t_tokens[0] == req.t_first_token
+
+
+def test_every_token_fetch_holds_one_wait_for_its_program(tiny_engine):
+    """Two requests of several chunks: every decode step's fetch and every
+    final chunk's fetch has exactly one ``wait.tokens`` child, inside it,
+    carrying the fetch's ``program``; no ``wait.tokens`` lies anywhere
+    else. Nothing counts the paged kernel's page groups any more."""
+    from autodist_tpu.serve.batcher import ContinuousBatcher, RequestState
+
+    tracer = obs_spans.get_tracer()
+    tracer.clear()
+    registry = M.MetricsRegistry()
+    batcher = ContinuousBatcher(tiny_engine, registry=registry)
+    batcher.start()
+    try:
+        reqs = [batcher.submit(np.arange(1, n, dtype=np.int32), 5)
+                for n in (21, 12)]
+        for r in reqs:
+            assert r.wait(120.0).state is RequestState.DONE
+    finally:
+        batcher.stop()
+    spans = tracer.spans()
+    by_id = {s.id: s for s in spans}
+    fetches = [s for s in spans if s.name == "serve.token_fetch"]
+    waits = [s for s in spans if s.name == "wait.tokens"]
+    steps = [s for s in spans if s.name == "serve.decode_step"]
+    finals = [s for s in spans if s.name == "serve.prefill_chunk"
+              and s.attrs["final"]]
+    assert len(finals) == 2 and len(steps) >= 4
+    assert len(fetches) == len(waits) == len(steps) + len(finals)
+    assert sorted(by_id[f.parent].name for f in fetches
+                  if f.attrs["program"] == "decode_step") == [
+        "serve.decode_step"] * len(steps)
+    for fetch in fetches:
+        mine = [w for w in waits if w.parent == fetch.id]
+        assert len(mine) == 1, fetch.attrs
+        wait, = mine
+        assert wait.attrs == {"program": fetch.attrs["program"]}
+        assert fetch.t_start_s - 1e-3 <= wait.t_start_s
+        assert wait.t_start_s + wait.dur_s <= (
+            fetch.t_start_s + fetch.dur_s + 1e-3)
+        assert wait.dur_s <= fetch.dur_s
+    for s in spans:
+        assert not {"kv_groups", "kv_groups_live"} & set(s.attrs), s.name
+    assert not [n for n in registry.snapshot() if "kv_groups" in n]
+    assert not hasattr(tiny_engine, "kv_groups")
+    assert not hasattr(tiny_engine, "kv_groups_live")
+
+
+def test_the_copy_starts_before_the_wait(tiny_engine, monkeypatch):
+    """The copy of a program's tokens to the host is started before the
+    host waits for the program (``wait.tokens``), so that it runs on the
+    device as soon as the program has, as it did when ``jax.device_get``
+    alone fetched them: every wait is on an array whose copy was started
+    since the wait before it."""
+    from jax._src.array import ArrayImpl
+
+    from autodist_tpu.serve.batcher import ContinuousBatcher, RequestState
+
+    events = []
+    copy, wait = ArrayImpl.copy_to_host_async, jax.block_until_ready
+
+    def copying(self):
+        events.append(("copy", self))
+        return copy(self)
+
+    def waiting(x):
+        events.append(("wait", x))
+        return wait(x)
+
+    monkeypatch.setattr(ArrayImpl, "copy_to_host_async", copying)
+    monkeypatch.setattr(jax, "block_until_ready", waiting)
+    batcher = ContinuousBatcher(tiny_engine, registry=M.MetricsRegistry())
+    batcher.start()
+    try:
+        req = batcher.submit(np.arange(1, 21, dtype=np.int32), 4)
+        assert req.wait(120.0).state is RequestState.DONE
+    finally:
+        batcher.stop()
+    waits = [i for i, (kind, _) in enumerate(events) if kind == "wait"]
+    assert len(waits) >= 4      # the final chunk's, then the decode steps'
+    since = 0
+    for i in waits:
+        assert any(kind == "copy" and x is events[i][1]
+                   for kind, x in events[since:i]), i
+        since = i + 1
+
+
+def test_span_fields_read_as_before():
+    """The ring keeps plain records; what ``spans()`` hands out reads as
+    the ``Span`` it always was: the OS pid, the thread id, the role and
+    the trace id of the recording process and thread."""
+    tracer = obs_spans.SpanTracer(trace_id="abc", process=3)
+    with tracer.span("outer", k=1) as attrs:
+        attrs["late"] = 2
+    t0 = time.time()
+    retro = tracer.add_span("retro", t0, 0.25, parent=7, why="x")
+    other = []
+    t = threading.Thread(target=lambda: other.append(
+        (threading.get_ident() % 1_000_000,
+         tracer.add_span("other", t0, 0.0))))
+    t.start()
+    t.join()
+    outer, got_retro, got_other = tracer.spans()
+    for sp in (outer, got_retro, got_other):
+        assert isinstance(sp, obs_spans.Span)
+        assert (sp.trace_id, sp.process, sp.os_pid) == ("abc", 3, os.getpid())
+        assert isinstance(sp.t_start_s, float) and isinstance(sp.dur_s, float)
+    assert outer.tid == threading.get_ident() % 1_000_000
+    assert got_other.tid == other[0][0] and got_other == other[0][1]
+    assert outer.attrs == {"k": 1, "late": 2} and outer.parent is None
+    assert got_retro == retro
+    assert (retro.name, retro.t_start_s, retro.dur_s, retro.parent,
+            retro.attrs) == ("retro", t0, 0.25, 7, {"why": "x"})
+    ev = retro.to_event()
+    assert ev["pid"] == os.getpid() and ev["args"]["trace_id"] == "abc"
+    assert ev["args"]["process"] == 3 and ev["args"]["why"] == "x"
+    # the ring's bound and its count of drops hold as before
+    small = obs_spans.SpanTracer(capacity=2)
+    for i in range(5):
+        small.add_span(f"s{i}", t0, 0.0)
+    assert [s.name for s in small.spans()] == ["s3", "s4"]
+    assert small.dropped == 3
+
+
+@pytest.mark.skipif(not hasattr(os, "fork"), reason="needs os.fork")
+def test_a_forked_child_records_its_own_pid():
+    """After a fork the child's spans carry the child's OS pid (and the
+    parent's, recorded before the fork, keep the parent's)."""
+    import json
+    import warnings
+
+    tracer = obs_spans.SpanTracer(trace_id="fork", process=0)
+    tracer.add_span("before", time.time(), 0.0)
+    with tracer.span("warm"):
+        pass
+    read, write = os.pipe()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")     # jax warns of fork and threads
+        pid = os.fork()
+    if pid == 0:                                            # the child
+        code = 1
+        try:
+            os.close(read)
+            with tracer.span("in.child"):
+                pass
+            tracer.add_span("retro.child", time.time(), 0.0)
+            got = [(s.name, s.os_pid, s.tid) for s in tracer.spans()]
+            os.write(write, json.dumps(
+                [os.getpid(), threading.get_ident() % 1_000_000, got]).encode())
+            code = 0
+        finally:
+            os._exit(code)
+    os.close(write)
+    chunks = []
+    while True:
+        chunk = os.read(read, 1 << 16)
+        if not chunk:
+            break
+        chunks.append(chunk)
+    os.close(read)
+    _, status = os.waitpid(pid, 0)
+    assert os.WIFEXITED(status) and os.WEXITSTATUS(status) == 0
+    child_pid, child_tid, got = json.loads(b"".join(chunks))
+    assert child_pid == pid != os.getpid()
+    assert [(n, p) for n, p, _ in got] == [
+        ("before", os.getpid()), ("warm", os.getpid()),
+        ("in.child", child_pid), ("retro.child", child_pid)]
+    assert got[2][2] == got[3][2] == child_tid
+    assert [s.os_pid for s in tracer.spans()] == [os.getpid()] * 2
 
 
 def test_itl_histogram_holds_every_gap(tiny_engine):
