@@ -501,6 +501,17 @@ class InferenceEngine:
         # updated (the decoding rows; the rest are skipped). Cumulative;
         # the batcher publishes it (serve_ssm_rows_total).
         self.ssm_rows = 0
+        # Over the sampler (``sample_tokens``): calls of the two programs
+        # whose rows were all greedy, which end in the argmax alone, and
+        # those with a row that samples, which run the draw. The device
+        # tests any ``temperature > 0`` in the arrays the call takes; the
+        # host reads the same from its mirror, through a count of the rows
+        # that sample kept at admission and release. Cumulative; a call's
+        # own rides its ``serve.decode_dispatch`` / ``serve.prefill_chunk``
+        # span as ``sampler`` (``"argmax"`` or ``"draw"``).
+        self.sampler_argmax_calls = 0
+        self.sampler_draw_calls = 0
+        self.stochastic_slots = 0
         # What only the device knows of a step (``DecodeModel.step_facts``):
         # summed over the decode steps fetched (``fact_steps`` of them), for
         # the batcher to publish; a chunk's facts stay on its span. A chunk
@@ -907,6 +918,15 @@ class InferenceEngine:
             self.input_puts += n
         return [self._dev[k] for k in keys]
 
+    def _count_sampler(self, sp, draws) -> None:
+        """Count a call by the sampler's branch it takes (``draws``: some
+        row it serves samples) and stamp the branch on its span."""
+        if draws:
+            self.sampler_draw_calls += 1
+        else:
+            self.sampler_argmax_calls += 1
+        sp["sampler"] = "draw" if draws else "argmax"
+
     def admit(self, prompt: np.ndarray, max_new_tokens: int,
               request_id: str = "",
               sampling: Optional["serve_sampling.SamplingParams"] = None,
@@ -989,6 +1009,7 @@ class InferenceEngine:
         self._set_row(idx, table=table.padded(self.max_pages),
                       tables=serve_pages.SCRATCH_PAGE, lengths=0, tokens=0,
                       samp=(sp.temperature, sp.top_k, sp.top_p, hi, lo))
+        self.stochastic_slots += int(self._samp["temperature"][idx] > 0)
         self._prompts[idx] = prompt
         self._request_ids[idx] = request_id
         self._prefill_pos[idx] = start_pos
@@ -1085,6 +1106,7 @@ class InferenceEngine:
             chunk[: len(valid)] = valid
             chunk[c:] = start, len(prompt), idx
             self.input_puts += 1
+            self._count_sampler(sp, self._samp["temperature"][idx] > 0)
             table, samp = self._inputs(sp, ("table", idx), ("samp", idx))
             state = () if self._state is None else (self._state,)
             first, self._cache, *state = self._prefill_fn(
@@ -1228,6 +1250,7 @@ class InferenceEngine:
                 last, lengths, tables, samp = self._inputs(
                     sp, ("tokens", None), ("lengths", None),
                     ("tables", None), ("samp", None))
+                self._count_sampler(sp, self.stochastic_slots > 0)
                 state = () if self._state is None else (self._state,)
                 if state:
                     # the rows whose state the step updates: the decoding
@@ -1307,6 +1330,7 @@ class InferenceEngine:
         self._prefill_start[idx] = 0
         self._tables[idx] = None
         self._phase[idx] = _FREE
+        self.stochastic_slots -= int(self._samp["temperature"][idx] > 0)
         self._set_row(idx, table=serve_pages.SCRATCH_PAGE,
                       tables=serve_pages.SCRATCH_PAGE, lengths=0, tokens=0,
                       samp=(0.0, 0, 1.0, 0, 0))

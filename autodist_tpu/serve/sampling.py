@@ -197,6 +197,14 @@ def sample_tokens(logits, counters, samp):
     bit-identical to the pre-sampling greedy programs. Everything here
     is shape-static: params ride as traced arrays, so the surrounding
     compiled program never recompiles per request.
+
+    The stochastic transform (the sorts, the softmax, the cumulative sum
+    and the Gumbel draw over the vocabulary) sits in the true branch of
+    one ``lax.cond`` on the device: it runs only when some row of the
+    call has ``temperature > 0``; a call whose rows are all greedy
+    returns the argmax alone. Either branch gives a greedy row its
+    argmax and a stochastic row always takes the transform, so every
+    row's token is the same whatever the batch around it.
     """
     import jax
     import jax.numpy as jnp
@@ -220,41 +228,50 @@ def sample_tokens(logits, counters, samp):
     key_hi = per_slot(key_hi, jnp.uint32)
     key_lo = per_slot(key_lo, jnp.uint32)
 
-    # Temperature scale (clamped: the T<=0 rows take the greedy branch
-    # of the final where, this value is never observed for them).
-    scaled = logits / jnp.maximum(temperature, 1e-6)[..., None]
+    def transform():
+        # Temperature scale (clamped: the T<=0 rows take the greedy
+        # branch of the final where, this value is never observed for
+        # them).
+        scaled = logits / jnp.maximum(temperature, 1e-6)[..., None]
 
-    # Top-k: keep scores >= the k-th largest; top_k<=0 disables.
-    sorted_desc = jnp.flip(jnp.sort(scaled, axis=-1), axis=-1)
-    k_eff = jnp.where(top_k <= 0, vocab, jnp.clip(top_k, 1, vocab))
-    kth = jnp.take_along_axis(sorted_desc, (k_eff - 1)[..., None], axis=-1)
-    masked = jnp.where(scaled >= kth, scaled, -jnp.inf)
+        # Top-k: keep scores >= the k-th largest; top_k<=0 disables.
+        sorted_desc = jnp.flip(jnp.sort(scaled, axis=-1), axis=-1)
+        k_eff = jnp.where(top_k <= 0, vocab, jnp.clip(top_k, 1, vocab))
+        kth = jnp.take_along_axis(sorted_desc, (k_eff - 1)[..., None],
+                                  axis=-1)
+        masked = jnp.where(scaled >= kth, scaled, -jnp.inf)
 
-    # Top-p nucleus over the top-k survivors: keep the smallest set of
-    # highest-probability tokens whose mass reaches top_p (always >= 1
-    # token — the strict '< top_p' on the EXCLUSIVE prefix sum keeps the
-    # head even when its own mass already exceeds top_p).
-    probs = jax.nn.softmax(masked, axis=-1)
-    p_sorted = jnp.flip(jnp.sort(probs, axis=-1), axis=-1)
-    prefix = jnp.cumsum(p_sorted, axis=-1) - p_sorted
-    keep_sorted = prefix < top_p[..., None]
-    thresh = jnp.min(jnp.where(keep_sorted, p_sorted, jnp.inf),
-                     axis=-1, keepdims=True)
-    filtered = jnp.where(probs >= thresh, masked, -jnp.inf)
+        # Top-p nucleus over the top-k survivors: keep the smallest set of
+        # highest-probability tokens whose mass reaches top_p (always >= 1
+        # token — the strict '< top_p' on the EXCLUSIVE prefix sum keeps
+        # the head even when its own mass already exceeds top_p).
+        probs = jax.nn.softmax(masked, axis=-1)
+        p_sorted = jnp.flip(jnp.sort(probs, axis=-1), axis=-1)
+        prefix = jnp.cumsum(p_sorted, axis=-1) - p_sorted
+        keep_sorted = prefix < top_p[..., None]
+        thresh = jnp.min(jnp.where(keep_sorted, p_sorted, jnp.inf),
+                         axis=-1, keepdims=True)
+        filtered = jnp.where(probs >= thresh, masked, -jnp.inf)
 
-    # Counter-based categorical draw via Gumbel-argmax: the key is a
-    # pure function of (request key, absolute position) — never carried
-    # state — so replay anywhere reproduces the identical draw. Raw
-    # threefry2x32 key material; fold_in mixes the position counter.
-    def draw(hi, lo, counter):
-        key = jax.random.fold_in(jnp.stack([hi, lo]), counter)
-        return jax.random.gumbel(key, (vocab,), jnp.float32)
+        # Counter-based categorical draw via Gumbel-argmax: the key is a
+        # pure function of (request key, absolute position) — never
+        # carried state — so replay anywhere reproduces the identical
+        # draw. Raw threefry2x32 key material; fold_in mixes the position
+        # counter.
+        def draw(hi, lo, counter):
+            key = jax.random.fold_in(jnp.stack([hi, lo]), counter)
+            return jax.random.gumbel(key, (vocab,), jnp.float32)
 
-    flat = jax.vmap(draw)(key_hi.reshape(-1), key_lo.reshape(-1),
-                          counters.reshape(-1))
-    gumbel = flat.reshape(shape + (vocab,))
-    sampled = jnp.argmax(filtered + gumbel, axis=-1).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy_tok, sampled)
+        flat = jax.vmap(draw)(key_hi.reshape(-1), key_lo.reshape(-1),
+                              counters.reshape(-1))
+        gumbel = flat.reshape(shape + (vocab,))
+        sampled = jnp.argmax(filtered + gumbel, axis=-1).astype(jnp.int32)
+        return jnp.where(temperature <= 0.0, greedy_tok, sampled)
+
+    # The predicate is read from the per-slot arrays the program already
+    # takes: no new input, no host round trip, no second program.
+    return jax.lax.cond(jnp.any(temperature > 0.0), transform,
+                        lambda: greedy_tok)
 
 
 # --------------------------------------------------------------- stats

@@ -159,6 +159,88 @@ class TestSampleTokens:
         assert not bad
 
 
+# ------------------------------------- unit: the draw runs only to sample
+def _primitives(jaxpr, skip=None):
+    """Every primitive name in ``jaxpr`` and the jaxprs nested in it,
+    leaving out the subtree ``skip``."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if sub is not skip and hasattr(sub, "eqns"):
+                    names |= _primitives(sub, skip)
+    return names
+
+
+_VOCAB_WORK = {"sort", "cumsum", "random_bits"}
+
+
+class TestGreedyBranch:
+    def test_vocab_work_only_in_the_draw_branch(self):
+        """The sorts, the cumulative sum and the random bits of the
+        transform lie in the ``cond``'s true branch alone: a call whose
+        rows are all greedy runs none of them."""
+        import jax
+        import jax.numpy as jnp
+
+        jaxpr = jax.make_jaxpr(sample_tokens)(
+            jnp.zeros((4, 64), jnp.float32), jnp.arange(4, dtype=jnp.int32),
+            _samp(4, SamplingParams())).jaxpr
+        [cond] = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+        greedy, draw = (b.jaxpr for b in cond.params["branches"])
+        assert _VOCAB_WORK <= _primitives(draw)
+        assert not _VOCAB_WORK & _primitives(jaxpr, skip=draw)
+        assert not _VOCAB_WORK & _primitives(greedy)
+
+    @pytest.mark.parametrize("verify", [False, True], ids=["decode", "verify"])
+    @pytest.mark.parametrize("sp", [
+        SamplingParams(temperature=1.0, seed=2),
+        SamplingParams(temperature=0.7, top_k=5, seed=3),
+        SamplingParams(temperature=1.3, top_p=0.8, seed=4),
+        SamplingParams(temperature=0.9, top_k=8, top_p=0.9, seed=5),
+    ], ids=["plain", "top_k", "top_p", "top_k_p"])
+    def test_row_token_independent_of_its_batch(self, sp, verify):
+        """Under ``jit``, a stochastic row draws the same token alone,
+        beside greedy rows and beside other stochastic rows; greedy rows
+        are ``argmax`` bit for bit in an all-greedy batch and a mixed one.
+        ``verify`` gives each row ``k+1`` positions, as spec verify does."""
+        import jax
+        import jax.numpy as jnp
+
+        pick = jax.jit(sample_tokens)
+        b, k1, vocab, row = 4, 3, 48, 2
+        rng = np.random.default_rng(43)
+        tail = (k1,) if verify else ()
+        logits = rng.normal(0, 2.0, (b,) + tail + (vocab,)).astype(np.float32)
+        counters = (np.arange(b * k1).reshape(b, k1) if verify
+                    else np.arange(b)).astype(np.int32) + 7
+
+        def batch(params, rows):
+            cols = [_samp(1, p, rid=f"r{i}") for i, p in zip(rows, params)]
+            samp = tuple(jnp.concatenate(c) for c in zip(*cols))
+            return np.asarray(pick(jnp.asarray(logits[rows]),
+                                   jnp.asarray(counters[rows]), samp))
+
+        alone = batch([sp], [row])[0]
+        others = [SamplingParams(temperature=1.2, seed=9),
+                  SamplingParams(temperature=0.5, top_k=3, seed=8),
+                  SamplingParams(temperature=0.8, top_p=0.7, seed=7)]
+        mixed = [SamplingParams()] * b
+        mixed[row] = sp
+        beside_greedy = batch(mixed, list(range(b)))
+        stochastic = others[:row] + [sp] + others[row:]
+        beside_stochastic = batch(stochastic, list(range(b)))
+        assert np.array_equal(beside_greedy[row], alone)
+        assert np.array_equal(beside_stochastic[row], alone)
+        want = np.argmax(logits, axis=-1)
+        greedy_rows = [i for i in range(b) if i != row]
+        assert np.array_equal(beside_greedy[greedy_rows], want[greedy_rows])
+        assert np.array_equal(
+            batch([SamplingParams()] * b, list(range(b))), want)
+
+
 # ------------------------------------------------- engine rig (CPU-sim)
 @pytest.fixture(scope="module")
 def rig():
@@ -234,6 +316,85 @@ class TestPrefixSampling:
         cold, _, _ = _tiny_engine(prefix_cache=True)
         assert cold.generate(shared, MAX_NEW, request_id="probe",
                              sampling=sp) == hit
+
+
+class TestSamplerCounters:
+    """``sampler_argmax_calls`` / ``sampler_draw_calls`` and the dispatch
+    spans' ``sampler`` attribute follow the branch each call takes."""
+
+    @staticmethod
+    def _branches(spans):
+        return {name: [s.attrs["sampler"] for s in spans if s.name == name]
+                for name in ("serve.decode_dispatch", "serve.prefill_chunk")}
+
+    def test_greedy_then_one_stochastic_request(self):
+        from autodist_tpu.api import AutoDist
+        from autodist_tpu.obs import spans as obs_spans
+        from autodist_tpu.serve.server import _tiny_engine
+
+        engine = _tiny_engine(n_slots=4, n_pages=56)[0]
+        tracer = obs_spans.get_tracer()
+        prompt = np.arange(1, 21, dtype=np.int32)   # three chunks
+        try:
+            tracer.clear()
+            engine.generate(prompt, MAX_NEW, request_id="g")
+            greedy = self._branches(tracer.spans())
+            calls = sum(map(len, greedy.values()))
+            assert calls and all(b == "argmax" for v in greedy.values()
+                                 for b in v)
+            assert engine.sampler_argmax_calls == calls
+            assert engine.sampler_draw_calls == 0
+
+            tracer.clear()
+            engine.generate(prompt, MAX_NEW, request_id="s",
+                            sampling=SamplingParams(temperature=0.8, seed=1))
+            drawn = self._branches(tracer.spans())
+            assert drawn["serve.decode_dispatch"] and all(
+                b == "draw" for v in drawn.values() for b in v)
+            assert engine.sampler_draw_calls == sum(map(len, drawn.values()))
+            assert engine.sampler_argmax_calls == calls
+            assert engine.stochastic_slots == 0
+        finally:
+            AutoDist.reset_default()
+
+    def test_mixed_batch_draws_and_greedy_chunk_does_not(self):
+        """A decode step over a greedy row and a stochastic one draws; the
+        greedy row's own chunk, which takes its row alone, does not; once
+        the stochastic row retires the step is back to the argmax."""
+        from autodist_tpu.api import AutoDist
+        from autodist_tpu.obs import spans as obs_spans
+        from autodist_tpu.serve.server import _tiny_engine
+
+        engine = _tiny_engine(n_slots=4, n_pages=56)[0]
+        tracer = obs_spans.get_tracer()
+
+        def prefill(slot):
+            while engine.prefill_step(slot) is None:
+                pass
+
+        try:
+            s = engine.admit(np.arange(1, 6), 8, request_id="s",
+                             sampling=SamplingParams(temperature=1.0, seed=2))
+            g = engine.admit(np.arange(3, 9), 8, request_id="g")
+            assert engine.stochastic_slots == 1
+            tracer.clear()
+            prefill(s)
+            prefill(g)
+            engine.step()
+            got = self._branches(tracer.spans())
+            assert got == {"serve.decode_dispatch": ["draw"],
+                           "serve.prefill_chunk": ["draw", "argmax"]}
+            engine.release(s)
+            assert engine.stochastic_slots == 0
+            tracer.clear()
+            engine.step()
+            assert self._branches(tracer.spans())[
+                "serve.decode_dispatch"] == ["argmax"]
+            engine.release(g)
+            assert engine.stochastic_slots == 0
+            assert engine.compiled_programs == 2
+        finally:
+            AutoDist.reset_default()
 
 
 # ----------------------------------------------------------- HTTP edge
